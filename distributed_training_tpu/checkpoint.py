@@ -220,11 +220,7 @@ def restore_checkpoint(directory: str, epoch: int, state: Any,
     # naming the directory and the remedy (resilience/verify.py).
     verify_lib.verify_checkpoint(path)
     ckptr = ocp.PyTreeCheckpointer()
-    saved_md = ckptr.metadata(path)
-    if hasattr(saved_md, "item_metadata"):  # orbax >= 0.9 metadata object
-        saved = saved_md.item_metadata.tree or {}
-    else:  # orbax <= 0.7: metadata() returns the tree dict directly
-        saved = saved_md or {}
+    saved = ckptr.metadata(path).item_metadata.tree or {}
     state_template = serialization.to_state_dict(state)
     rename = _legacy_block_rename(saved.get("state"), state_template)
     rename.update(_legacy_vit_rename(saved.get("state"), state_template))
@@ -244,17 +240,8 @@ def restore_checkpoint(directory: str, epoch: int, state: Any,
     # stacking); symmetric compare with default 1/identity on both sides,
     # so legacy saves without the key count as identity and a saved
     # non-identity key the caller did not declare still refuses.
-    try:
-        meta = ckptr.restore(
-            path, item={"meta": meta_template}, partial_restore=True)["meta"]
-    except TypeError:
-        # orbax <= 0.7 has no partial_restore kwarg; empty transforms +
-        # per-leaf RestoreArgs is that API's partial-restore spelling.
-        meta = ckptr.restore(
-            path, item={"meta": meta_template}, transforms={},
-            restore_args=jax.tree.map(
-                lambda _: ocp.RestoreArgs(), {"meta": meta_template}),
-        )["meta"]
+    meta = ckptr.restore(
+        path, item={"meta": meta_template}, partial_restore=True)["meta"]
     saved_layout = {k[len("layout_"):]: int(v) for k, v in meta.items()
                     if k.startswith("layout_")}
     want_layout = {k: int(v) for k, v in (layout or {}).items()}

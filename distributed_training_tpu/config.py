@@ -822,8 +822,8 @@ class LMConfig:
     # Head/logits compute dtype: "bf16" (default since round 6, matching
     # the train.py/bench.py/generate.py CLI defaults — ADVICE r5 flagged
     # the divergence) or "fp32". bf16 halves the [B, T, vocab] logits HBM
-    # round-trips (measured +7% tok/s on GPT-2-small T1024, BASELINE.md
-    # round 4; 8-epoch chip A/B tracks fp32 to the 4th decimal, round 5);
+    # round-trips (measured +7% tok/s on GPT-2-small T1024 in round 4;
+    # 8-epoch chip A/B tracks fp32 to the 4th decimal, round 5);
     # the CE still reduces in fp32 (train/lm_step.py::_fused_ce_rows),
     # only the stored logits round to bf16. tests/test_config.py pins
     # config default == CLI default.

@@ -69,8 +69,7 @@ def synthetic_cifar10_hard(n: int, train: bool, seed: int = 0):
     pixels stays near chance, so a model reaching high accuracy had to
     learn oriented-frequency conv features — making a multi-epoch
     convergence run a real signal (used for the 5-epoch reference-protocol
-    run on the real chip when the actual CIFAR-10 binaries are absent;
-    BASELINE.md "convergence").
+    run on the real chip when the actual CIFAR-10 binaries are absent).
     """
     rng = np.random.RandomState(seed + (0 if train else 1))
     labels = rng.randint(0, NUM_CLASSES, size=n).astype(np.int32)

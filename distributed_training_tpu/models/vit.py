@@ -92,7 +92,7 @@ class ViT(nn.Module):
     # Rematerialize each encoder block in the backward pass (activation
     # checkpointing): O(depth) activation memory for ~30% extra FLOPs —
     # measured to unlock batch 512/chip on v5e where plain bf16 OOMs by
-    # 16 MB (BASELINE.md).
+    # 16 MB.
     remat: bool = False
 
     @nn.compact

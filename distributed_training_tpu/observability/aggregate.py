@@ -74,10 +74,9 @@ _generation = itertools.count()
 def _coordination_client():
     """jax's distributed-coordination KV client (None single-process).
 
-    Private-module import (``jax._src.distributed``) with the same
-    rationale as utils/compat.py: there is no public host-side KV
-    surface, and the alternative — an XLA all-gather — both occupies
-    the accelerators and is unimplemented on multi-process CPU.
+    Private-module import (``jax._src.distributed``): there is no
+    public host-side KV surface, and the alternative — an XLA
+    all-gather — puts work on the accelerators at every meter flush.
     """
     from jax._src import distributed
 

@@ -21,7 +21,8 @@ was tried and measured NO faster at T1024/4096/16384: the VPU cost there
 is the exp, not the mask; reverted to keep one code path.)
 
 Off-TPU (tests, CPU mesh) the kernels run in pallas interpret mode,
-bit-compatible with the compiled path. Block sizes default to the 128-lane
+bit-compatible with the compiled path; on TPU interpret mode is refused
+(``utils/compat.py::pallas_interpret``). Block sizes default to the 128-lane
 hardware tile; sequence length must divide into blocks.
 
 No reference counterpart exists (the reference has no attention model at
@@ -38,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from distributed_training_tpu.utils.compat import on_tpu
+from distributed_training_tpu.utils.compat import pallas_interpret
 
 NEG_INF = -1e30
 
@@ -575,7 +576,7 @@ def _flat_args(q, k, v, block_q, block_k, bwd_block_q, bwd_block_k,
     """Shared arg prep: shape check, auto block rule, flatten lead dims."""
     if q.shape != k.shape or q.shape != v.shape:
         raise ValueError(f"q/k/v shapes differ: {q.shape} {k.shape} {v.shape}")
-    run_interpret = (not on_tpu()) if interpret is None else interpret
+    run_interpret = pallas_interpret(interpret)
     t, d = q.shape[-2:]
     if block_q is None:
         block_q = min(t, 1024)

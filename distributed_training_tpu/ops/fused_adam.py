@@ -31,7 +31,7 @@ import optax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from distributed_training_tpu.utils.compat import on_tpu
+from distributed_training_tpu.utils.compat import pallas_interpret
 
 # VPU-tile-aligned block: 8 sublanes × 128 lanes × 32 rows.
 _BLOCK = 8 * 128 * 32
@@ -107,7 +107,7 @@ def fused_adam_kernel_update(
         out_specs=[tensor_spec, tensor_spec, tensor_spec],
         out_shape=[jax.ShapeDtypeStruct((rows, 128), jnp.float32)] * 3,
         input_output_aliases={1: 0, 3: 1, 4: 2},
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(scalars, pf, gf, mf, vf)
 
     unflat = lambda x: x.reshape(-1)[:n].reshape(orig_shape)  # noqa: E731
@@ -145,7 +145,7 @@ def fused_adam(
     def update_fn(updates, state, params):
         if params is None:
             raise ValueError("fused_adam requires params")
-        run_interpret = (not on_tpu()) if interpret is None else interpret
+        run_interpret = pallas_interpret(interpret)
         count = state.count + 1
         lr = learning_rate(count) if callable(learning_rate) else learning_rate
         lr = jnp.asarray(lr, jnp.float32)

@@ -1,33 +1,66 @@
 """ctypes binding for the native augmentation library.
 
-Lazy-builds ``libaugment.so`` with g++ on first use (no pybind11 in this
-image; plain C ABI + ctypes per the environment's binding guidance) and
-falls back to the pure-numpy implementations in ``data/transforms.py`` when
-no compiler is available — the native path is an accelerator, never a hard
-dependency.
+Lazy-builds the library with g++ on first use (no pybind11 in this image;
+plain C ABI + ctypes per the environment's binding guidance) and falls back
+to the pure-numpy implementations in ``data/transforms.py`` when no compiler
+is available — the native path is an accelerator, never a hard dependency.
+Which of the two is in use is said once on stderr.
+
+The library's file name carries a hash of ``augment.cpp``, the compiler
+flags and (because of ``-march=native``) this machine's CPU flags: a library
+left in the tree by another source revision or another machine — file
+mtimes mean nothing after a copy — is never loaded; it is rebuilt here.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
+import sys
 import threading
 
 import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "augment.cpp")
-_LIB = os.path.join(_HERE, "libaugment.so")
+_FLAGS = ("-O3", "-fPIC", "-shared", "-pthread", "-march=native")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _build_failed = False
 
 
-def _build() -> bool:
-    cmd = ["g++", "-O3", "-fPIC", "-shared", "-pthread",
-           "-march=native", "-o", _LIB, _SRC]
+def _cpu_identity() -> str:
+    """What ``-march=native`` resolved against: the ISA-extension list of
+    this machine's CPU (Linux), else just the architecture name."""
+    try:
+        with open("/proc/cpuinfo") as fh:
+            for line in fh:
+                if line.startswith(("flags", "Features")):
+                    return platform.machine() + " " + line.split(":", 1)[1]
+    except OSError:
+        pass
+    return platform.machine()
+
+
+def lib_path() -> str:
+    """The library this source + flags + CPU builds to."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as fh:
+        h.update(fh.read())
+    h.update(" ".join(_FLAGS).encode())
+    h.update(_cpu_identity().encode())
+    return os.path.join(_HERE, f"libaugment-{h.hexdigest()[:16]}.so")
+
+
+def _build(out: str) -> bool:
+    # Build beside the target and rename: a concurrent process never
+    # loads a half-written library.
+    tmp = f"{out}.{os.getpid()}.tmp"
+    cmd = ["g++", *_FLAGS, "-o", tmp, _SRC]
     try:
         res = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
         if res.returncode != 0:
@@ -35,14 +68,27 @@ def _build() -> bool:
             cmd.remove("-march=native")
             res = subprocess.run(
                 cmd, capture_output=True, text=True, timeout=120)
-        return res.returncode == 0
+        if res.returncode != 0:
+            return False
+        os.replace(tmp, out)
+        return True
     except (OSError, subprocess.TimeoutExpired):
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+
+
+def _numpy_path(reason: str) -> None:
+    global _build_failed
+    _build_failed = True
+    print(f"[native] augment: numpy reference path ({reason})",
+          file=sys.stderr)
 
 
 def get_lib() -> ctypes.CDLL | None:
     """Load (building if needed) the native library; None if unavailable."""
-    global _lib, _build_failed
+    global _lib
     if _lib is not None:
         return _lib
     if _build_failed:
@@ -50,17 +96,20 @@ def get_lib() -> ctypes.CDLL | None:
     with _lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB) or (
-                os.path.exists(_SRC)
-                and os.path.getmtime(_SRC) > os.path.getmtime(_LIB)):
-            if not _build():
-                _build_failed = True
-                return None
-        try:
-            lib = ctypes.CDLL(_LIB)
-        except OSError:
-            _build_failed = True
+        if _build_failed:
             return None
+        path = lib_path()
+        built = not os.path.exists(path)
+        if built and not _build(path):
+            _numpy_path("g++ build failed")
+            return None
+        try:
+            lib = ctypes.CDLL(path)
+        except OSError as e:
+            _numpy_path(f"{os.path.basename(path)} did not load: {e}")
+            return None
+        print(f"[native] augment: native library {os.path.basename(path)} "
+              f"({'built now' if built else 'found'})", file=sys.stderr)
         lib.pad_crop_flip_u8.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p,
             ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,

@@ -48,8 +48,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from distributed_training_tpu.utils.compat import axis_size
-
 from distributed_training_tpu.runtime.mesh import AXIS_MODEL
 
 
@@ -82,7 +80,7 @@ def _allgather_matmul_impl(x, w, axis_name):
     Returns [..., n·t, N]. Each of the n-1 hops ppermutes the next input
     shard while the current shard's matmul fills its output slice.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x @ w
     i0 = lax.axis_index(axis_name)
@@ -114,7 +112,7 @@ def _gather_xt_dy_ring(x, dy, axis_name):
     visiting shard's ``x_srcᵀ · dy[src block]`` — the weight-gradient half
     of the allgather_matmul backward.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     i0 = lax.axis_index(axis_name)
     t = x.shape[-2]
 
@@ -202,7 +200,7 @@ def _matmul_reducescatter_impl(x, w, axis_name, scatter_dim):
     contribution for chunk (j - s - 1) mod n at step s, so after n-1 hops
     each device holds the fully-reduced chunk it owns.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x @ w
     if scatter_dim == -2 and x.shape[-2] % n:
@@ -239,7 +237,7 @@ def _gather_dy_bwd_ring(x, w, dy, axis_name, scatter_dim):
     visiting chunk twice — once into dx (rows of ``dz @ wᵀ`` for the rows
     mode; a rank-N/n update of ``dx`` for the cols mode) and once into dw.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     i0 = lax.axis_index(axis_name)
     dx0 = jnp.zeros(x.shape, jnp.result_type(dy.dtype, w.dtype))
     dw0 = jnp.zeros(w.shape, jnp.result_type(x.dtype, dy.dtype))
@@ -327,7 +325,7 @@ def matmul_reducescatter(x, w, axis_name: str = AXIS_MODEL,
 
 
 def _ring_all_gather_impl(x, axis_name, dim):
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     i0 = lax.axis_index(axis_name)
@@ -350,7 +348,7 @@ def _ring_all_gather_impl(x, axis_name, dim):
 
 def _ring_reduce_scatter_impl(x, axis_name, dim):
     """Σ_dev x_dev, scattered over ``dim`` (each device keeps its chunk)."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     if x.shape[dim] % n:
@@ -437,7 +435,7 @@ def overlap_finalize_grads(grads, axis_name: str = AXIS_MODEL):
     )
     from distributed_training_tpu.utils.tree import path_str
 
-    tp = axis_size(axis_name)
+    tp = lax.axis_size(axis_name)
 
     def has_model(entry):
         return (entry == axis_name
@@ -509,7 +507,7 @@ def seq_overlap_interceptor(axis_name: str = AXIS_MODEL):
         if mod.is_initializing() or context.method_name != "__call__":
             return next_fun(*args, **kwargs)
         name = mod.name or ""
-        n = axis_size(axis_name)
+        n = lax.axis_size(axis_name)
 
         if isinstance(mod, nn.Dense) and name == "fc1":
             x = args[0]
@@ -589,7 +587,7 @@ def replicated_overlap_interceptor(axis_name: str = AXIS_MODEL):
         if mod.is_initializing() or context.method_name != "__call__":
             return next_fun(*args, **kwargs)
         name = mod.name or ""
-        n = axis_size(axis_name)
+        n = lax.axis_size(axis_name)
 
         if isinstance(mod, nn.Dense) and name == "fc1":
             # Column-parallel, replicated input: local shard matmul (the

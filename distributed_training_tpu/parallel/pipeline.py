@@ -54,7 +54,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_training_tpu.runtime.mesh import AXIS_DATA, AXIS_PIPE
-from distributed_training_tpu.utils.compat import axis_size, shard_map
+from distributed_training_tpu.utils.compat import shard_map
 
 
 def circular_layer_order(num_layers: int, stages: int,
@@ -147,7 +147,7 @@ def spmd_pipeline(
     live ticks only (warmup/drain garbage is masked), psum'd over the pipe
     axis so every rank holds the full-depth value.
     """
-    s = axis_size(axis_name)
+    s = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     m = num_microbatches
     v = virtual_stages

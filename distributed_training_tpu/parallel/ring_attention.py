@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from distributed_training_tpu.utils.compat import axis_size as _axis_size
-
 
 class PagedKV(NamedTuple):
     """Per-call paged-KV routing state (a pytree of device arrays).
@@ -124,7 +122,7 @@ def ring_attention(
         p = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
         return jnp.einsum("...qk,...kd->...qd", p.astype(v.dtype), v)
 
-    axis_size = _axis_size(axis_name)
+    axis_size = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     # Accumulate in fp32 regardless of compute dtype: the recurrence
     # subtracts running maxima and sums many exps — bf16 drifts.
@@ -179,7 +177,7 @@ def _ring_attention_flash(q, k, v, *, axis_name: str, causal: bool):
         flash_attention_lse,
     )
 
-    axis_size = _axis_size(axis_name)
+    axis_size = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     o = jnp.zeros(q.shape, jnp.float32)
     lse_acc = jnp.full(q.shape[:-1], NEG_INF, jnp.float32)

@@ -41,7 +41,7 @@ from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_training_tpu.runtime.mesh import AXIS_DATA
-from distributed_training_tpu.utils.compat import axis_size, shard_map
+from distributed_training_tpu.utils.compat import shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -123,7 +123,7 @@ def make_zero1_train_step(
     axis = AXIS_DATA
 
     def body(state: Zero1State, batch, rng):
-        world = axis_size(axis)
+        world = lax.axis_size(axis)
         rank = lax.axis_index(axis)
         rng = jax.random.fold_in(rng, rank)
 

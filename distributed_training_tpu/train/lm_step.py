@@ -40,7 +40,7 @@ from distributed_training_tpu.runtime.mesh import (
 )
 from distributed_training_tpu.train.precision import commit_gradients
 from distributed_training_tpu.train.train_state import TrainState
-from distributed_training_tpu.utils.compat import axis_size, shard_map
+from distributed_training_tpu.utils.compat import shard_map
 
 _GRAD_AXES = (AXIS_DATA, AXIS_SEQUENCE)
 
@@ -122,7 +122,7 @@ def _fused_ce_rows(logits, targets, with_correct: bool = False):
     impossible, so the metric can overcount top-1 by the (tiny) tie rate.
     Either way it deletes the separate argmax reduction, a full extra HBM
     pass over the [B, T, vocab] tensor (measured 4.4 ms / +3.8% tok/s on
-    the GPT-2-small B16 T1024 step, BASELINE.md round 4).
+    the GPT-2-small B16 T1024 step in round 4).
     """
     m = lax.stop_gradient(
         jnp.max(logits, axis=-1, keepdims=True)).astype(jnp.float32)
@@ -225,7 +225,7 @@ def chunked_ce_and_accuracy(hidden, head_params, targets, chunk: int,
     """CE + token accuracy WITHOUT materializing the [B, T, vocab] logits.
 
     For long contexts × large vocabs the logits tensor dominates memory
-    (B8·T16384·V50304 fp32 = 26 GB — measured OOM on v5e, BASELINE.md):
+    (B8·T16384·V50304 fp32 = 26 GB — measured OOM on v5e):
     scan over time chunks, apply the lm_head to one [B, C, D] slice at a
     time, and reduce CE/accuracy to scalars. The body is
     ``jax.checkpoint``-ed so the backward also recomputes each chunk's
@@ -400,7 +400,7 @@ def _lm_grads_body(gstate: TrainState, batch, rng,
     targets = batch["targets"]
     positions = _global_positions(tokens.shape[1])
     # Decorrelate dropout across shards; no-op when the model has none.
-    fold = (lax.axis_index(AXIS_SEQUENCE) * axis_size(AXIS_DATA)
+    fold = (lax.axis_index(AXIS_SEQUENCE) * lax.axis_size(AXIS_DATA)
             + lax.axis_index(AXIS_DATA))
     if tp_overlap:
         import flax.linen as nn
@@ -409,7 +409,7 @@ def _lm_grads_body(gstate: TrainState, batch, rng,
             seq_overlap_interceptor,
         )
 
-        tp = axis_size(AXIS_MODEL)
+        tp = lax.axis_size(AXIS_MODEL)
         fold = fold * tp + lax.axis_index(AXIS_MODEL)
         # The stack's logits come out time-sharded over model (the overlap
         # layout never re-gathers them); slice the targets to match. The
@@ -656,8 +656,8 @@ def _check_ce_options(ce_chunk, ce_save_probs, logits_dtype=jnp.float32):
 
         warnings.warn(
             "ce_save_probs under bf16 logits is a measured throughput "
-            "LOSS (123.7k vs 125.2k tok/s at GPT-2-small B16 T1024; "
-            "BASELINE.md round 5) — its win is fp32 logits only",
+            "LOSS (123.7k vs 125.2k tok/s at GPT-2-small B16 T1024, "
+            "round 5) — its win is fp32 logits only",
             stacklevel=3)
 
 

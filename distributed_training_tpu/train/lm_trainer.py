@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +43,7 @@ from distributed_training_tpu.data.lm_text import (
 )
 from distributed_training_tpu.models import get_model
 from distributed_training_tpu.parallel.sharding import place_state
+from distributed_training_tpu.runtime.backend import device_banner
 from distributed_training_tpu.runtime.coordinator import Coordinator
 from distributed_training_tpu.runtime.mesh import (
     AXIS_MODEL,
@@ -66,6 +68,7 @@ from distributed_training_tpu.train.train_state import (
     init_train_state,
     param_count,
 )
+from distributed_training_tpu.utils.compat import on_tpu
 from distributed_training_tpu.observability import (
     AnomalyError,
     TrainObservability,
@@ -141,6 +144,19 @@ class LMTrainer:
         # ``model`` automatic), so megatron TP shardings propagate inside
         # the shards and GSPMD inserts the row-parallel psums there.
         self.tp_size = model_par
+        if (cfg.lm.attn_impl == "flash" and self.strategy == "tensor/dp"
+                and self.mesh.size > 1 and on_tpu()):
+            # Seen on a real 2x2 v5e host (PR 21): under plain jit XLA
+            # cannot split a Mosaic kernel over the data/model axes
+            # ("Mosaic kernels cannot be automatically partitioned"), and
+            # libtpu 0.0.34 has no emitter for custom_partitioning either.
+            # The CPU interpreter hides it, so say it here, before the
+            # first compile. The shard_map strategies run flash on chips.
+            raise NotImplementedError(
+                "attn_impl='flash' does not run under the tensor/dp "
+                "strategy on more than one TPU chip (XLA cannot partition "
+                "a Mosaic kernel under plain jit); use --sp N (ring+flash), "
+                "--pp N, or --attn-impl exact")
         if cfg.tp_overlap and self.strategy == "pipeline":
             raise NotImplementedError(
                 "tp_overlap does not compose with the pipeline strategy "
@@ -487,7 +503,8 @@ class LMTrainer:
             f"mesh={shape} strategy={strategy_label} "
             f"zero_stage={cfg.zero.stage} dtype={cfg.precision.dtype} "
             f"seq_len={lm.seq_len}"
-            + (f" grad_accum={self.grad_accum}" if self.grad_accum > 1 else ""))
+            + (f" grad_accum={self.grad_accum}" if self.grad_accum > 1 else "")
+            + f" {device_banner()}", file=sys.stderr)
 
     # -- resilience ---------------------------------------------------------
     def _save_ckpt(self, epoch: int, *, sync: bool = False, **kw) -> None:

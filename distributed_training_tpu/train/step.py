@@ -386,10 +386,9 @@ def _overlap_tp_grads_body(gstate: TrainState, batch, rng, *,
         replicated_overlap_interceptor,
     )
     from distributed_training_tpu.runtime.mesh import AXIS_FSDP, AXIS_MODEL
-    from distributed_training_tpu.utils.compat import axis_size
 
     rng = jax.random.fold_in(
-        rng, jax.lax.axis_index(AXIS_DATA) * axis_size(AXIS_FSDP)
+        rng, jax.lax.axis_index(AXIS_DATA) * jax.lax.axis_size(AXIS_FSDP)
         + jax.lax.axis_index(AXIS_FSDP))
     with nn.intercept_methods(replicated_overlap_interceptor(AXIS_MODEL)):
         if accum_steps > 1:

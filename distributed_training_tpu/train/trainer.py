@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +33,7 @@ from distributed_training_tpu.parallel.sharding import (
     place_state,
     state_shardings,
 )
+from distributed_training_tpu.runtime.backend import device_banner
 from distributed_training_tpu.runtime.coordinator import Coordinator
 from distributed_training_tpu.runtime.mesh import MeshConfig, create_mesh, data_axis_size
 from distributed_training_tpu.train.optim import make_optimizer
@@ -267,7 +269,8 @@ class Trainer:
             f"mesh={dict(zip(self.mesh.axis_names, self.mesh.devices.shape))} "
             f"plugin={cfg.plugin} zero_stage={cfg.zero.stage} "
             f"dtype={cfg.precision.dtype}"
-            + (f" grad_accum={self.grad_accum}" if self.grad_accum > 1 else ""))
+            + (f" grad_accum={self.grad_accum}" if self.grad_accum > 1 else "")
+            + f" {device_banner()}", file=sys.stderr)
 
     # -- resilience ---------------------------------------------------------
     def _save_ckpt(self, epoch: int, *, sync: bool = False, **kw) -> None:

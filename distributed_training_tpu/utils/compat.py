@@ -1,59 +1,38 @@
-"""JAX API + platform shims shared across the framework."""
+"""Platform query + the framework's ``shard_map`` spelling."""
 
 from __future__ import annotations
 
 import jax
+from jax import shard_map as _shard_map
 
 
 def on_tpu() -> bool:
-    """True when the first visible device is a TPU (Pallas ops use this to
-    pick compiled vs interpret mode)."""
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    """True when the first visible device is a TPU.
 
-try:  # jax >= 0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a bound mesh axis, across jax versions.
-
-    ``lax.axis_size`` only exists on newer jax; the classic spelling —
-    ``psum`` of the constant 1 over the axis, which constant-folds to the
-    (static) axis size — works everywhere a collective would.
+    A failed backend query RAISES (whatever ``jax.devices()`` raised): the
+    Pallas ops pick compiled vs interpret mode from this, and a swallowed
+    error would silently turn a compiled kernel into the interpreter.
     """
-    lax = jax.lax
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    return jax.devices()[0].platform == "tpu"
 
 
-def supports_partial_manual() -> bool:
-    """True when this jax's ``shard_map`` accepts ``axis_names`` (jax >=
-    0.6) — i.e. the partial-manual compositions (TP×SP, PP×TP, PP×EP,
-    SP-accum, SP×PP) can run at all. The test suite gates its xfail marks
-    on this so the known-broken compositions don't burn CI minutes
-    re-raising the same TypeError on older jax, yet re-run (and XPASS,
-    flagging the marks for removal) the moment the environment upgrades.
-    """
-    import inspect
-
-    try:
-        return "axis_names" in inspect.signature(_shard_map).parameters
-    except (TypeError, ValueError):  # pragma: no cover - exotic builds
-        return False
+def pallas_interpret(requested: bool | None) -> bool:
+    """Resolve a Pallas op's ``interpret`` argument — the one place that
+    decides it. ``None`` = auto: compiled on TPU, interpret mode elsewhere
+    (tests, CPU mesh). On platform ``tpu`` interpret mode is refused: a
+    kernel that cannot compile must fail, never run interpreted."""
+    tpu = on_tpu()
+    if requested is None:
+        return not tpu
+    if requested and tpu:
+        raise RuntimeError(
+            "pallas interpret mode requested on platform 'tpu'; kernels "
+            "run compiled on the chip (interpret mode is for CPU tests)")
+    return bool(requested)
 
 
 def shard_map(fn, mesh, in_specs, out_specs, axis_names=None):
-    """``shard_map`` without replication checking, across jax versions.
-
-    The replication-check flag was renamed ``check_rep`` → ``check_vma``;
-    both spellings are handled here so callers don't each carry the
-    try/except.
+    """``shard_map`` without replication checking.
 
     ``axis_names`` selects *partial-manual* mode: only the named mesh axes
     are manual (specs refer to them); the remaining axes stay automatic, so
@@ -63,14 +42,5 @@ def shard_map(fn, mesh, in_specs, out_specs, axis_names=None):
     megatron ``model``-axis psums are inserted by GSPMD inside the shards.
     """
     kwargs = {} if axis_names is None else {"axis_names": set(axis_names)}
-    try:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False, **kwargs)
-    except TypeError:
-        if axis_names is not None:
-            raise RuntimeError(
-                "this jax version's shard_map has no axis_names "
-                "(partial-manual) support; TP×SP / PP×TP composition "
-                "requires jax >= 0.6")
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False, **kwargs)

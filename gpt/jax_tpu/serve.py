@@ -305,6 +305,10 @@ def main() -> int:
         moe_kwargs_from_flags,
     )
     from distributed_training_tpu.inference.sampler import CacheBudgetError
+    from distributed_training_tpu.runtime.backend import (
+        device_banner,
+        enable_compile_cache,
+    )
     from distributed_training_tpu.runtime.preemption import PreemptionGuard
     from distributed_training_tpu.serving import (
         DrainingError,
@@ -313,6 +317,7 @@ def main() -> int:
         QueueFullError,
     )
 
+    enable_compile_cache()
     moe_kwargs = moe_kwargs_from_flags(
         enabled=args.moe, num_experts=args.num_experts,
         top_k=args.moe_top_k, min_capacity=args.min_capacity,
@@ -456,7 +461,8 @@ def main() -> int:
     # SIGTERM re-raises through the previous handler ("now" semantics).
     texts: dict[int, str] = {}
     with PreemptionGuard() as guard:
-        print("[serve] engine ready", file=sys.stderr, flush=True)
+        print(f"[serve] engine ready {device_banner()}", file=sys.stderr,
+              flush=True)
         for text in lines:
             if guard.triggered:
                 engine.queue.close()  # idempotent; typed rejects below
@@ -496,6 +502,10 @@ def main() -> int:
         # Journal recoveries (redelivered + completed-at-replay) join
         # the report: they are this process's deliveries too.
         done = recovered + engine.drain()
+        # Drained: every page must be back in the pool (or held by the
+        # prefix trie at exactly one reference) — a leak raises here
+        # rather than exit 0 (serve_bench's discipline).
+        engine.check_balanced()
         if guard.triggered:
             print(f"[serve] SIGTERM: drained {len(done)} in-flight "
                   f"request(s), admission closed", file=sys.stderr)

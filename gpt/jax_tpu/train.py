@@ -16,7 +16,13 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
 import sys
+
+# Script-style backend dir (like serve.py): make the package importable
+# when run from anywhere, not just with PYTHONPATH set.
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
 
 
 def add_argument() -> argparse.Namespace:
@@ -53,8 +59,8 @@ def add_argument() -> argparse.Namespace:
                              "(round 5): halves the [B,T,vocab] HBM "
                              "traffic, CE still reduces in fp32, and 3- "
                              "and 8-epoch chip A/Bs track fp32 step-for-"
-                             "step (final ppl 1.0784 vs 1.0785, "
-                             "BASELINE.md); fp32 remains selectable")
+                             "step (final ppl 1.0784 vs 1.0785); fp32 "
+                             "remains selectable")
     parser.add_argument("--ce-save-probs", action="store_true", default=False,
                         help="CE backward from saved bf16 softmax probs: "
                              "+2%% tok/s under --logits-dtype fp32 (its "
@@ -313,11 +319,13 @@ def build_config(args: argparse.Namespace):
 def main() -> int:
     args = add_argument()
 
+    from distributed_training_tpu.runtime.backend import enable_compile_cache
     from distributed_training_tpu.runtime.distributed import (
         initialize_distributed,
     )
     from distributed_training_tpu.train.lm_trainer import LMTrainer
 
+    enable_compile_cache()
     initialize_distributed()
     cfg = build_config(args)
     trainer = LMTrainer(cfg)

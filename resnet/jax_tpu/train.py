@@ -27,7 +27,13 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import os
 import sys
+
+# Script-style backend dir (like serve.py): make the package importable
+# when run from anywhere, not just with PYTHONPATH set.
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
 
 
 def add_argument() -> argparse.Namespace:
@@ -403,11 +409,13 @@ def build_config(args: argparse.Namespace):
 def main() -> int:
     args = add_argument()
 
+    from distributed_training_tpu.runtime.backend import enable_compile_cache
     from distributed_training_tpu.runtime.distributed import (
         initialize_distributed,
     )
     from distributed_training_tpu.train.trainer import Trainer
 
+    enable_compile_cache()
     initialize_distributed()  # no-op single-process; env-driven multi-host
     cfg = build_config(args)
     trainer = Trainer(cfg)

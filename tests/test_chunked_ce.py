@@ -3,7 +3,7 @@
 Equivalence is the load-bearing property: chunked CE must reproduce the
 whole-logits loss, gradients, and training trajectory bitwise (same fp32
 head matmul, just sliced over time). The memory win itself is measured on
-hardware (BASELINE.md: B8·T16384·V50304 fp32 logits = 26 GB > HBM).
+hardware (B8·T16384·V50304 fp32 logits = 26 GB > HBM).
 """
 
 import jax

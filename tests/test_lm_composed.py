@@ -31,9 +31,6 @@ from distributed_training_tpu.train.train_state import init_train_state
 
 VOCAB = 64
 
-# Shared xfail for the known partial-manual env gap (see tests/conftest.py).
-from conftest import needs_partial_manual
-
 
 @pytest.fixture(scope="module")
 def sp_tp_mesh():
@@ -80,7 +77,6 @@ def _assert_tree_close(a, b, atol=1e-5, rtol=1e-4):
 
 
 class TestSequenceTensorComposition:
-    @needs_partial_manual
     def test_sp_tp_step_matches_single_device(self, sp_tp_mesh):
         """(data=2 × sequence=2 × model=2) ring step with megatron-sharded
         weights == single-device step."""
@@ -115,7 +111,6 @@ class TestSequenceTensorComposition:
         fc1 = placed.params["block0"]["mlp"]["fc1"]["kernel"]
         assert fc1.sharding.shard_shape(fc1.shape)[1] == fc1.shape[1] // 2
 
-    @needs_partial_manual
     def test_sp_tp_loss_decreases(self, sp_tp_mesh):
         """Smoke: 25 composed steps on a learnable pattern drop the loss."""
         start = np.random.RandomState(0).randint(0, VOCAB, (8, 1))
@@ -141,7 +136,6 @@ class TestSequenceTensorComposition:
 
 
 class TestPipelineTensorComposition:
-    @needs_partial_manual
     def test_pp_tp_step_matches_single_device(self, pp_tp_mesh):
         """(data=2 × pipe=2 × model=2) GPipe step with megatron-sharded
         stage weights == single-device step."""
@@ -237,7 +231,6 @@ class TestLMTrainerComposition:
                         train_sequences=64, eval_sequences=16),
         )
 
-    @needs_partial_manual
     def test_lm_trainer_runs_sp_tp(self):
         from distributed_training_tpu.train.lm_trainer import LMTrainer
 
@@ -247,7 +240,6 @@ class TestLMTrainerComposition:
         assert result["steps"] == 4
         assert np.isfinite(result["final_perplexity"])
 
-    @needs_partial_manual
     def test_lm_trainer_runs_pp_tp(self):
         from distributed_training_tpu.train.lm_trainer import LMTrainer
 
@@ -257,7 +249,6 @@ class TestLMTrainerComposition:
         assert result["steps"] == 4
         assert np.isfinite(result["final_perplexity"])
 
-    @needs_partial_manual
     def test_lm_trainer_runs_sequence_pipe(self):
         """seq×pipe composes since round 5 (was the engine's last refusal):
         the pipeline strategy drives a seq_axis model with ring attention
@@ -294,7 +285,6 @@ class TestSequenceExpertComposition:
             input_dtype=jnp.int32)
         return model, state
 
-    @needs_partial_manual
     def test_sp_ep_step_is_placement_invariant(self):
         devices = jax.devices()
         ep_mesh = create_mesh(MeshConfig(data=2, sequence=2, expert=2),
@@ -327,7 +317,6 @@ class TestSequenceExpertComposition:
         w1 = s_ep.params["block1"]["moe_mlp"]["experts"]["w1"]
         assert w1.sharding.shard_shape(w1.shape)[0] == w1.shape[0] // 2
 
-    @needs_partial_manual
     def test_lm_trainer_runs_sp_ep(self):
         import dataclasses
 
@@ -348,7 +337,6 @@ class TestSequenceExpertComposition:
 
 
 class TestSequenceGradAccum:
-    @needs_partial_manual
     def test_sp_accum_matches_single_shot(self, sp_tp_mesh):
         """SP grad accumulation (scan inside the shard_map body) == the
         single-shot step on the same effective batch: equal-sized
@@ -406,7 +394,6 @@ class TestSequencePipeComposition:
     matches the plain (seq_axis=None) pipeline step, whose own
     equivalence to the single-device model is already pinned."""
 
-    @needs_partial_manual
     def test_sp_pp_step_matches_plain_pp(self):
         from distributed_training_tpu.train.train_state import TrainState
 
@@ -442,7 +429,6 @@ class TestSequencePipeComposition:
                                    float(ref_m["loss"]), rtol=1e-6)
         _assert_tree_close(got_params, ref_params, atol=1e-6, rtol=1e-5)
 
-    @needs_partial_manual
     def test_pp_sp_tp_one_program_matches_plain_pp(self):
         """Every explicit axis at once (pipe × sequence × model in one
         compiled SPMD program; data=1 — ZeRO would be a no-op sharding
@@ -483,7 +469,6 @@ class TestSequencePipeComposition:
                                    rtol=1e-5)
         assert float(deep["grads_finite"]) == 1.0
 
-    @needs_partial_manual
     def test_sp_pp_zero1_circular(self):
         """The deeper product: sequence × pipe × circular schedule ×
         ZeRO-1 runs one finite step."""

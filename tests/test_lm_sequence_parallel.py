@@ -123,13 +123,6 @@ def test_lm_loss_decreases_under_sequence_parallelism(lm_mesh):
     assert last < first * 0.5, (first, last)
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="pre-existing on the baked jax 0.4.37 CPU mesh: the ZeRO-1 "
-           "reduce-scatter reassociates differently from the replicated "
-           "all-reduce and 3 Adam steps amplify it past the strict "
-           "1e-6/1e-5 tolerance (max |Δparam| ~4e-5; tracked with the "
-           "round-6/7 environment gaps in CHANGES.md)")
 def test_sequence_parallel_zero1_matches_replicated(lm_mesh):
     """SP×ZeRO-1 (VERDICT r2 #2): the flagship long-context path with Adam
     state sharded over the data × sequence replica group must trace the

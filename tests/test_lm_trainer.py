@@ -24,8 +24,6 @@ from distributed_training_tpu.data.lm_text import (
 )
 from distributed_training_tpu.train.lm_trainer import LMTrainer
 
-from conftest import needs_partial_manual
-
 LM = LMConfig(seq_len=32, num_layers=2, num_heads=4, hidden_dim=32,
               max_len=64, train_sequences=256, eval_sequences=64,
               num_microbatches=2)
@@ -117,7 +115,6 @@ def test_lm_trainer_rejects_bad_meshes(tmp_path):
         LMTrainer(cfg)
 
 
-@needs_partial_manual
 def test_lm_trainer_sequence_pipe_composes(tmp_path):
     """seq×pipe (round 5): the pipeline engine drives a seq_axis model —
     ring attention over the manual sequence axis inside each tick."""

@@ -29,8 +29,6 @@ from distributed_training_tpu.train.lm_step import (
 )
 from distributed_training_tpu.train.lm_trainer import LMTrainer
 
-from conftest import needs_partial_manual
-
 VOCAB = 64
 
 
@@ -313,7 +311,6 @@ class TestPipelineMoE:
         _, m = step(state, batch, rng)
         return m
 
-    @needs_partial_manual
     def test_exact_vs_plain_at_whole_batch_granularity(self, devices):
         """data=1 × m=1: the PP stage routes the identical token set, so
         loss AND aux match the plain GSPMD model to fp32 tolerance."""
@@ -330,7 +327,6 @@ class TestPipelineMoE:
         np.testing.assert_allclose(float(pm["aux_loss"]),
                                    float(rm["aux_loss"]), rtol=1e-4)
 
-    @needs_partial_manual
     def test_dp_pp_ep_zero1_step(self, devices):
         """The full product: data × pipe × expert mesh, ZeRO-1 moments,
         microbatched schedule — aux flows, gradients finite."""
@@ -378,7 +374,6 @@ class TestPipelineMoE:
                            match="PipelineModule cannot carry MoE"):
             PipelinedLM(model, mesh, num_microbatches=2)
 
-    @needs_partial_manual
     def test_trainer_end_to_end(self, devices):
         """LMTrainer drives pipe × expert × homogeneous MoE (config
         surface: moe.every=1)."""

@@ -22,7 +22,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORKER = textwrap.dedent("""
     import os, sys
     import jax
-    jax.config.update("jax_platforms", "cpu")
 
     from distributed_training_tpu.runtime.distributed import initialize_distributed
     initialize_distributed()  # from MASTER_ADDR/MASTER_PORT/RANK/WORLD_SIZE
@@ -76,7 +75,6 @@ WORKER = textwrap.dedent("""
 TRAIN_CKPT_WORKER = textwrap.dedent("""
     import os, sys
     import jax
-    jax.config.update("jax_platforms", "cpu")
 
     from distributed_training_tpu.runtime.distributed import initialize_distributed
     initialize_distributed()
@@ -161,7 +159,6 @@ TRAIN_CKPT_WORKER = textwrap.dedent("""
 TP_WORKER = textwrap.dedent("""
     import os, sys
     import jax
-    jax.config.update("jax_platforms", "cpu")
 
     from distributed_training_tpu.runtime.distributed import initialize_distributed
     initialize_distributed()

@@ -294,20 +294,16 @@ class TestFlightReportTool:
         assert "serving_ttft_hist_p99_ms" in out
 
 
-# The multi-process drill. Deliberately XLA-free: the baked jax 0.4.37
-# CANNOT run cross-process computations on the CPU backend (the same
-# pre-existing limitation that keeps every test_multihost drill red
-# there), which is exactly why the aggregation exchanges payloads over
-# the coordination-service KV store instead of an XLA collective — so
-# THIS path, the one this round ships, is testable on a real
-# multi-process CPU mesh today. The worker drives the real round-9
+# The multi-process drill. Deliberately XLA-free, like the path it
+# tests: the aggregation exchanges payloads over the coordination-
+# service KV store instead of an XLA collective, so a meter flush never
+# puts work on the accelerators. The worker drives the real round-9
 # injector (ChaosMonkey.on_step, host-gated, real sleep) through the
 # real recorder and the real cross-process gather, then writes the
 # aggregated flight dump each rank would dump.
 DRILL_WORKER = textwrap.dedent("""
     import json, os, time
     import jax
-    jax.config.update("jax_platforms", "cpu")
 
     from distributed_training_tpu.runtime.distributed import (
         initialize_distributed)

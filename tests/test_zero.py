@@ -185,8 +185,8 @@ class TestCpuOffload:
     The CPU backend accepts pinned_host PLACEMENT (device_put) but cannot
     execute a jitted step with host-memory out_shardings ("side-effect ops
     cannot be replicated"), so the executing-step validation lives on the
-    real chip (BASELINE.md round 4: 2408 img/s offloaded vs 2528 on-device
-    at zero-1); these tests pin the placement metadata and the refusal
+    real chip (round 4: 2408 img/s offloaded vs 2528 on-device at
+    zero-1); these tests pin the placement metadata and the refusal
     contract.
     """
 

@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Bench regression gate: diff two bench JSON files, fail on regression.
 
-The repo's throughput story has been asserted by eyeballing BENCH_r0X
-trajectories; this turns it into an automated gate. Give it a committed
+The repo's throughput story used to be asserted by eyeballing one bench
+record against the last; this turns it into an automated gate. Give it a committed
 baseline and a fresh run — ``bench.py`` JSON lines, a ``serve_bench.py``
 SLA line, or the driver's BENCH wrapper object — and it compares the
 metrics both sides share against per-metric thresholds, prints one line

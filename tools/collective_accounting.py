@@ -23,13 +23,6 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
-
-if "cpu" in os.environ.get("JAX_PLATFORMS", ""):
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
-
 import jax.numpy as jnp
 import numpy as np
 import optax

@@ -71,7 +71,7 @@ def _conv_kernel(x_ref, w_ref, o_ref, s_ref, ss_ref, acc, *, h, w, cin, cout,
                                                   keepdims=True)
 
 
-def pallas_conv3x3_stats(x, w, *, bn=1, interpret=False):
+def pallas_conv3x3_stats(x, w, *, bn=1):
     """x [N,H,W,Cin] (unpadded), w [3,3,Cin,Cout] ->
     (out [N,H,W,Cout], sum [Cout], sumsq [Cout])."""
     n, h, wd, cin = x.shape
@@ -96,7 +96,6 @@ def pallas_conv3x3_stats(x, w, *, bn=1, interpret=False):
             jax.ShapeDtypeStruct((n // bn, 1, cout), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((h * wd, cout), jnp.float32)],
-        interpret=interpret,
     )(xp, w)
     return out, s.sum(axis=(0, 1)), ss.sum(axis=(0, 1))
 
@@ -137,22 +136,25 @@ def main():
     ap.add_argument("--verify-only", action="store_true")
     args = ap.parse_args()
 
-    interpret = jax.devices()[0].platform != "tpu"
-    print(f"platform: {jax.devices()[0].platform} (interpret={interpret})",
+    from distributed_training_tpu.runtime.backend import (
+        enable_compile_cache,
+        require_tpu,
+    )
+
+    enable_compile_cache()
+    device = require_tpu("conv_fusion_probe")
+    print(f"platform: {device['platform']} ({device['kind']})",
           file=sys.stderr)
 
     for key in args.shapes:
         label, n, h, w, cin, cout = SHAPES[key]
-        if interpret:
-            n = 4  # interpret mode is slow; correctness only
         rng = np.random.RandomState(0)
         x = jnp.asarray(rng.randn(n, h, w, cin), jnp.bfloat16)
         wts = jnp.asarray(rng.randn(3, 3, cin, cout) * 0.05, jnp.bfloat16)
 
         ref_out, ref_s, ref_ss = jax.jit(xla_conv_stats)(x, wts)
         got_out, got_s, got_ss = jax.jit(
-            functools.partial(pallas_conv3x3_stats, bn=args.bn,
-                              interpret=interpret))(x, wts)
+            functools.partial(pallas_conv3x3_stats, bn=args.bn))(x, wts)
         np.testing.assert_allclose(
             np.asarray(got_out, np.float32), np.asarray(ref_out, np.float32),
             atol=0.5, rtol=5e-2)
@@ -161,7 +163,7 @@ def main():
         np.testing.assert_allclose(np.asarray(got_ss), np.asarray(ref_ss),
                                    rtol=2e-2)
         print(f"verify {key}: ok", file=sys.stderr)
-        if args.verify_only or interpret:
+        if args.verify_only:
             continue
 
         t_conv = bench(xla_conv, (x, wts))

@@ -273,8 +273,10 @@ def main() -> int:
 
     from distributed_training_tpu.config import ServeConfig
     from distributed_training_tpu.models import get_model
+    from distributed_training_tpu.runtime.backend import enable_compile_cache
     from distributed_training_tpu.serving import Engine
 
+    enable_compile_cache()
     # Per-slot budget exactly as the engine computes it; sampled prompt
     # lengths are clamped so every generated request is admissible (an
     # uncaught CacheBudgetError mid-measurement would kill the bench

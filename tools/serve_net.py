@@ -97,8 +97,10 @@ def build_engine(args: argparse.Namespace, trace=None):
 
 def run_replica(args: argparse.Namespace) -> int:
     from distributed_training_tpu.observability.trace import fleet_session
+    from distributed_training_tpu.runtime.backend import enable_compile_cache
     from distributed_training_tpu.serving.frontend import ServingFrontend
 
+    enable_compile_cache()
     # Fleet tracing: the replica's session pid is os.getpid() and the
     # file carries the pid in its name, so a SIGKILLed incarnation's
     # trace survives its successor (tools/fleet_trace.py merges them
@@ -240,6 +242,19 @@ def _settle_and_audit(sup, timeout_s: float = 60.0):
 
 def run_front_door(args: argparse.Namespace) -> int:
     from distributed_training_tpu.observability.trace import fleet_session
+    from distributed_training_tpu.runtime.backend import expected_platform
+
+    # One process per chip: every replica is its own process asking JAX
+    # for every device, and this launcher assigns none (ROADMAP B6 owns
+    # chip assignment). On a TPU host only one replica can be placed —
+    # refuse before spawning anything. The door itself stays off JAX's
+    # backends (it imports the package, never a device).
+    if args.replicas > 1 and expected_platform() == "tpu":
+        print(f"serve_net: refusing --replicas {args.replicas} on platform "
+              f"'tpu': each replica process would claim every chip and a "
+              f"chip belongs to one process; run --replicas 1 here (or "
+              f"JAX_PLATFORMS=cpu for the CPU drills)", file=sys.stderr)
+        return 2
     from distributed_training_tpu.serving.router import (
         HttpReplica, Router, RouterFrontDoor)
     from distributed_training_tpu.serving.supervisor import (
