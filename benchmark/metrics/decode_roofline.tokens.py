@@ -1,0 +1,3 @@
+"""Reader of the per-layer metric ``decode_roofline.tokens`` (see PERF.md, Layers)."""
+
+from benchmark.readers import decode_roofline as read  # noqa: F401

@@ -1,0 +1,3 @@
+"""Reader of the per-layer metric ``engine_iter_ms_p50.tokens`` (see PERF.md, Layers)."""
+
+from benchmark.readers import engine_iter_ms_p50 as read  # noqa: F401

@@ -1,0 +1,3 @@
+"""Reader of the per-layer metric ``hbm_peak_gb.train`` (see PERF.md, Layers)."""
+
+from benchmark.readers import hbm_peak_gb as read  # noqa: F401

@@ -1,0 +1,3 @@
+"""Reader of the per-layer metric ``mfu.tokens`` (see PERF.md, Layers)."""
+
+from benchmark.readers import serve_mfu as read  # noqa: F401

@@ -1,0 +1,3 @@
+"""Reader of the per-layer metric ``slot_occupancy.tpot`` (see PERF.md, Layers)."""
+
+from benchmark.readers import slot_occupancy as read  # noqa: F401

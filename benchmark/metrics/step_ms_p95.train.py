@@ -1,0 +1,7 @@
+"""Reader of the per-layer metric ``step_ms_p95.train`` (see PERF.md, Layers)."""
+
+from benchmark import readers
+
+
+def read(ctx):
+    return readers.step_ms(ctx, 95)
