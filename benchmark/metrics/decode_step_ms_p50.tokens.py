@@ -1,0 +1,3 @@
+"""Reader of the per-layer metric ``decode_step_ms_p50.tokens`` (see PERF.md, Layers)."""
+
+from benchmark.spanreaders import decode_step_ms_p50 as read  # noqa: F401

@@ -1,0 +1,3 @@
+"""Reader of the per-layer metric ``serve_host_ms_p50.tpot`` (see PERF.md, Layers)."""
+
+from benchmark.spanreaders import serve_host_ms_p50 as read  # noqa: F401

@@ -23,6 +23,8 @@ from typing import Any, Callable, Iterable, Iterator
 
 import jax
 
+from distributed_training_tpu.observability import trace as trace_lib
+
 _END = object()
 
 
@@ -61,8 +63,15 @@ class DevicePrefetcher:
 
         def worker():
             try:
-                for batch in self._batches:
-                    if stop.is_set() or not put(self._place(batch)):
+                # The worker's two halves, keyed by the batch's ordinal:
+                # the loader's next() and the placement onto the devices.
+                for n, batch in enumerate(
+                        trace_lib.spanned(self._batches, "data.next")):
+                    if stop.is_set():
+                        return
+                    with trace_lib.span("data.place", key=n):
+                        placed = self._place(batch)
+                    if not put(placed):
                         return
             except BaseException as e:  # noqa: BLE001 — reraised in consumer
                 put(("__error__", e))

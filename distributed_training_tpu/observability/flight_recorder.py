@@ -27,6 +27,7 @@ import os
 import time
 from typing import Any
 
+from distributed_training_tpu.observability import trace as trace_lib
 from distributed_training_tpu.observability.histogram import FixedHistogram
 
 FORMAT_VERSION = 1
@@ -46,6 +47,18 @@ def percentile(values, q: float) -> float:
     hi = min(lo + 1, len(xs) - 1)
     frac = pos - lo
     return xs[lo] * (1.0 - frac) + xs[hi] * frac
+
+
+def host_span_stats() -> dict[str, dict[str, float]]:
+    """``{name: {count, p50_ms, p95_ms, max_ms}}`` over the ring of the
+    program's own spans (``observability/trace.py::host_spans``): the
+    flight dump's view of them."""
+    by_name: dict[str, list[float]] = {}
+    for s in trace_lib.host_spans():
+        by_name.setdefault(s.name, []).append(s.seconds * 1e3)
+    return {name: {"count": len(ms), "p50_ms": percentile(ms, 50),
+                   "p95_ms": percentile(ms, 95), "max_ms": max(ms)}
+            for name, ms in sorted(by_name.items())}
 
 
 class FlightRecorder:
@@ -207,6 +220,9 @@ class FlightRecorder:
             snap["histograms"] = {"step_time_ms": self.step_hist.to_dict()}
         if phase_totals:
             snap["wall_clock"] = self.goodput(phase_totals)
+        host_spans = host_span_stats()
+        if host_spans:
+            snap["host_spans"] = host_spans
         if extra:
             snap.update(extra)
         return snap

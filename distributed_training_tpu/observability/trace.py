@@ -21,11 +21,21 @@ kind of cross-component trace). This module is that timeline:
   request arrivals, finish reasons); **counter samples** (``ph: "C"``)
   plot series like queue depth.
 
-Overhead contract: tracing is OFF by default and every integration point
-holds ``trace: TraceSession | None`` — when None, no span body runs and
-the hot loop is byte-identical to the pre-trace code (the transfer-guard
-test keeps pinning that). When ON, a span costs two ``perf_counter``
-reads and one lock-guarded list append.
+- :func:`span` / :func:`record` / :func:`spanned` are the ONE way the
+  program opens a span. A span enters ``jax.profiler.TraceAnnotation``
+  (so it lies on the profiler's clock beside the device's operations in
+  any ``jax.profiler`` trace, whoever started it), lands in a
+  process-wide bounded ring (:func:`host_spans`; always on, like the
+  flight recorder's) and is forwarded to a :class:`TraceSession` when
+  one is attached.
+
+Overhead contract: "off" means no ``TraceSession`` and no profiler
+session — integration points hold ``trace: TraceSession | None`` and
+draw no Chrome event when None. The ring and the annotation remain: a
+span costs two ``perf_counter`` reads, one annotation object and one
+lock-guarded deque append, no device interaction (the transfer-guard
+tests keep pinning that). With a session attached it costs one more
+lock-guarded list append.
 
 Clock: all timestamps are ``time.perf_counter()`` seconds, the SAME
 clock the flight recorder and serving telemetry use — so a latency
@@ -36,12 +46,16 @@ the session epoch (Chrome's unit).
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import itertools
 import json
 import os
 import threading
 import time
-from typing import Any
+from typing import Any, Callable, Iterable, Iterator
+
+from jax.profiler import TraceAnnotation
 
 # One JSON object per file (not the bare-array variant): carries the
 # displayTimeUnit + metadata alongside the events.
@@ -207,6 +221,150 @@ class TraceSession:
         does read devices — so the handler-reachable spelling gets its
         own name and resolves only here."""
         return self.save(path)
+
+
+# -- the program's spans ------------------------------------------------------
+# Ring capacity: a 40 s window of the fastest cell (~6 engine iterations
+# or ~8 training steps a second, <= 10 spans each) plus its set-up and
+# pre-roll fits four times over; at ~0.4 KB a record the ring tops out
+# near 13 MB.
+RING_SPANS = 32768
+
+_ring: collections.deque = collections.deque(maxlen=RING_SPANS)
+_ring_lock = threading.Lock()
+_ids = itertools.count(1)
+_local = threading.local()
+
+
+def _stack() -> list:
+    try:
+        return _local.stack
+    except AttributeError:
+        _local.stack = []
+        return _local.stack
+
+
+class Span(contextlib.ContextDecorator):
+    """One span of the program: the context manager :func:`span` returns
+    and, once closed, the record :func:`host_spans` hands out.
+
+    ``id``/``parent`` nest per thread; ``t0``/``t1`` are
+    ``perf_counter`` seconds; ``key`` names the unit of work (iteration,
+    step, request uid) and ``attrs`` is a small dict the body may still
+    fill (``sp.attrs["program"] = "fused"``) until the span closes.
+    ``key``, ``session`` and ``track`` left ``None`` are the enclosing
+    span's on this thread, so the phases of one iteration share them
+    without repeating them.
+    """
+
+    __slots__ = ("name", "key", "attrs", "id", "parent", "t0", "t1",
+                 "thread", "session", "track", "_annotation")
+
+    def __init__(self, name: str, key: Any, session: "TraceSession | None",
+                 track: str | None, attrs: dict[str, Any]):
+        self.name = name
+        self.key = key
+        self.session = session
+        self.track = track
+        self.attrs = attrs
+        self.id = self.parent = self.t0 = self.t1 = self.thread = None
+
+    def _recreate_cm(self):     # as a decorator: a fresh span per call
+        return Span(self.name, self.key, self.session, self.track,
+                    dict(self.attrs))
+
+    def _adopt(self, stack: list) -> None:
+        """Take id, thread and what the enclosing span hands down."""
+        self.id = next(_ids)
+        self.thread = threading.current_thread().name
+        if stack:
+            outer = stack[-1]
+            self.parent = outer.id
+            if self.key is None:
+                self.key = outer.key
+            if self.session is None:
+                self.session = outer.session
+            if self.track is None:
+                self.track = outer.track
+
+    def _close(self) -> None:
+        with _ring_lock:
+            _ring.append(self)
+        if self.session is not None:
+            args = (self.attrs if self.key is None
+                    else {**self.attrs, "key": self.key})
+            self.session.complete(self.name, self.t0, self.t1,
+                                  track=self.track or self.thread, **args)
+            self.session = None     # the ring must not keep a session alive
+
+    def __enter__(self) -> "Span":
+        stack = _stack()
+        self._adopt(stack)
+        stack.append(self)
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        self._annotation = None
+        _stack().pop()
+        self._close()
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+
+def span(name: str, *, key: Any = None,
+         session: "TraceSession | None" = None, track: str | None = None,
+         **attrs: Any) -> Span:
+    """Open a span around a ``with`` body (or, as a decorator, around
+    every call of a function)."""
+    return Span(name, key, session, track, attrs)
+
+
+def record(name: str, t0: float, t1: float, *, key: Any = None,
+           session: "TraceSession | None" = None, track: str | None = None,
+           **attrs: Any) -> Span:
+    """A span after the fact, from ``perf_counter`` endpoints the caller
+    already holds (a request's queueing, known when it seats). It has no
+    live extent, so it enters no profiler annotation."""
+    sp = Span(name, key, session, track, attrs)
+    sp._adopt(_stack())
+    sp.t0, sp.t1 = t0, t1
+    sp._close()
+    return sp
+
+
+def spanned(iterable: Iterable, name: str, *,
+            key: Callable[[], Any] | None = None, **kw: Any) -> Iterator:
+    """``iterable``, with every ``next()`` (the last, which ends it,
+    too) inside a span: the wait of a loop on its source. ``key()`` is
+    read before each wait; without it the key is the item's ordinal."""
+    it = iter(iterable)
+    for n in itertools.count():
+        with span(name, key=n if key is None else key(), **kw):
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+        yield item
+
+
+def host_spans(since: float | None = None,
+               until: float | None = None) -> list[Span]:
+    """The ring's closed spans, oldest first; with bounds, those that lie
+    wholly inside ``[since, until]`` (``perf_counter`` seconds)."""
+    with _ring_lock:
+        spans = list(_ring)
+    if since is not None:
+        spans = [s for s in spans if s.t0 >= since]
+    if until is not None:
+        spans = [s for s in spans if s.t1 <= until]
+    return spans
 
 
 def session_for_run(cfg, *, default_dir: str, component: str = "train"

@@ -190,6 +190,7 @@ def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret):
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -414,6 +415,7 @@ def _flash_bwd(res, g, *, causal, block_q, block_k, interpret, g_lse=None):
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_fused",
     )(q, k, v, g, lse, delta)
     dq = (dq_partial[0] if nk == 1
           else dq_partial.sum(axis=0).astype(q.dtype))
@@ -449,6 +451,7 @@ def _flash_bwd_split(res, g, *, causal, block_q, block_k, interpret,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, g, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -476,6 +479,7 @@ def _flash_bwd_split(res, g, *, causal, block_q, block_k, interpret,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, g, lse, delta)
     return dq, dk, dv
 

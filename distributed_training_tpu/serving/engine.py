@@ -107,6 +107,7 @@ from distributed_training_tpu.inference.sampler import (
     sample_token,
 )
 from distributed_training_tpu.models.gpt import init_decode_cache
+from distributed_training_tpu.observability import trace as trace_lib
 from distributed_training_tpu.parallel.ring_attention import PagedKV
 from distributed_training_tpu.resilience.errors import SwapError
 from distributed_training_tpu.serving.alerts import (
@@ -169,15 +170,18 @@ class Engine:
 
     ``trace`` (an :class:`~distributed_training_tpu.observability.trace.
     TraceSession`, or None = off) draws the engine on a Perfetto
-    timeline: per-iteration decode spans on an 'engine' track, a
+    timeline: the paged path's ``serve.iteration`` spans and their
+    phases on an 'engine' track (``observability/trace.py::span``), a
     queue-depth counter series, admission marks on a 'queue' track, and
     — the Orca view — one track PER DECODE SLOT carrying each request's
-    queued → prefill (per-chunk spans in paged mode) → decode lifecycle
+    serve.queued → serve.prefill (per-chunk spans in paged mode) →
+    decode lifecycle
     and finish marks. All timestamps come from the same ``perf_counter``
     clock as :class:`ServeTelemetry`, so span-derived latencies equal
     the SLA numbers exactly (pinned by tests/test_trace.py).
     """
 
+    @trace_lib.span("setup.engine_init")
     def __init__(self, model: Any, params: Any, cfg: ServeConfig, *,
                  trace=None, weights_epoch: int = -1, drafter=None):
         check_unsharded(model)
@@ -419,8 +423,9 @@ class Engine:
             # routing (page tables, write heads, last tokens, RNGs) is
             # host-side numpy, shipped as tiny step inputs — so page
             # allocation and slot membership never touch compiled code.
-            self._cache = init_decode_cache(self.model, params,
-                                            batch_size=1)
+            with trace_lib.span("setup.cache_alloc"):
+                self._cache = init_decode_cache(self.model, params,
+                                                batch_size=1)
             self._tables = np.zeros((s, self.pages_per_slot), np.int32)
             self._slot_rng = np.zeros(
                 (s,) + self._base_rng.shape,
@@ -435,11 +440,13 @@ class Engine:
             # decide what enters the trie.
             self._slot_shared = [0] * s
             self._slot_seq: list[ActiveSequence | None] = [None] * s
-            self._fused = jax.jit(
-                self._fused_impl, donate_argnums=(1,) if donate else ())
-            self._decode = jax.jit(
-                self._decode_only_impl,
-                donate_argnums=(1,) if donate else ())
+            with trace_lib.span("setup.program_build"):
+                self._fused = jax.jit(
+                    self._fused_impl,
+                    donate_argnums=(1,) if donate else ())
+                self._decode = jax.jit(
+                    self._decode_only_impl,
+                    donate_argnums=(1,) if donate else ())
         else:
             # Slot-axis device state. The stacked cache comes from the
             # model's own structure (init_decode_cache), so scatters
@@ -577,18 +584,20 @@ class Engine:
         dispatches and never blocks it. The two sub-applies touch
         disjoint pages (the chunk's slot is not decoding), so their
         order is arithmetic-free."""
-        cache, c_sampled = self._chunk_step(params, cache, c_tok, c_pos,
-                                            c_valid, c_table, c_rng)
-        cache, nxt, accept = self._decode_step(
-            params, cache, d_tok, d_pos, d_valid, d_rngs, tables)
+        with jax.named_scope("serve.fused"):
+            cache, c_sampled = self._chunk_step(
+                params, cache, c_tok, c_pos, c_valid, c_table, c_rng)
+            cache, nxt, accept = self._decode_step(
+                params, cache, d_tok, d_pos, d_valid, d_rngs, tables)
         return cache, nxt, accept, c_sampled
 
     def _decode_only_impl(self, params, cache, d_tok, d_pos, d_valid,
                           d_rngs, tables):
         """Iterations with no prefill pending skip the chunk lane's
         compute entirely (the second compiled program)."""
-        return self._decode_step(params, cache, d_tok, d_pos, d_valid,
-                                 d_rngs, tables)
+        with jax.named_scope("serve.decode"):
+            return self._decode_step(params, cache, d_tok, d_pos, d_valid,
+                                     d_rngs, tables)
 
     # -- compiled pieces: legacy contiguous slots ----------------------------
     def _prefill_impl(self, params, prompt, true_len, rng):
@@ -1359,18 +1368,22 @@ class Engine:
             req.ledger.add_tokens(CAUSE_DECODE, 1)
         self.telemetry.on_admitted((seq.seated_t - req.arrival_t) * 1e3,
                                    (t - seq.seated_t) * 1e3)
+        # arrival→seated is queueing, seated→first token is prefill (the
+        # chunk lane's wait included): the request's two spans, keyed by
+        # its uid.
+        track = f"slot {seq.slot}"
+        trace_lib.record("serve.queued", req.arrival_t, seq.seated_t,
+                         key=req.uid, session=self.trace, track=track,
+                         trace=req.trace_id)
+        trace_lib.record("serve.prefill", seq.seated_t, t, key=req.uid,
+                         session=self.trace, track=track,
+                         trace=req.trace_id,
+                         # graftlint: disable=hot-path-transfer -- host int for a JSON trace arg (prompt.size, no device value)
+                         prompt_len=int(req.prompt.size))
         if self.trace is not None:
-            track = f"slot {seq.slot}"
-            # arrival→seated is queueing, seated→first token is prefill;
-            # the raw clock values ride along so the trace-derived TTFT
+            # The raw clock values ride along so the trace-derived TTFT
             # is (t_first_token - t_arrival)*1e3 — bitwise the same
             # arithmetic ServeTelemetry performs.
-            self.trace.complete("queued", req.arrival_t, seq.seated_t,
-                                track=track, uid=req.uid,
-                                trace=req.trace_id)
-            self.trace.complete("prefill", seq.seated_t, t, track=track,
-                                uid=req.uid, trace=req.trace_id,
-                                prompt_len=int(req.prompt.size))
             self.trace.instant("first_token", track=track, t=t,
                                uid=req.uid, trace=req.trace_id,
                                t_arrival=req.arrival_t,
@@ -1572,207 +1585,231 @@ class Engine:
     def _step_paged(self) -> list[FinishedRequest]:
         it = self._iteration
         self._iteration += 1
+        # The iteration and its phases, in order (docs/OBSERVABILITY.md
+        # "Span-level tracing"): admit, assemble, device_step (dispatch,
+        # token_wait), commit, finish. The phases take key, session and
+        # track from the iteration's span.
+        with trace_lib.span("serve.iteration", key=it, session=self.trace,
+                            track="engine") as it_span:
+            return self._iterate_paged(it, it_span)
+
+    def _iterate_paged(self, it: int, it_span) -> list[FinishedRequest]:
+        span = trace_lib.span
         eos = self.sample_cfg.eos_id
         deadlines = (self.cfg.ttft_deadline_ms is not None
                      or self.cfg.deadline_ms is not None)
         finished: list[FinishedRequest] = []
-        if deadlines:
-            self._expire_queue(finished, time.perf_counter())
-        self._cancel_pass(finished)
+        with span("serve.admit"):
+            if deadlines:
+                self._expire_queue(finished, time.perf_counter())
+            self._cancel_pass(finished)
 
-        had_work = not self.idle
-        if had_work:
-            self.telemetry.begin_work()
-        # Tier-aware, page-aware admission (_admit_pass): candidates
-        # seat in tier-strict tenant-fair order when the pool can commit
-        # their worst case; a blocked higher tier preempts the worst
-        # lower-tier active sequence instead of waiting behind it.
-        # Seating costs NO device work here; the prompt (or a
-        # resumption's carried prefix) prefills chunk-by-chunk below,
-        # riding the decode iterations.
-        self._admit_pass(finished)
-        # Head-of-line blocking: anything still queued after the
-        # admission pass is blocked on a slot OR on pool pages until the
-        # next boundary — bill the rest of this iteration as
-        # admission-blocked time (the legacy definition, generalized
-        # from "all slots busy" to "cannot seat").
-        blocked_t0 = (time.perf_counter() if len(self.queue) > 0
-                      else None)
+            had_work = not self.idle
+            if had_work:
+                self.telemetry.begin_work()
+            # Tier-aware, page-aware admission (_admit_pass): candidates
+            # seat in tier-strict tenant-fair order when the pool can
+            # commit their worst case; a blocked higher tier preempts the
+            # worst lower-tier active sequence instead of waiting behind
+            # it. Seating costs NO device work here; the prompt (or a
+            # resumption's carried prefix) prefills chunk-by-chunk below,
+            # riding the decode iterations.
+            self._admit_pass(finished)
+            # Head-of-line blocking: anything still queued after the
+            # admission pass is blocked on a slot OR on pool pages until
+            # the next boundary — bill the rest of this iteration as
+            # admission-blocked time (the legacy definition, generalized
+            # from "all slots busy" to "cannot seat").
+            blocked_t0 = (time.perf_counter() if len(self.queue) > 0
+                          else None)
 
-        active_seqs = self.scheduler.active()
-        decoding = [s for s in active_seqs if not s.prefilling]
-        prefilling = [s for s in active_seqs if s.prefilling]
-        # Oldest prefilling request first (seat order == arrival order):
-        # one chunk per iteration keeps the fused step's shape fixed and
-        # admission FIFO-fair.
-        chunk_seq = min(prefilling, key=lambda s: s.request.uid,
-                        default=None)
+            active_seqs = self.scheduler.active()
+            decoding = [s for s in active_seqs if not s.prefilling]
+            prefilling = [s for s in active_seqs if s.prefilling]
+            # Oldest prefilling request first (seat order == arrival
+            # order): one chunk per iteration keeps the fused step's
+            # shape fixed and admission FIFO-fair.
+            chunk_seq = min(prefilling, key=lambda s: s.request.uid,
+                            default=None)
+            program = ("fused" if chunk_seq is not None
+                       else "decode" if decoding else "idle")
+            it_span.attrs.update(live=len(active_seqs),
+                                 queued=len(self.queue), program=program)
 
-        if chunk_seq is not None or decoding:
-            t_step0 = time.perf_counter()
-            # Verify-window assembly (plain one-token decode when
-            # spec_k=0): incoming token + drafts per decoding slot;
-            # pages ensured only for the VALID width, so speculation
-            # draws nothing beyond the admission commitment.
-            d_tok, d_pos, d_valid, useful_by_slot, drafted = \
-                self._draft_window(decoding)
-            for seq in decoding:
-                # Write positions of this window = tokens already
-                # cached (prompt + generated minus the uncached last)
-                # through the last valid draft row.
-                p = seq.request.prompt.size + len(seq.tokens) - 1
-                self._ensure_pages(
-                    seq.slot, p + useful_by_slot[seq.slot] + 1)
-            t_draft1 = time.perf_counter()
-            c = 0
-            if chunk_seq is not None:
-                # prefill_tokens == the prompt for a fresh seat; for a
-                # resumption it carries prompt + emitted-minus-last, so
-                # the re-prefill rebuilds exactly the cache prefix the
-                # preemption freed (same positions, same fold_in RNG).
-                pre_toks = chunk_seq.prefill_tokens
-                n = pre_toks.size
-                start = chunk_seq.prefill_pos
-                c = min(self.prefill_chunk, n - start)
-                self._ensure_pages(chunk_seq.slot, start + c)
-                cw = self.prefill_chunk
-                c_tok = np.full((cw,), self.sample_cfg.pad_id, np.int32)
-                c_pos = np.zeros((cw,), np.int32)
-                c_valid = np.zeros((cw,), bool)
-                c_tok[:c] = pre_toks[start:start + c]
-                c_pos[:c] = np.arange(start, start + c)
-                c_valid[:c] = True
-                self._cache, nxt, acc, c_sampled = self._fused(
-                    self.params, self._cache, jnp.asarray(d_tok),
-                    jnp.asarray(d_pos), jnp.asarray(d_valid),
-                    jnp.asarray(self._slot_rng),
-                    jnp.asarray(self._tables), jnp.asarray(c_tok),
-                    jnp.asarray(c_pos), jnp.asarray(c_valid),
-                    jnp.asarray(self._tables[chunk_seq.slot][None]),
-                    jnp.asarray(self._slot_rng[chunk_seq.slot]))
-            else:
-                self._cache, nxt, acc = self._decode(
-                    self.params, self._cache, jnp.asarray(d_tok),
-                    jnp.asarray(d_pos), jnp.asarray(d_valid),
-                    jnp.asarray(self._slot_rng),
-                    jnp.asarray(self._tables))
-            # graftlint: disable=hot-path-transfer -- THE per-iteration sync: tokens must land (docs/SERVING.md)
-            toks = np.asarray(nxt)
-            # graftlint: disable=hot-path-transfer -- per-slot accept lengths ride the same iteration sync
-            accepts = np.asarray(acc)
-            t = time.perf_counter()
-            emitted, accepted = self._apply_accepts(
-                decoding, toks, accepts, useful_by_slot, t)
-            if self.spec_k:
-                # Host-side accept/rewind bookkeeping cost, attributed
-                # explicitly like admission_blocked_s/swap_blocked_s —
-                # and billed to each decoding request's ledger as
-                # 'spec_rollback' (the batch shares the wall window).
-                t_roll = time.perf_counter()
+        if program != "idle":
+            with span("serve.assemble") as asm_span:
+                # Verify-window assembly (plain one-token decode when
+                # spec_k=0): incoming token + drafts per decoding slot;
+                # pages ensured only for the VALID width, so speculation
+                # draws nothing beyond the admission commitment.
+                d_tok, d_pos, d_valid, useful_by_slot, drafted = \
+                    self._draft_window(decoding)
                 for seq in decoding:
-                    if seq.request.ledger is not None:
-                        seq.request.ledger.stamp(CAUSE_SPEC_ROLLBACK,
-                                                 t_roll)
-                self.telemetry.on_spec(
-                    drafted=drafted, accepted=accepted,
-                    rollback_s=t_roll - t)
-            self.telemetry.on_decode(lanes=len(decoding), tokens=emitted)
-            self.telemetry.on_tokens(emitted, t)
-            if chunk_seq is not None:
-                start = chunk_seq.prefill_pos
-                chunk_seq.prefill_pos = start + c
-                # Ledger chunk boundary: this iteration's span (chunk-
-                # lane wait included) and the cache positions the chunk
-                # wrote. Positions the chunk REwrites (the sequence's
-                # recompute debt from preemptions/crashes) bill to
-                # 'recompute'; first-time writes bill to 'prefill' —
-                # so the token split mirrors the engine's recompute
-                # counters exactly. The wall span takes the chunk's
-                # dominant cause.
-                led = chunk_seq.request.ledger
-                if led is not None:
-                    rec = min(c, chunk_seq.recompute_owed)
-                    chunk_seq.recompute_owed -= rec
-                    # Recovery-attribution share never exceeds the
-                    # remaining debt (prefix-hit credit bookkeeping).
-                    chunk_seq.recovery_owed = min(
-                        chunk_seq.recovery_owed,
-                        chunk_seq.recompute_owed)
-                    if rec:
-                        led.add_tokens(CAUSE_RECOMPUTE, rec)
-                    if c - rec:
-                        led.add_tokens(CAUSE_PREFILL, c - rec)
-                    led.stamp(CAUSE_RECOMPUTE if rec * 2 >= c
-                              else CAUSE_PREFILL, t)
-                if self.trace is not None:
-                    self.trace.complete(
-                        "prefill_chunk", t_step0, t,
+                    # Write positions of this window = tokens already
+                    # cached (prompt + generated minus the uncached last)
+                    # through the last valid draft row.
+                    p = seq.request.prompt.size + len(seq.tokens) - 1
+                    self._ensure_pages(
+                        seq.slot, p + useful_by_slot[seq.slot] + 1)
+                if self.spec_k and decoding:
+                    # Proposal assembly (host); its verification is the
+                    # device step's (serve.verify, below).
+                    trace_lib.record(
+                        "serve.draft", asm_span.t0, time.perf_counter(),
+                        tokens=drafted, slots=len(decoding))
+                c = 0
+                if chunk_seq is not None:
+                    # prefill_tokens == the prompt for a fresh seat; for
+                    # a resumption it carries prompt + emitted-minus-last,
+                    # so the re-prefill rebuilds exactly the cache prefix
+                    # the preemption freed (same positions, same fold_in
+                    # RNG).
+                    pre_toks = chunk_seq.prefill_tokens
+                    n = pre_toks.size
+                    start = chunk_seq.prefill_pos
+                    c = min(self.prefill_chunk, n - start)
+                    self._ensure_pages(chunk_seq.slot, start + c)
+                    cw = self.prefill_chunk
+                    c_tok = np.full((cw,), self.sample_cfg.pad_id,
+                                    np.int32)
+                    c_pos = np.zeros((cw,), np.int32)
+                    c_valid = np.zeros((cw,), bool)
+                    c_tok[:c] = pre_toks[start:start + c]
+                    c_pos[:c] = np.arange(start, start + c)
+                    c_valid[:c] = True
+            with span("serve.device_step", program=program) as dev_span:
+                with span("serve.dispatch",
+                          uploads=10 if chunk_seq is not None else 5):
+                    if chunk_seq is not None:
+                        self._cache, nxt, acc, c_sampled = self._fused(
+                            self.params, self._cache, jnp.asarray(d_tok),
+                            jnp.asarray(d_pos), jnp.asarray(d_valid),
+                            jnp.asarray(self._slot_rng),
+                            jnp.asarray(self._tables), jnp.asarray(c_tok),
+                            jnp.asarray(c_pos), jnp.asarray(c_valid),
+                            jnp.asarray(self._tables[chunk_seq.slot][None]),
+                            jnp.asarray(self._slot_rng[chunk_seq.slot]))
+                    else:
+                        self._cache, nxt, acc = self._decode(
+                            self.params, self._cache, jnp.asarray(d_tok),
+                            jnp.asarray(d_pos), jnp.asarray(d_valid),
+                            jnp.asarray(self._slot_rng),
+                            jnp.asarray(self._tables))
+                with span("serve.token_wait"):
+                    # graftlint: disable=hot-path-transfer -- THE per-iteration sync: tokens must land (docs/SERVING.md)
+                    toks = np.asarray(nxt)
+                    # graftlint: disable=hot-path-transfer -- per-slot accept lengths ride the same iteration sync
+                    accepts = np.asarray(acc)
+            # The tokens' landing time: every ledger stamp, TTFT and
+            # deadline below reads this one clock value.
+            t = dev_span.t1
+            with span("serve.commit"):
+                emitted, accepted = self._apply_accepts(
+                    decoding, toks, accepts, useful_by_slot, t)
+                if self.spec_k:
+                    # Host-side accept/rewind bookkeeping cost, attributed
+                    # explicitly like admission_blocked_s/swap_blocked_s —
+                    # and billed to each decoding request's ledger as
+                    # 'spec_rollback' (the batch shares the wall window).
+                    t_roll = time.perf_counter()
+                    for seq in decoding:
+                        if seq.request.ledger is not None:
+                            seq.request.ledger.stamp(CAUSE_SPEC_ROLLBACK,
+                                                     t_roll)
+                    self.telemetry.on_spec(
+                        drafted=drafted, accepted=accepted,
+                        rollback_s=t_roll - t)
+                    if decoding:
+                        # The batched target dispatch that verified the
+                        # drafts; the per-slot accept marks land in
+                        # _apply_accepts.
+                        trace_lib.record(
+                            "serve.verify", dev_span.t0, t,
+                            drafted=drafted, accepted=accepted)
+                self.telemetry.on_decode(lanes=len(decoding),
+                                         tokens=emitted)
+                self.telemetry.on_tokens(emitted, t)
+                if chunk_seq is not None:
+                    start = chunk_seq.prefill_pos
+                    chunk_seq.prefill_pos = start + c
+                    # Ledger chunk boundary: this iteration's span (chunk-
+                    # lane wait included) and the cache positions the
+                    # chunk wrote. Positions the chunk REwrites (the
+                    # sequence's recompute debt from preemptions/crashes)
+                    # bill to 'recompute'; first-time writes bill to
+                    # 'prefill' — so the token split mirrors the engine's
+                    # recompute counters exactly. The wall span takes the
+                    # chunk's dominant cause.
+                    led = chunk_seq.request.ledger
+                    if led is not None:
+                        rec = min(c, chunk_seq.recompute_owed)
+                        chunk_seq.recompute_owed -= rec
+                        # Recovery-attribution share never exceeds the
+                        # remaining debt (prefix-hit credit bookkeeping).
+                        chunk_seq.recovery_owed = min(
+                            chunk_seq.recovery_owed,
+                            chunk_seq.recompute_owed)
+                        if rec:
+                            led.add_tokens(CAUSE_RECOMPUTE, rec)
+                        if c - rec:
+                            led.add_tokens(CAUSE_PREFILL, c - rec)
+                        led.stamp(CAUSE_RECOMPUTE if rec * 2 >= c
+                                  else CAUSE_PREFILL, t)
+                    trace_lib.record(
+                        "serve.prefill_chunk", dev_span.t0, t,
+                        key=chunk_seq.request.uid,
                         track=f"slot {chunk_seq.slot}",
                         trace=chunk_seq.request.trace_id,
                         # graftlint: disable=hot-path-transfer -- host ints for JSON trace args
-                        uid=chunk_seq.request.uid, start=int(start),
-                        # graftlint: disable=hot-path-transfer -- host int for a JSON trace arg
-                        tokens=int(c))
-                if chunk_seq.prefill_pos == chunk_seq.prefill_tokens.size:
-                    if chunk_seq.tokens:
-                        # Resumed mid-decode: the final chunk's sample
-                        # recomputes the last emitted token bitwise
-                        # (same logits row, same fold_in position) — it
-                        # was already emitted before the preemption, so
-                        # nothing lands; the slot just resumes decoding
-                        # with it as the incoming token.
-                        pass
-                    else:
-                        # Final chunk: its last valid row is the
-                        # request's first token (same RNG fold and
-                        # logits row as a full-prompt prefill).
-                        # graftlint: disable=hot-path-transfer -- the deliberate sync: the chunked-path TTFT measurement point
-                        first = int(np.asarray(c_sampled)[c - 1])
-                        self._note_first_token(chunk_seq, first, t)
-            # KV utilization, host-side only: reserved = pages actually
-            # held by occupied slots (the paged win — compare the legacy
-            # path's active × full budget), written = live cache
-            # positions, both reconstructed without a device read.
-            counted = decoding + ([chunk_seq] if chunk_seq is not None
-                                  else [])
-            reserved = sum(len(self._slot_pages[q.slot]) for q in counted
-                           ) * self.page_size
-            written = sum(q.request.prompt.size + len(q.tokens) - 1
-                          for q in decoding)
-            if chunk_seq is not None:
-                written += chunk_seq.prefill_pos
-            self.telemetry.on_kv(
-                reserved=reserved, written=written, active=len(counted),
-                slots=self.cfg.max_batch,
-                pages_allocated=self.pool.num_allocated,
-                pages_total=self.pool.num_pages)
-            if blocked_t0 is not None:
-                self.telemetry.on_admission_blocked(t - blocked_t0)
-            if self.trace is not None:
-                if self.spec_k and decoding:
-                    # Draft (proposal assembly, host) and verify (the
-                    # batched target dispatch) phases of the iteration;
-                    # the per-slot accept marks land in _apply_accepts.
-                    self.trace.complete("draft", t_step0, t_draft1,
-                                        track="engine", iteration=it,
-                                        tokens=drafted,
-                                        slots=len(decoding))
-                    self.trace.complete("verify", t_draft1, t,
-                                        track="engine", iteration=it,
-                                        drafted=drafted,
-                                        accepted=accepted)
-                self.trace.complete("decode", t_step0, t, track="engine",
-                                    iteration=it, active=len(decoding),
-                                    # graftlint: disable=hot-path-transfer -- host int for a JSON trace arg
-                                    prefill_chunk=int(c))
-                self.trace.counter("active_slots", len(counted))
-                self.trace.counter("kv_written_tokens", written)
-                self.trace.counter("kv_pages_allocated",
-                                   self.pool.num_allocated)
-            finished.extend(self.scheduler.evict_finished(
-                eos, now=t if deadlines else None))
+                        start=int(start), tokens=int(c))
+                    if (chunk_seq.prefill_pos
+                            == chunk_seq.prefill_tokens.size):
+                        if chunk_seq.tokens:
+                            # Resumed mid-decode: the final chunk's sample
+                            # recomputes the last emitted token bitwise
+                            # (same logits row, same fold_in position) —
+                            # it was already emitted before the
+                            # preemption, so nothing lands; the slot just
+                            # resumes decoding with it as the incoming
+                            # token.
+                            pass
+                        else:
+                            # Final chunk: its last valid row is the
+                            # request's first token (same RNG fold and
+                            # logits row as a full-prompt prefill).
+                            # graftlint: disable=hot-path-transfer -- the deliberate sync: the chunked-path TTFT measurement point
+                            first = int(np.asarray(c_sampled)[c - 1])
+                            self._note_first_token(chunk_seq, first, t)
+                # KV utilization, host-side only: reserved = pages
+                # actually held by occupied slots (the paged win — compare
+                # the legacy path's active × full budget), written = live
+                # cache positions, both reconstructed without a device
+                # read.
+                counted = decoding + ([chunk_seq] if chunk_seq is not None
+                                      else [])
+                reserved = sum(len(self._slot_pages[q.slot])
+                               for q in counted) * self.page_size
+                written = sum(q.request.prompt.size + len(q.tokens) - 1
+                              for q in decoding)
+                if chunk_seq is not None:
+                    written += chunk_seq.prefill_pos
+                self.telemetry.on_kv(
+                    reserved=reserved, written=written,
+                    active=len(counted), slots=self.cfg.max_batch,
+                    pages_allocated=self.pool.num_allocated,
+                    pages_total=self.pool.num_pages)
+                if blocked_t0 is not None:
+                    self.telemetry.on_admission_blocked(t - blocked_t0)
+                if self.trace is not None:
+                    self.trace.counter("active_slots", len(counted))
+                    self.trace.counter("kv_written_tokens", written)
+                    self.trace.counter("kv_pages_allocated",
+                                       self.pool.num_allocated)
+                finished.extend(self.scheduler.evict_finished(
+                    eos, now=t if deadlines else None))
 
-        return self._finish_iteration(it, had_work, finished)
+        with span("serve.finish"):
+            return self._finish_iteration(it, had_work, finished)
 
     def _step_legacy(self) -> list[FinishedRequest]:
         it = self._iteration
@@ -1806,7 +1843,6 @@ class Engine:
 
         active_seqs = self.scheduler.active()
         if active_seqs:
-            t_decode = time.perf_counter()
             if self.spec_k:
                 # Verify-window variant: slot routing (write heads,
                 # tokens, drafts) is host-assembled like the paged path;
@@ -1814,7 +1850,6 @@ class Engine:
                 # the host head, which IS the speculative rewind.
                 d_tok, d_pos, d_valid, useful_by_slot, drafted = \
                     self._draft_window(active_seqs)
-                t_draft1 = time.perf_counter()
                 self._cache, nxt, acc = self._decode(
                     self.params, self._cache, jnp.asarray(d_tok),
                     jnp.asarray(d_pos[:, 0]), jnp.asarray(d_valid),
@@ -1837,15 +1872,6 @@ class Engine:
                 self.telemetry.on_decode(lanes=len(active_seqs),
                                          tokens=emitted)
                 self.telemetry.on_tokens(emitted, t)
-                if self.trace is not None:
-                    self.trace.complete("draft", t_decode, t_draft1,
-                                        track="engine", iteration=it,
-                                        tokens=drafted,
-                                        slots=len(active_seqs))
-                    self.trace.complete("verify", t_draft1, t,
-                                        track="engine", iteration=it,
-                                        drafted=drafted,
-                                        accepted=accepted)
             else:
                 mask = self.scheduler.active_mask()
                 self._cache, nxt, self._pos = self._decode(
@@ -1875,9 +1901,6 @@ class Engine:
             if blocked_t0 is not None:
                 self.telemetry.on_admission_blocked(t - blocked_t0)
             if self.trace is not None:
-                self.trace.complete("decode", t_decode, t, track="engine",
-                                    iteration=it,
-                                    active=len(active_seqs))
                 self.trace.counter("active_slots", len(active_seqs))
                 self.trace.counter("kv_written_tokens", written)
             finished.extend(self.scheduler.evict_finished(

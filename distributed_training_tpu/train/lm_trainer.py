@@ -122,6 +122,7 @@ def restore_lm_checkpoint(directory: str, epoch: int, state, layout=None):
 class LMTrainer:
     """Epoch-loop engine for :class:`TransformerLM` on a device mesh."""
 
+    @trace_lib.span("setup.trainer_init")
     def __init__(self, cfg: TrainConfig, mesh=None):
         self.cfg = cfg
         self.coord = Coordinator()
@@ -305,22 +306,23 @@ class LMTrainer:
                 moe_mlp_type=cfg.moe.mlp_type,
                 moe_expert_axis="expert" if expert > 1 else None,
             )
-        self.model = get_model(
-            "transformer_lm",
-            num_classes=lm.vocab_size,
-            dtype=policy.compute_dtype,
-            remat=cfg.remat,
-            seq_axis=AXIS_SEQUENCE if seq > 1 else None,
-            num_layers=lm.num_layers,
-            num_heads=lm.num_heads,
-            hidden_dim=lm.hidden_dim,
-            mlp_ratio=lm.mlp_ratio,
-            max_len=lm.max_len,
-            attn_impl=lm.attn_impl,
-            logits_dtype=parse_logits_dtype(lm.logits_dtype),
-            head_bias=lm.head_bias,
-            **moe_kwargs,
-        )
+        with trace_lib.span("setup.model_init"):
+            self.model = get_model(
+                "transformer_lm",
+                num_classes=lm.vocab_size,
+                dtype=policy.compute_dtype,
+                remat=cfg.remat,
+                seq_axis=AXIS_SEQUENCE if seq > 1 else None,
+                num_layers=lm.num_layers,
+                num_heads=lm.num_heads,
+                hidden_dim=lm.hidden_dim,
+                mlp_ratio=lm.mlp_ratio,
+                max_len=lm.max_len,
+                attn_impl=lm.attn_impl,
+                logits_dtype=parse_logits_dtype(lm.logits_dtype),
+                head_bias=lm.head_bias,
+                **moe_kwargs,
+            )
         self.world_size = data_axis_size(self.mesh)
         self.train_gbs, self.eval_gbs, self.grad_accum = effective_batch_sizes(
             cfg, self.world_size)
@@ -352,54 +354,49 @@ class LMTrainer:
         loss_scale = LossScaleState.create(cfg.precision)
 
         self.rng, init_rng = jax.random.split(jax.random.PRNGKey(cfg.seed))
-        if self.strategy == "pipeline":
-            self.train_step = make_pp_lm_train_step(
-                self.mesh, model=self.model,
-                num_microbatches=self._pp_microbatches,
-                ce_chunk=lm.ce_chunk_size,
-                accuracy_metric=lm.metrics_accuracy,
-                zero_stage=cfg.zero.stage,
-                virtual_stages=lm.virtual_stages,
-                cpu_offload=cfg.zero.cpu_offload,
-                ce_save_probs=lm.ce_save_probs,
-                grad_norm_metric=cfg.observability.grad_norm)
-            plm = self.train_step.pipelined
-            state = TrainState.create(
-                apply_fn=plm.apply_fn, params=plm.init_params(init_rng),
-                tx=self.tx, loss_scale=loss_scale)
+        with trace_lib.span("setup.step_build"):
+            if self.strategy == "pipeline":
+                self.train_step = make_pp_lm_train_step(
+                    self.mesh, model=self.model,
+                    num_microbatches=self._pp_microbatches,
+                    ce_chunk=lm.ce_chunk_size,
+                    accuracy_metric=lm.metrics_accuracy,
+                    zero_stage=cfg.zero.stage,
+                    virtual_stages=lm.virtual_stages,
+                    cpu_offload=cfg.zero.cpu_offload,
+                    ce_save_probs=lm.ce_save_probs,
+                    grad_norm_metric=cfg.observability.grad_norm)
+            else:
+                # The sequence strategy's partial-manual shard_map leaves
+                # `model` automatic, so both builders take the same
+                # arguments; over a model axis of size 1 every TP spec of
+                # the rule table is a no-op shard.
+                make_step = (make_lm_train_step
+                             if self.strategy == "sequence"
+                             else make_tp_lm_train_step)
+                self.train_step = make_step(
+                    self.mesh, model=self.model, ce_chunk=lm.ce_chunk_size,
+                    grad_accum_steps=self.grad_accum,
+                    zero_stage=cfg.zero.stage,
+                    accuracy_metric=lm.metrics_accuracy,
+                    cpu_offload=cfg.zero.cpu_offload,
+                    ce_save_probs=lm.ce_save_probs,
+                    tp_overlap=cfg.tp_overlap and model_par > 1,
+                    grad_norm_metric=cfg.observability.grad_norm)
+        with trace_lib.span("setup.state_init"):
+            if self.strategy == "pipeline":
+                plm = self.train_step.pipelined
+                state = TrainState.create(
+                    apply_fn=plm.apply_fn,
+                    params=plm.init_params(init_rng),
+                    tx=self.tx, loss_scale=loss_scale)
+            else:
+                state = init_train_state(
+                    self.model, init_rng, (1, 8), self.tx,
+                    loss_scale=loss_scale, input_dtype=jnp.int32)
+            # TP rule table (+ ZeRO recruitment over the data axes).
             self.shardings = self.train_step.state_shardings(state)
-        elif self.strategy == "sequence":
-            self.train_step = make_lm_train_step(
-                self.mesh, model=self.model, ce_chunk=lm.ce_chunk_size,
-                grad_accum_steps=self.grad_accum, zero_stage=cfg.zero.stage,
-                accuracy_metric=lm.metrics_accuracy,
-                cpu_offload=cfg.zero.cpu_offload,
-                ce_save_probs=lm.ce_save_probs,
-                tp_overlap=cfg.tp_overlap and model_par > 1,
-                grad_norm_metric=cfg.observability.grad_norm)
-            state = init_train_state(
-                self.model, init_rng, (1, 8), self.tx,
-                loss_scale=loss_scale, input_dtype=jnp.int32)
-            # TP rule table (+ ZeRO recruitment over data × sequence): over
-            # a model axis of size 1 every TP spec is a no-op shard; with
-            # model > 1 the weights shard megatron-style and the sequence
-            # step's partial-manual shard_map leaves them automatic.
-            self.shardings = self.train_step.state_shardings(state)
-        else:
-            self.train_step = make_tp_lm_train_step(
-                self.mesh, model=self.model, zero_stage=cfg.zero.stage,
-                grad_accum_steps=self.grad_accum,
-                ce_chunk=lm.ce_chunk_size,
-                accuracy_metric=lm.metrics_accuracy,
-                cpu_offload=cfg.zero.cpu_offload,
-                ce_save_probs=lm.ce_save_probs,
-                tp_overlap=cfg.tp_overlap and model_par > 1,
-                grad_norm_metric=cfg.observability.grad_norm)
-            state = init_train_state(
-                self.model, init_rng, (1, 8), self.tx,
-                loss_scale=loss_scale, input_dtype=jnp.int32)
-            self.shardings = self.train_step.state_shardings(state)
-        self.state = place_state(state, self.shardings)
+            self.state = place_state(state, self.shardings)
 
         self.batch_shardings = self.train_step.batch_shardings
 
@@ -607,8 +604,13 @@ class LMTrainer:
         bar = EpochBar(len(loader), epoch, self.cfg.num_epochs,
                        self.coord.is_master())
         gbatch = None
-        for gbatch in self._batches(loader):
-            with self.clock.phase("step"):
+        # Spans of one optimizer step share its number as their key: the
+        # main thread's wait on the prefetcher, the dispatch, the fetch.
+        for gbatch in trace_lib.spanned(
+                self._batches(loader), "train.batch_wait",
+                key=lambda: self._global_step + 1):
+            with self.clock.phase("step"), trace_lib.span(
+                    "train.dispatch", key=self._global_step + 1):
                 self.rng, step_rng = jax.random.split(self.rng)
                 self.state, metrics = self.train_step(
                     self.state, gbatch, step_rng)

@@ -30,6 +30,8 @@ from typing import Any
 
 import jax
 
+from distributed_training_tpu.observability import trace as trace_lib
+
 try:
     from tqdm import tqdm
 except ImportError:  # pragma: no cover
@@ -65,7 +67,10 @@ class MetricMeter:
         # unfetched (their buffers were never copied to host).
         step, metrics = self._pending[-1]
         self._pending.clear()
-        self.last = {k: float(jax.device_get(v)) for k, v in metrics.items()}
+        # The one place a training loop waits for the device by design.
+        with trace_lib.span("train.metrics_fetch", key=step):
+            self.last = {k: float(jax.device_get(v))
+                         for k, v in metrics.items()}
         self.last["step"] = step
         return self.last
 

@@ -6,7 +6,6 @@ DeepSpeed's ``wall_clock_breakdown`` flag turned off
 TPU-native equivalents:
 
 - ``jax.profiler`` traces (TensorBoard trace viewer) via :func:`trace`;
-- ``jax.named_scope`` as the NVTX-range analogue (re-exported);
 - :class:`WallClock` — a working ``wall_clock_breakdown``: wall-time split
   into data / step / logging phases per epoch.
 """
@@ -14,12 +13,11 @@ TPU-native equivalents:
 from __future__ import annotations
 
 import contextlib
-import time
 from collections import defaultdict
 
 import jax
 
-named_scope = jax.named_scope
+from distributed_training_tpu.observability import trace as trace_lib
 
 
 @contextlib.contextmanager
@@ -44,12 +42,12 @@ class WallClock:
     wall-time — which is what lets the flight recorder's goodput read
     them as fractions that sum to 1 (``observability/flight_recorder.py``).
 
-    With a ``trace`` session attached, every phase additionally emits one
-    complete span (entry → exit, INCLUSIVE of nested phases — the
-    timeline wants the enclosing extent; exclusivity is the totals'
-    concern) onto ``track``, which is how both trainers get their
-    step/eval/ckpt Perfetto tracks without touching a single phase call
-    site (``observability/trace.py``).
+    Every phase is also one ``train.<name>`` span of the program
+    (``observability/trace.py::span``: entry → exit, INCLUSIVE of nested
+    phases — the timeline wants the enclosing extent; exclusivity is the
+    totals' concern), forwarded onto ``track`` of the ``trace`` session
+    when one is attached — which is how both trainers get their
+    step/eval/ckpt spans without touching a single phase call site.
     """
 
     def __init__(self, enabled: bool = False, *, trace=None,
@@ -61,7 +59,7 @@ class WallClock:
         # Run-lifetime totals: ``report()`` clears ``totals`` per epoch,
         # but the flight recorder's goodput wants the whole run.
         self.lifetime: dict[str, float] = defaultdict(float)
-        self._stack: list[list] = []  # [name, segment_start, entry] frames
+        self._stack: list[list] = []  # [name, segment_start] frames
 
     def _accrue(self, name: str, dt: float) -> None:
         self.totals[name] += dt
@@ -83,22 +81,22 @@ class WallClock:
         if not self.enabled:
             yield
             return
-        now = time.perf_counter()
-        if self._stack:  # pause the outer phase
-            outer = self._stack[-1]
-            self._accrue(outer[0], now - outer[1])
-        self._stack.append([name, now, now])
+        sp = trace_lib.span("train." + name, session=self.trace,
+                            track=self.track)
         try:
-            yield
+            with sp:
+                now = sp.t0
+                if self._stack:  # pause the outer phase
+                    outer = self._stack[-1]
+                    self._accrue(outer[0], now - outer[1])
+                self._stack.append([name, now])
+                yield
         finally:
-            now = time.perf_counter()
+            now = sp.t1
             frame = self._stack.pop()
             self._accrue(frame[0], now - frame[1])
             if self._stack:  # resume the outer phase's segment
                 self._stack[-1][1] = now
-            if self.trace is not None:
-                self.trace.complete(frame[0], frame[2], now,
-                                    track=self.track)
 
     def snapshot(self) -> dict[str, float]:
         """Run-lifetime phase totals, never cleared (the flight

@@ -118,15 +118,15 @@ class TestWallClockTrace:
         obj = load_trace(tr.save(str(tmp_path / "t.json")))
         spans = {e["name"]: e for e in obj["traceEvents"]
                  if e["ph"] == "X"}
-        assert set(spans) == {"step", "data"}
+        assert set(spans) == {"train.step", "train.data"}
+        step, data = spans["train.step"], spans["train.data"]
         # Trace spans are INCLUSIVE (enclosing extent), even though the
         # totals attribute exclusively: step's span contains data's.
-        assert spans["step"]["ts"] <= spans["data"]["ts"]
-        assert (spans["step"]["ts"] + spans["step"]["dur"]
-                >= spans["data"]["ts"] + spans["data"]["dur"])
+        assert step["ts"] <= data["ts"]
+        assert step["ts"] + step["dur"] >= data["ts"] + data["dur"]
         # The TOTALS still partition (exclusive attribution unchanged).
         assert clock.lifetime["step"] + clock.lifetime["data"] \
-            == pytest.approx(spans["step"]["dur"] / 1e6, rel=0.2)
+            == pytest.approx(step["dur"] / 1e6, rel=0.2)
 
     def test_disabled_clock_emits_nothing(self):
         from distributed_training_tpu.utils.profiling import WallClock
@@ -173,14 +173,17 @@ class TestServingTrace:
         names = collections.Counter(
             e["name"] for e in events if e["ph"] != "M")
         # Every request leaves a full lifecycle on its slot track.
-        assert names["queued"] == 4
-        assert names["prefill"] == 4
+        assert names["serve.queued"] == 4
+        assert names["serve.prefill"] == 4
         assert names["first_token"] == 4
-        assert names["decode"] >= 4  # per-slot + per-iteration spans
+        assert names["decode"] == 4  # first → last token, per slot
+        # The engine track: one span per iteration, its phases inside.
+        assert names["serve.iteration"] >= 4
+        assert names["serve.device_step"] == names["serve.token_wait"] > 0
         # Chunked prefill (paged engine default): each prompt fits one
-        # chunk here, so exactly one prefill_chunk span per request
-        # rides a slot track — the prefill/decode interleaving view.
-        assert names["prefill_chunk"] == 4
+        # chunk here, so exactly one chunk span per request rides a
+        # slot track — the prefill/decode interleaving view.
+        assert names["serve.prefill_chunk"] == 4
         assert names["request.arrival"] == 4
         assert names["finish:length"] == 4
         tracks = {e["args"]["name"] for e in events
@@ -311,7 +314,8 @@ class TestTrainerTrace:
         assert_valid_trace(obj)
         names = collections.Counter(
             e["name"] for e in obj["traceEvents"] if e["ph"] != "M")
-        assert names["step"] == 6
+        assert names["train.step"] == 6
+        assert names["train.dispatch"] == 6
         assert names["ckpt.persist"] == 1  # the writer thread's track
         assert names["chaos.slow_step"] == 1
         tracks = {e["args"]["name"] for e in obj["traceEvents"]

@@ -89,6 +89,9 @@ def summarize(snap: dict) -> dict:
     # single-process dump lacks the section and must render unchanged.
     if snap.get("fleet"):
         out["fleet"] = snap["fleet"]
+    # The program's own spans (observability/flight_recorder.py::host_span_stats).
+    if snap.get("host_spans"):
+        out["host_spans"] = snap["host_spans"]
     return out
 
 
@@ -314,6 +317,12 @@ def render(summary: dict) -> str:
             body = "  ".join(f"{k} {v}" for k, v in sorted(faults.items())
                              if v)
             add(f"    chaos faults: {body or 'none fired'}")
+    spans = summary.get("host_spans")
+    if spans:
+        add("  host spans (ms):  count     p50     p95     max")
+        for name, row in spans.items():
+            add(f"    {name:<22} {row['count']:>6} {row['p50_ms']:>7.2f} "
+                f"{row['p95_ms']:>7.2f} {row['max_ms']:>7.2f}")
     if summary["anomalies"]:
         add("  ANOMALIES:")
         for a in summary["anomalies"]:
