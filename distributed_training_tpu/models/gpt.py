@@ -397,7 +397,7 @@ def init_decode_cache(model: "TransformerLM", params: Any,
     freed slots without ever tracing a throwaway forward.
 
     Paged layout (``kv_page_size`` set): per block, the batch-free flat
-    pools ``key_pages``/``value_pages`` [kv_pages * kv_page_size, H, hd]
+    pools ``key_pages``/``value_pages`` [kv_pages * kv_page_size, H·hd]
     shared by every decode slot — routing state (page tables, write
     positions) is per-call :class:`~distributed_training_tpu.parallel.
     ring_attention.PagedKV` input, not cache state, so the same pool

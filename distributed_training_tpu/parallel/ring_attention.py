@@ -294,6 +294,58 @@ class _OutProj(nn.Module):
         return y + bias.astype(self.dtype)
 
 
+def paged_formulation(t_in: int, num_heads: int, head_dim: int,
+                      page_size: int, dtype, kv_dtype: str | None) -> str:
+    """Which way a paged decode call of these shapes attends: ``"kernel"``
+    (``ops/paged_attention.py``: live pages fetched where they lie) or
+    ``"gather"`` (every row's whole table gathered). Shapes and dtypes
+    only — no knob, no backend. The int8 pool dequantizes in its gather;
+    a wide window (the prefill chunk) or a page smaller than a tile is
+    outside what the kernel serves."""
+    from distributed_training_tpu.ops.paged_attention import kernel_fits
+
+    if kv_dtype is None and kernel_fits(t_in, num_heads, head_dim,
+                                        page_size, dtype):
+        return "kernel"
+    return "gather"
+
+
+def paged_gather_attention(q, k_all, v_all, table, positions, *,
+                           page_size: int, scales=None):
+    """The gather formulation of paged attention, and the plain oracle of
+    the kernel: q [B, T_in, H, hd] against pools [pool_rows, H·hd].
+
+    Static shapes: row b reads its table's pages in logical order —
+    positions 0..L-1 exactly as the contiguous cache lays them out
+    (L = pages_per_slot × page_size; unallocated logical pages read the
+    null page) — and the global-position causal mask hides everything
+    past the query along with the future. ``scales`` (int8 pools): the
+    per-row per-head (key, value) scales [pool_rows, H], applied in the
+    gather — dequantization inside the same compiled program as the
+    attention, so the compiled-program inventory grows by zero.
+    """
+    b, _, num_heads, head_dim = q.shape
+    l_all = table.shape[1] * page_size
+    gather_idx = (table[:, :, None] * page_size
+                  + jnp.arange(page_size)[None, None, :]).reshape(b, l_all)
+    heads = (b, l_all, num_heads, head_dim)
+    kg = k_all[gather_idx].reshape(heads)  # [B, L, H, hd]
+    vg = v_all[gather_idx].reshape(heads)
+    if scales is not None:
+        kg = kg.astype(jnp.float32) * scales[0][gather_idx][..., None]
+        vg = vg.astype(jnp.float32) * scales[1][gather_idx][..., None]
+    qh, kh, vh = (jnp.swapaxes(t, -3, -2) for t in (q, kg, vg))
+    scale = 1.0 / jnp.sqrt(jnp.asarray(head_dim, jnp.float32))
+    s = jnp.einsum("...qd,...kd->...qk", qh.astype(jnp.float32),
+                   kh.astype(jnp.float32)) * scale
+    kpos = jnp.arange(l_all)
+    s = jnp.where(kpos[None, None, None, :] > positions[:, None, :, None],
+                  -jnp.inf, s)
+    p = jax.nn.softmax(s, axis=-1).astype(vh.dtype)
+    out = jnp.einsum("...qk,...kd->...qd", p, vh)
+    return jnp.swapaxes(out, -3, -2)  # back to [B, T, H, hd]
+
+
 class RingSelfAttention(nn.Module):
     """Multi-head self-attention with ring-parallel sequence sharding.
 
@@ -386,28 +438,51 @@ class RingSelfAttention(nn.Module):
         """Paged-pool cached-KV attention (serving engine's decode path).
 
         Shapes: q/k/v [B, T_in, H, hd]; the cache collection holds one
-        flat pool per K and V — [kv_pages * kv_page_size, H, hd], page 0
-        being the reserved null page. Each incoming token scatters its
-        K/V at ``table[b, pos // ps] * ps + pos % ps`` (null page when
-        ``valid`` is False), then every query row gathers its OWN row's
-        page table back into a contiguous-looking [L, H, hd] view
-        (L = pages_per_slot × ps) and attends with the same global-
-        position causal mask the contiguous path uses. Row arithmetic is
-        identical to :meth:`_decode_attend` — gathered entries for
+        flat pool per K and V — [kv_pages * kv_page_size, H·hd], a row
+        holding every head of one token, page 0 being the reserved null
+        page. (Two dimensions, not [rows, H, hd]: the device lays a
+        [rows, 20, 64] array out with ROWS minor-most, so every use of
+        it as rows began and ended with a relayout of the whole pool;
+        [rows, H·hd] is row-major on the device as it is here, and a page
+        is one contiguous tile.) Each incoming token scatters its K/V at
+        ``table[b, pos // ps] * ps + pos % ps`` (null page when ``valid``
+        is False) — in place: the pool that enters is the pool that
+        leaves. Then every query row attends keys ``0..pos`` of its OWN
+        row's page table, the rows this same call wrote included, in one
+        of two formulations, chosen from the call's shapes and dtypes
+        alone (:func:`paged_formulation`):
+
+        - **kernel** — a narrow window (the decode lane's one row, a
+          speculative verify window) on a pool in the compute dtype with
+          tile-sized pages: the Pallas kernel fetches each slot's live
+          pages from the pool by page index and nothing else.
+        - **gather** — the prefill chunk, the int8 pool, pages smaller
+          than a tile: every row gathers its table back into a
+          contiguous-looking [L, H, hd] view (L = pages_per_slot × ps;
+          for the chunk lane that is one slot's budget) and attends with
+          the same global-position causal mask the contiguous path uses.
+          The plain oracle the kernel is tested against.
+
+        Row arithmetic follows :meth:`_decode_attend` — entries for
         written positions ARE the contiguous cache values, and everything
         past the query position (unwritten pages, stale freed pages, the
-        null page) is masked to -inf exactly like the contiguous tail —
-        so greedy outputs stay token-identical to the sequential
-        ``Generator`` (pinned by tests/test_serving.py).
+        null page) is masked exactly like the contiguous tail — so
+        greedy outputs stay token-identical to the sequential
+        ``Generator`` (pinned by tests/test_serving.py). A position past
+        the table poisons THAT row with NaN in both.
 
         The engine's speculative verify window rides this same
         generality: ``T_in = spec_k + 1`` rows per slot (incoming token
-        + drafts), scatter-before-gather meaning each draft row attends
+        + drafts), scatter-before-read meaning each draft row attends
         the rows before it in the SAME call — which is what lets a
         rejected draft suffix be overwritten by the next window before
         any valid query can see it (tests/test_speculative.py pins the
         resulting bitwise oracle).
         """
+        from distributed_training_tpu.ops.paged_attention import (
+            paged_attention,
+        )
+
         b, t_in = q.shape[0], q.shape[1]
         if self.kv_pages is None:
             raise ValueError("paged decode requires kv_pages (pool size)")
@@ -417,7 +492,8 @@ class RingSelfAttention(nn.Module):
         quant = self.kv_dtype == "int8"
         ps = int(self.kv_page_size)
         pool_rows = int(self.kv_pages) * ps
-        shape = (pool_rows, self.num_heads, head_dim)
+        width = self.num_heads * head_dim
+        shape = (pool_rows, width)
         ck = self.variable("cache", "key_pages", jnp.zeros, shape,
                            jnp.int8 if quant else k.dtype)
         cv = self.variable("cache", "value_pages", jnp.zeros, shape,
@@ -455,60 +531,36 @@ class RingSelfAttention(nn.Module):
                               -127, 127).astype(jnp.int8)
                 return qr, scl
 
-            kq, k_scl = _quantize_rows(k_rows)
-            vq, v_scl = _quantize_rows(v_rows)
-            k_all = ck.value.at[write_idx].set(kq)
-            v_all = cv.value.at[write_idx].set(vq)
+            k_rows, k_scl = _quantize_rows(k_rows)
+            v_rows, v_scl = _quantize_rows(v_rows)
             ks_all = cks.value.at[write_idx].set(k_scl)
             vs_all = cvs.value.at[write_idx].set(v_scl)
             if not self.is_initializing():
-                ck.value, cv.value = k_all, v_all
                 cks.value, cvs.value = ks_all, vs_all
-        else:
-            k_all = ck.value.at[write_idx].set(k_rows)
-            v_all = cv.value.at[write_idx].set(v_rows)
-            if not self.is_initializing():
-                ck.value, cv.value = k_all, v_all
+        k_all = ck.value.at[write_idx].set(k_rows.reshape(-1, width))
+        v_all = cv.value.at[write_idx].set(v_rows.reshape(-1, width))
+        if not self.is_initializing():
+            ck.value, cv.value = k_all, v_all
 
-        # Static-shape gather: row b reads its table's pages in logical
-        # order — positions 0..L-1 exactly as the contiguous cache lays
-        # them out (unallocated logical pages read the null page; the
-        # causal mask below hides them along with the future).
-        l_all = table.shape[1] * ps
-        gather_idx = (table[:, :, None] * ps
-                      + jnp.arange(ps)[None, None, :]).reshape(b, l_all)
-        if quant:
-            # Dequantize-in-gather: int8 rows × their per-row scales,
-            # inside the same compiled program as the attention —
-            # compiled-program inventory grows by zero.
-            kg = (k_all[gather_idx].astype(jnp.float32)
-                  * ks_all[gather_idx][..., None])  # [B, L, H, hd]
-            vg = (v_all[gather_idx].astype(jnp.float32)
-                  * vs_all[gather_idx][..., None])
+        if paged_formulation(t_in, self.num_heads, head_dim, ps, k.dtype,
+                             self.kv_dtype) == "kernel":
+            out = paged_attention(
+                q.reshape(b, t_in, width), k_all, v_all, table, positions,
+                valid, num_heads=self.num_heads, page_size=ps)
+            out = out.reshape(q.shape)
         else:
-            kg = k_all[gather_idx]  # [B, L, H, hd]
-            vg = v_all[gather_idx]
-        qh = jnp.swapaxes(q, -3, -2)               # [B, H, T_in, hd]
-        kh, vh = (jnp.swapaxes(t, -3, -2) for t in (kg, vg))
-        scale = 1.0 / jnp.sqrt(jnp.asarray(head_dim, jnp.float32))
-        s = jnp.einsum("...qd,...kd->...qk", qh.astype(jnp.float32),
-                       kh.astype(jnp.float32)) * scale
-        qpos = positions                            # [B, T_in]
-        kpos = jnp.arange(l_all)
-        s = jnp.where(kpos[None, None, None, :] > qpos[:, None, :, None],
-                      -jnp.inf, s)
+            out = paged_gather_attention(
+                q, k_all, v_all, table, positions, page_size=ps,
+                scales=(ks_all, vs_all) if quant else None)
+            # Dequantized math ran in fp32; hand back the compute dtype
+            # the contiguous path would have produced.
+            out = out.astype(v.dtype)
         # Per-ROW overflow poison (the contiguous path's guard, scoped to
         # the offending query so a padded chunk row can't poison real
         # ones): a write position past the page table corrupts whatever
         # page the clamped table gather aliased, so that row is wrong.
-        s = jnp.where((qpos >= l_all)[:, None, :, None], jnp.nan, s)
-        p = jax.nn.softmax(s, axis=-1).astype(vh.dtype)
-        out = jnp.einsum("...qk,...kd->...qd", p, vh)
-        if quant:
-            # Dequantized math ran in fp32; hand back the compute dtype
-            # the contiguous path would have produced.
-            out = out.astype(v.dtype)
-        return jnp.swapaxes(out, -3, -2)  # back to [B, T, H, hd]
+        overflow = positions >= table.shape[1] * ps
+        return jnp.where(overflow[:, :, None, None], jnp.nan, out)
 
     @nn.compact
     def __call__(self, x, deterministic: bool = True, decode: bool = False,

@@ -38,9 +38,10 @@ Shared discipline either way — masks, never shapes:
   retraces.
 - **Lane independence = bitwise determinism.** A slot's row arithmetic
   is identical regardless of which other requests share the batch
-  (rows of every position-wise op and of the per-row paged gather are
-  independent), and sampling RNG is ``fold_in(fold_in(seed, uid),
-  position)`` — a pure function of the request and position. A
+  (rows of every position-wise op and of the per-row paged attention —
+  kernel or gather — are independent), and sampling RNG is
+  ``fold_in(fold_in(seed, uid), position)`` — a pure function of the
+  request and position. A
   request's tokens are therefore bitwise independent of batch
   composition AND of the paging/chunking configuration, and greedy
   decode is token-identical to the sequential ``Generator`` (pinned by
@@ -108,7 +109,10 @@ from distributed_training_tpu.inference.sampler import (
 )
 from distributed_training_tpu.models.gpt import init_decode_cache
 from distributed_training_tpu.observability import trace as trace_lib
-from distributed_training_tpu.parallel.ring_attention import PagedKV
+from distributed_training_tpu.parallel.ring_attention import (
+    PagedKV,
+    paged_formulation,
+)
 from distributed_training_tpu.resilience.errors import SwapError
 from distributed_training_tpu.serving.alerts import (
     AlertEngine,
@@ -416,13 +420,19 @@ class Engine:
         self._cancel_uids: set[int] = set()
 
         # Donation keeps one cache resident instead of two per decode
-        # step; the CPU backend can't donate (it would only warn noisily).
+        # step — and on the paged path lets every layer write its new
+        # rows into the donated pool in place (the pool is held row-major,
+        # [rows, H·hd], so no relayout stands between the buffer and the
+        # scatter; ring_attention._paged_decode_attend). The CPU backend
+        # can't donate (it would only warn noisily).
         donate = jax.default_backend() != "cpu"
         if self.paged:
             # Device state: ONLY the page pool (batch-free). Slot
             # routing (page tables, write heads, last tokens, RNGs) is
             # host-side numpy, shipped as tiny step inputs — so page
-            # allocation and slot membership never touch compiled code.
+            # allocation and slot membership never touch compiled code,
+            # and how much of each slot's table is live is data the
+            # decode lane's attention kernel reads, not a shape.
             with trace_lib.span("setup.cache_alloc"):
                 self._cache = init_decode_cache(self.model, params,
                                                 batch_size=1)
@@ -440,7 +450,20 @@ class Engine:
             # decide what enters the trie.
             self._slot_shared = [0] * s
             self._slot_seq: list[ActiveSequence | None] = [None] * s
-            with trace_lib.span("setup.program_build"):
+            with trace_lib.span("setup.program_build") as build_span:
+                # Which attention formulation each lane's shapes select
+                # (ring_attention.paged_formulation — the model decides
+                # by the same call when the programs trace).
+                m = self.model
+                self.lane_formulation = {
+                    lane: paged_formulation(
+                        t_in, m.num_heads, m.hidden_dim // m.num_heads,
+                        self.page_size, m.dtype, cfg.kv_dtype)
+                    for lane, t_in in (("decode", self.spec_k + 1),
+                                       ("chunk", self.prefill_chunk))}
+                build_span.attrs.update(
+                    decode_lane=self.lane_formulation["decode"],
+                    chunk_lane=self.lane_formulation["chunk"])
                 self._fused = jax.jit(
                     self._fused_impl,
                     donate_argnums=(1,) if donate else ())
@@ -1677,6 +1700,20 @@ class Engine:
                     c_tok[:c] = pre_toks[start:start + c]
                     c_pos[:c] = np.arange(start, start + c)
                     c_valid[:c] = True
+            # What this iteration's attention has to read: the pages the
+            # live slots' positions cover (each decoding slot through its
+            # window's last valid row, the chunk's slot through the
+            # chunk's last row), beside the fixed budget the gather
+            # formulation reads whatever is live.
+            rows_live = [q.request.prompt.size + len(q.tokens)
+                         + useful_by_slot[q.slot] for q in decoding]
+            if chunk_seq is not None:
+                rows_live.append(chunk_seq.prefill_pos + c)
+            pages_live = sum(pages_for(r, self.page_size)
+                             for r in rows_live)
+            pages_budget = self.cfg.max_batch * self.pages_per_slot
+            it_span.attrs.update(kv_pages_live=pages_live,
+                                 kv_pages_budget=pages_budget)
             with span("serve.device_step", program=program) as dev_span:
                 with span("serve.dispatch",
                           uploads=10 if chunk_seq is not None else 5):
@@ -1797,7 +1834,8 @@ class Engine:
                     reserved=reserved, written=written,
                     active=len(counted), slots=self.cfg.max_batch,
                     pages_allocated=self.pool.num_allocated,
-                    pages_total=self.pool.num_pages)
+                    pages_total=self.pool.num_pages,
+                    pages_live=pages_live, pages_budget=pages_budget)
                 if blocked_t0 is not None:
                     self.telemetry.on_admission_blocked(t - blocked_t0)
                 if self.trace is not None:

@@ -189,6 +189,10 @@ class ServeTelemetry:
         # headroom view the allocator adds on top of reserved/written.
         self.page_iters_allocated = 0
         self.page_iters_total = 0
+        # What attention had to read (pages the live positions cover) of
+        # what a whole-table read moves (slots × pages per slot).
+        self.page_iters_live = 0
+        self.page_iters_budget = 0
         self.admission_blocked_s = 0.0
         # Live weight hot-swap accounting (serving/hotswap.py): applied
         # and rejected swap attempts, and the wall-time swap barriers
@@ -277,15 +281,20 @@ class ServeTelemetry:
 
     def on_kv(self, *, reserved: int, written: int, active: int,
               slots: int, pages_allocated: int | None = None,
-              pages_total: int | None = None) -> None:
+              pages_total: int | None = None,
+              pages_live: int | None = None,
+              pages_budget: int | None = None) -> None:
         """One decode iteration's KV-cache occupancy: ``reserved`` =
         KV positions actually HELD for occupied slots (allocated pages ×
         page size under the paged allocator; active slots × full budget
         on the legacy path), ``written`` = Σ live cache write heads
         (prompt + generated positions actually holding K/V). The paged
         engine also reports pool occupancy (``pages_allocated`` of
-        ``pages_total``). All host-side integers the engine already
-        tracks — no device read."""
+        ``pages_total``), and what the iteration's attention had to
+        read: ``pages_live`` = pages the live slots' positions cover, of
+        the ``pages_budget`` = slots × pages per slot that a read of
+        every slot's whole table moves. All host-side integers the
+        engine already tracks — no device read."""
         self.kv_reserved_tokens += int(reserved)
         self.kv_written_tokens += int(written)
         self.slot_iters_active += int(active)
@@ -293,6 +302,9 @@ class ServeTelemetry:
         if pages_allocated is not None and pages_total is not None:
             self.page_iters_allocated += int(pages_allocated)
             self.page_iters_total += int(pages_total)
+        if pages_live is not None and pages_budget is not None:
+            self.page_iters_live += int(pages_live)
+            self.page_iters_budget += int(pages_budget)
 
     def on_admitted(self, queue_wait_ms: float,
                     prefill_ms: float) -> None:
@@ -611,6 +623,13 @@ class ServeTelemetry:
                 self.page_iters_allocated / self.page_iters_total
                 if self.page_iters_total else 0.0),
             "kv_pages_allocated_iters": int(self.page_iters_allocated),
+            # Paged attention's read (0.0 legacy): pages the live slots'
+            # positions covered ÷ slots × pages per slot, over the run —
+            # the share of the whole-table read that was live.
+            "kv_pages_live_iters": int(self.page_iters_live),
+            "kv_read_share": (
+                self.page_iters_live / self.page_iters_budget
+                if self.page_iters_budget else 0.0),
             # Prefix cache (serving/prefix_cache.py): reuse economics —
             # hit_tokens is prefill compute SAVED in cache positions
             # (deterministic under --virtual-dt, bench-gated), the page

@@ -155,6 +155,11 @@ def render(summary: dict) -> str:
                 f"{srv['page_pool_occupancy_mean']:.1%}  "
                 f"({srv.get('kv_pages_allocated_iters', 0)} "
                 f"page-iters allocated)")
+        # What paged attention had to read: live pages of the budget.
+        if srv.get("kv_read_share"):
+            add(f"    kv read: {srv['kv_read_share']:.1%} of slots x "
+                f"pages-per-slot was live  "
+                f"({srv.get('kv_pages_live_iters', 0)} page-iters)")
         # Radix-tree prefix cache (serving/prefix_cache.py): reuse
         # economics — prefill compute saved, trie page churn/residency.
         if (srv.get("prefix_cache_hit_requests")
