@@ -11,12 +11,11 @@ slots are of mixed age; its requests are not counted.
 
 from __future__ import annotations
 
-import importlib
 import time
 
 import numpy as np
 
-from benchmark import servestats, tracing, trafficgen, weights
+from benchmark import servestats, tracing, trafficgen, weights, workmodel
 from benchmark.tracereduce import WINDOW_SPAN
 
 
@@ -91,7 +90,7 @@ class Session:
             t1 = time.perf_counter()
         it = {"t0": t0, "t1": t1, "landed": self._landed, "live": live,
               "queued": len(self.engine.queue), "context_rows": 0,
-              "prompt_flops_tokens": []}
+              "contexts": [], "prompt_flops_tokens": []}
         for uid, n in self._landed.items():
             before = self.generated.get(uid, 0)
             p_len = int(self.requests[uid]["prompt"].size)
@@ -100,8 +99,10 @@ class Session:
                 n_decoded = n - 1
             else:
                 n_decoded = n
-            if n_decoded:
-                it["context_rows"] += p_len + max(before, 1)
+            if n_decoded:     # a decoding slot: the rows its step attends
+                live = p_len + max(before, 1)
+                it["contexts"].append(live)
+                it["context_rows"] += live
             self.generated[uid] = before + n
         self.iterations.append(it)
         return it
@@ -114,21 +115,11 @@ def build_engine(cfg: dict, spec: dict, seed: int,
     import jax.numpy as jnp
 
     from distributed_training_tpu.config import ServeConfig
-    from distributed_training_tpu.models import get_model
     from distributed_training_tpu.serving.engine import Engine
 
-    ref = importlib.import_module(f"benchmark.reference.{cfg['reference']}")
-    shapes = ref.param_shapes(cfg)
+    shapes = workmodel.reference(cfg).param_shapes(cfg)
     m = spec["model"]
-    dtypes = {"bf16": jnp.bfloat16, "fp32": jnp.float32}
-    model = get_model(
-        "transformer_lm",
-        num_classes=int(cfg["assumed"]["padded_vocab_size"]),
-        dtype=dtypes[m["dtype"]], num_layers=int(cfg["n_layer"]),
-        num_heads=int(cfg["n_head"]), hidden_dim=int(cfg["n_embd"]),
-        max_len=int(cfg["n_positions"]),
-        head_bias=bool(cfg["assumed"]["head_bias"]),
-        logits_dtype=dtypes[m["logits_dtype"]])
+    model = workmodel.family(cfg).build_model(cfg, m)
     theirs = jax.eval_shape(
         lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
     theirs = {k: tuple(v.shape)
@@ -156,7 +147,8 @@ def setup(ctx: dict) -> Session:
 def prepare(engine, cfg: dict, spec: dict, seed: int,
             mark=lambda name: None) -> Session:
     """Warm the engine's programs and run the traffic's pre-roll."""
-    stream = trafficgen.RequestStream(spec, seed, int(cfg["vocab_size"]))
+    stream = trafficgen.RequestStream(spec, seed,
+                                      workmodel.family(cfg).token_ids(cfg))
     s = Session(engine, stream, spec)
 
     # Warm both compiled programs (fused chunk+decode, decode-only) at
@@ -286,44 +278,78 @@ def release(ctx: dict, s: Session) -> dict:
             "finished": len(done)}
 
 
+# Positions whose logits the comparison holds at once: the most it keeps on
+# the device beside the weights is one request's states and ``CHECK_BLOCK x
+# rows`` logits (twice that under the control), whatever the number of
+# requests, their length or the vocabulary.
+CHECK_BLOCK = 512
+
+
+def padded_length(n: int, longest: int) -> int:
+    """One of a few fixed lengths (64, 128, 256, ... and the traffic's
+    longest sequence), so that the reference compiles a few times and not
+    once a request."""
+    length = 64
+    while length < n:
+        length *= 2
+    return min(length, longest)
+
+
 def reference_gaps(cfg: dict, seed: int, spec: dict, sample: list,
-                   lowp=None):
+                   lowp=None, block: int = CHECK_BLOCK):
     """For each served token of each sampled request: how far its logit
     lies below the reference's best at that position. The reference runs
-    once over prompt + served tokens, in float32 at ``highest``, on the
-    weights the seed gives (rounded to the type they are served in).
+    once over each request's prompt + served tokens, one request at a time
+    at its own (padded) length, in float32 at ``highest``, on the weights
+    the seed gives (rounded to the type they are served in); its output
+    head is applied to ``block`` positions at a time and each block's
+    logits are reduced to the two numbers a position needs.
 
     With ``lowp`` (the control) it returns instead the gap of the token
     that the lower-precision reference puts first at those positions."""
     import jax
     import jax.numpy as jnp
 
-    ref = importlib.import_module(f"benchmark.reference.{cfg['reference']}")
-    n = int(spec["check"]["requests"])
-    width = int(spec["prompt_tokens"]["max"]) \
+    ref = workmodel.reference(cfg)
+    longest = int(spec["prompt_tokens"]["max"]) \
         + int(spec["output_tokens"]["max"])
-    toks = np.zeros((n, width), np.int32)
-    mask = np.zeros((n, width), bool)     # positions that predict a served
-    for i, (prompt, served) in enumerate(sample):
-        seq = np.concatenate([prompt, served])
-        toks[i, :seq.size] = seq
-        mask[i, prompt.size - 1:seq.size - 1] = True
     params = weights.make(seed, ref.param_shapes(cfg),
                           jnp.dtype(spec["model"]["params_dtype"]))
 
     @jax.jit
-    def gaps(params, toks):
-        logits = ref.forward(params, toks, cfg)
-        best = logits.max(-1)
-        if lowp is None:
-            chosen = jnp.roll(toks, -1, axis=1)
-        else:
-            chosen = ref.forward(params, toks, cfg, lowp).argmax(-1)
-        at = jnp.take_along_axis(logits, chosen[..., None], -1)[..., 0]
-        return best - at
+    def gaps(params, toks):               # toks [1, length]
+        length = toks.shape[1]
+        size = min(block, length)
 
-    g = np.asarray(gaps(params, jnp.asarray(toks)))
-    return g[mask]
+        def in_blocks(a):
+            a = jnp.pad(a, [(0, -length % size)] + [(0, 0)] * (a.ndim - 1))
+            return a.reshape(-1, size, *a.shape[1:])
+
+        x = ref.hidden(params, toks, cfg)[0]
+        if lowp is None:
+            other = jnp.roll(toks[0], -1)         # the served tokens
+        else:
+            other = ref.hidden(params, toks, cfg, lowp)[0]
+
+        def one(args):
+            xb, ob = args
+            logits = ref.head(params, xb, cfg)
+            chosen = ob if lowp is None else \
+                ref.head(params, ob, cfg, lowp).argmax(-1)
+            at = jnp.take_along_axis(logits, chosen[:, None], -1)[:, 0]
+            return logits.max(-1) - at
+
+        g = jax.lax.map(one, (in_blocks(x), in_blocks(other)))
+        return g.reshape(-1)[:length]
+
+    out = []
+    for prompt, served in sample:
+        seq = np.concatenate([prompt, served])
+        toks = np.zeros((1, padded_length(seq.size, longest)), np.int32)
+        toks[0, :seq.size] = seq
+        g = np.asarray(gaps(params, jnp.asarray(toks)))
+        out.append(g[prompt.size - 1:seq.size - 1])   # predict a served
+    return np.concatenate(out) if out else np.zeros((0,), np.float32)
 
 
 def check(ctx: dict, held: dict) -> list:
@@ -344,8 +370,7 @@ def control(ctx: dict, held: dict) -> dict:
     configuration states. Not run by the benchmark's own runs."""
     import jax.numpy as jnp
 
-    ref = importlib.import_module(
-        f"benchmark.reference.{ctx['config']['reference']}")
+    ref = workmodel.reference(ctx["config"])
     g = reference_gaps(ctx["config"], ctx["seed"], ctx["traffic"],
                        held["sample"],
                        lowp=ref.round_to(jnp.float8_e4m3fn))

@@ -11,7 +11,6 @@ fetches.
 
 from __future__ import annotations
 
-import importlib
 import importlib.util
 import os
 import sys
@@ -19,7 +18,7 @@ import time
 
 import numpy as np
 
-from benchmark import tracing, trafficgen, weights
+from benchmark import tracing, trafficgen, weights, workmodel
 from benchmark.tracereduce import WINDOW_SPAN
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -69,18 +68,14 @@ class Feed:
 
 def _cli_config(cfg: dict, spec: dict, seed: int, work_dir: str):
     """The ``TrainConfig`` that ``gpt/jax_tpu/train.py`` builds from the
-    traffic file's flags and the configuration's sizes."""
+    traffic file's flags and the model flags the configuration's family
+    gives."""
     path = os.path.join(ROOT, "gpt", "jax_tpu", "train.py")
     mspec = importlib.util.spec_from_file_location("bench_gpt_train_cli",
                                                    path)
     cli = importlib.util.module_from_spec(mspec)
     mspec.loader.exec_module(cli)
-    argv = ["train.py",
-            "--num-layers", str(cfg["n_layer"]),
-            "--num-heads", str(cfg["n_head"]),
-            "--hidden-dim", str(cfg["n_embd"]),
-            "--max-len", str(cfg["n_positions"]),
-            "--vocab-size", str(cfg["assumed"]["padded_vocab_size"]),
+    argv = ["train.py", *workmodel.family(cfg).train_flags(cfg),
             "--seed", str(int(seed) & 0x7FFFFFFF),
             "-c", os.path.join(work_dir, "ckpt"),
             *[str(f) for f in spec["flags"]]]
@@ -137,7 +132,7 @@ def setup(ctx: dict) -> dict:
     from distributed_training_tpu.train.lm_trainer import LMTrainer
 
     cfg, spec, seed = ctx["config"], ctx["traffic"], ctx["seed"]
-    ref = importlib.import_module(f"benchmark.reference.{cfg['reference']}")
+    ref = workmodel.reference(cfg)
     mark = ctx.get("mark", lambda name: None)
     trainer = LMTrainer(_cli_config(cfg, spec, seed, ctx["work_dir"]))
     mark("trainer_built")
@@ -156,7 +151,8 @@ def setup(ctx: dict) -> dict:
     mark("weights_made")
 
     n_check = int(spec["check"]["steps"])
-    rows = trafficgen.token_rows(spec, seed, int(cfg["vocab_size"]))
+    rows = trafficgen.token_rows(spec, seed,
+                                 workmodel.family(cfg).token_ids(cfg))
     feed = Feed(TokenLoader(rows, global_batch_size=trainer.train_gbs,
                             shuffle=True, seed=int(seed) & 0x7FFFFFFF),
                 keep=n_check)
@@ -300,7 +296,7 @@ def reference_run(cfg: dict, seed: int, batches: list, **plant) -> dict:
     through to ``train_steps``."""
     import jax.numpy as jnp
 
-    ref = importlib.import_module(f"benchmark.reference.{cfg['reference']}")
+    ref = workmodel.reference(cfg)
     params = weights.make(seed, ref.param_shapes(cfg), jnp.float32)
     fed = [{"tokens": jnp.asarray(b[:, :-1]), "targets": jnp.asarray(b[:, 1:])}
            for b in batches]
@@ -325,7 +321,7 @@ def control(ctx: dict, held: dict) -> dict:
     import jax.numpy as jnp
 
     cfg, seed = ctx["config"], ctx["seed"]
-    ref = importlib.import_module(f"benchmark.reference.{cfg['reference']}")
+    ref = workmodel.reference(cfg)
     truth = held.get("reference") or reference_run(cfg, seed,
                                                    held["batches"])
     rows = held["batches"][0].shape[0]
@@ -346,9 +342,9 @@ def control(ctx: dict, held: dict) -> dict:
 def seed_batches(ctx: dict) -> dict:
     """The first batches the seed's rows give, without the program: enough
     for the control, which compares the reference with itself."""
-    spec = ctx["traffic"]
+    spec, cfg = ctx["traffic"], ctx["config"]
     rows = trafficgen.token_rows(spec, ctx["seed"],
-                                 int(ctx["config"]["vocab_size"]))
+                                 workmodel.family(cfg).token_ids(cfg))
     n = int(spec["global_batch"])
     return {"batches": [rows[k * n:(k + 1) * n]
                         for k in range(int(spec["check"]["steps"]))]}

@@ -49,11 +49,12 @@ def iteration_flops(cfg: dict, it: dict) -> float:
     """Forward FLOPs of what one engine iteration processed: the prompts
     whose prefill it finished, and one token for every decoding slot at its
     mean live context."""
-    flops = sum(workmodel.prompt_forward_flops(cfg, p)
+    counts = workmodel.family(cfg)
+    flops = sum(counts.prompt_forward_flops(cfg, p)
                 for p in it["prompt_flops_tokens"])
     n_dec = sum(it["landed"].values()) - len(it["prompt_flops_tokens"])
     if n_dec > 0:
-        flops += n_dec * workmodel.forward_flops_token(
+        flops += n_dec * counts.forward_flops_token(
             cfg, it["context_rows"] / n_dec + 1)
     return flops
 
@@ -76,17 +77,19 @@ def serve_mfu(ctx: dict):
 
 def decode_roofline(ctx: dict):
     """Least time the chip could take for the traced iterations' decode
-    work (the weights once an iteration plus the K and V rows of the live
-    context, at the memory peak; or their FLOPs at the compute peak,
-    whichever is longer) over the device time of those iterations, in %."""
+    work (the bytes the family counts for the iteration's per-slot live
+    contexts, weights included, at the memory peak; or their FLOPs at the
+    compute peak, whichever is longer) over the device time of those
+    iterations, in %."""
     its = [it for it in traced_iterations(ctx) if it["landed"]]
     red = ctx["trace_reduced"]
     if not its or not red:
         return None
     cfg, peaks = ctx["config"], ctx["peaks"]
+    counts = workmodel.family(cfg)
     least = 0.0
     for it in its:
-        nbytes = workmodel.decode_iteration_bytes(cfg, it["context_rows"])
+        nbytes = counts.decode_iteration_bytes(cfg, it["contexts"])
         least += workmodel.least_seconds(iteration_flops(cfg, it), nbytes,
                                          peaks)[0]
     # Device time of the decode programs: the busy time of the iterations
@@ -102,7 +105,8 @@ def train_mfu(ctx: dict):
     w = ctx["window"]
     if "tokens_per_s" not in w:
         return None
-    per_token = workmodel.train_flops_token(ctx["config"], w["seq_len"])
+    per_token = workmodel.family(ctx["config"]).train_flops_token(
+        ctx["config"], w["seq_len"])
     return 100.0 * per_token * w["tokens_per_s"] / (
         ctx["chips"] * ctx["peaks"]["flops_per_s"])
 

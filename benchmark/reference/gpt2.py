@@ -120,11 +120,13 @@ def _stack_blocks(params: dict, n_layer: int) -> dict:
             for n in names}
 
 
-def forward(params: dict, tokens, cfg: dict, lowp=None):
-    """Logits ``[B, T, padded_vocab_size]`` in float32 for int tokens
-    ``[B, T]``; ``params`` is ``{path: array}`` as ``param_shapes`` names
-    them (any float type; computed in float32)."""
-    p = {k: v.astype(jnp.float32) for k, v in params.items()}
+def hidden(params: dict, tokens, cfg: dict, lowp=None):
+    """The final, normed states ``[B, T, width]`` in float32 for int tokens
+    ``[B, T]``: everything but the output head. ``params`` is ``{path:
+    array}`` as ``param_shapes`` names them (any float type; computed in
+    float32)."""
+    p = {k: v.astype(jnp.float32) for k, v in params.items()
+         if k != "lm_head/kernel"}
     eps = float(cfg["layer_norm_epsilon"])
     t = tokens.shape[1]
     x = p["tok_embed/embedding"][tokens] + p["pos_embed"][:t][None]
@@ -135,8 +137,20 @@ def forward(params: dict, tokens, cfg: dict, lowp=None):
         return _block(x, blk, eps, lowp), None
 
     x, _ = jax.lax.scan(body, x, stacked)
-    x = _ln(x, p["ln_f/scale"], p["ln_f/bias"], eps)
-    return _mm("btm,mv->btv", x, p["lm_head/kernel"], lowp)
+    return _ln(x, p["ln_f/scale"], p["ln_f/bias"], eps)
+
+
+def head(params: dict, x, cfg: dict, lowp=None):
+    """Logits ``[..., padded_vocab_size]`` of states ``[..., width]`` that
+    :func:`hidden` gave: any block of positions at a time."""
+    return _mm("...m,mv->...v", x,
+               params["lm_head/kernel"].astype(jnp.float32), lowp)
+
+
+def forward(params: dict, tokens, cfg: dict, lowp=None):
+    """Logits ``[B, T, padded_vocab_size]`` in float32 for int tokens
+    ``[B, T]``."""
+    return head(params, hidden(params, tokens, cfg, lowp), cfg, lowp)
 
 
 def loss(params: dict, tokens, targets, cfg: dict, lowp=None):

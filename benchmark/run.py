@@ -154,9 +154,11 @@ def run_cell(cell: dict, bench: dict, seed: int, seconds: float, trace: bool,
     """Everything of a run after the look for a chip; returns the result
     object. Tests call this on the CPU at a toy size."""
     from benchmark import peaks as peaks_mod
-    from benchmark import tracereduce, tracing, trafficgen
+    from benchmark import (spanreaders, tracereduce, tracing, trafficgen,
+                           workmodel)
 
     cfg = load_config(cell["config"], bench)
+    workmodel.family(cfg).validate(cfg)
     spec = trafficgen.load(cell["traffic"], traffic_dir)
     driver = importlib.import_module(f"benchmark.drivers.{spec['driver']}")
     if limits is None:
@@ -168,6 +170,8 @@ def run_cell(cell: dict, bench: dict, seed: int, seconds: float, trace: bool,
 
     session = driver.setup(ctx)
     setup_s = process_age_s()
+    # now, before the window's spans can turn the program's ring over
+    init_s = spanreaders.constructor_s(device)
     setup_counts = counters.snapshot() if counters else {}
     window = driver.measure(ctx, session)
     compiled_in_window = ((counters.snapshot()["compile_s"]
@@ -206,7 +210,8 @@ def run_cell(cell: dict, bench: dict, seed: int, seconds: float, trace: bool,
     else:
         reported = set(end_to_end)
         rctx = {**ctx, "window": window, "trace_reduced": reduced,
-                "setup": setup_counts, "memory_peak_bytes": peak,
+                "setup": setup_counts, "init_s": init_s,
+                "memory_peak_bytes": peak,
                 "peaks": peaks_mod.peaks_for(device["kind"]),
                 "device": device, "end_to_end": end_to_end}
         values = {}

@@ -15,9 +15,9 @@ from __future__ import annotations
 from benchmark import servestats
 
 
-def _ring(ctx: dict):
+def _ring(device: dict):
     """``host_spans`` of the program under test, or ``None``."""
-    if ctx["device"]["platform"] != "tpu":
+    if device["platform"] != "tpu":
         return None
     try:
         from distributed_training_tpu.observability import trace
@@ -40,7 +40,7 @@ def window_of(ctx: dict):
 
 def spans(ctx: dict, name: str, **attrs) -> list:
     """The window's spans of one name whose attributes match."""
-    read, bounds = _ring(ctx), window_of(ctx)
+    read, bounds = _ring(ctx["device"]), window_of(ctx)
     if read is None or bounds is None:
         return []
     return [s for s in read(*bounds) if s.name == name
@@ -115,12 +115,19 @@ def metrics_fetch_ms_p50(ctx: dict):
     return p_ms(spans(ctx, "train.metrics_fetch"), 50)
 
 
-def init_s(ctx: dict):
+def constructor_s(device: dict):
     """Seconds in the constructor of the trainer or of the engine: the
-    last such span before the window opened."""
-    read, bounds = _ring(ctx), window_of(ctx)
-    if read is None or bounds is None:
+    last such span in the ring. The harness calls this when set-up ends: a
+    serving window opens more spans than the ring holds (32 768, some
+    3 000 iterations), so by its close the constructor's has gone."""
+    read = _ring(device)
+    if read is None:
         return None
-    found = [s for s in read(None, bounds[0])
+    found = [s for s in read()
              if s.name in ("setup.trainer_init", "setup.engine_init")]
     return found[-1].t1 - found[-1].t0 if found else None
+
+
+def init_s(ctx: dict):
+    """What :func:`constructor_s` read when set-up ended."""
+    return ctx.get("init_s")

@@ -1,60 +1,28 @@
-"""Operations and bytes the algorithm needs, from shapes alone.
+"""Operations and bytes the algorithm needs, from shapes alone, and the
+look-up from a configuration to its family's counts.
 
-Matmul convention (2 per multiply-add), no recomputation counted, and
-causal attention counted as causal: a query at position t attends t + 1
-keys. These are the numerators of every ``mfu.*`` and ``*_roofline``.
+What depends on a configuration file's keys (parameters, FLOPs of a token,
+bytes of a decode iteration) is counted by the family's own file,
+``benchmark/families/<reference>.py``; what is here needs shapes only.
 """
 
 from __future__ import annotations
 
-
-def dims(cfg: dict) -> dict:
-    """The sizes the formulas use, from a configuration file's keys."""
-    d = int(cfg["n_embd"])
-    return {
-        "layers": int(cfg["n_layer"]), "heads": int(cfg["n_head"]),
-        "d": d, "head_dim": d // int(cfg["n_head"]),
-        "mlp": int(cfg.get("n_inner") or 4 * d),
-        "rows": int(cfg["assumed"]["padded_vocab_size"]),
-        "positions": int(cfg["n_positions"]),
-    }
+import importlib
 
 
-def param_count(cfg: dict) -> int:
-    """Parameters of the model as run (untied head, no head bias)."""
-    s = dims(cfg)
-    d, m = s["d"], s["mlp"]
-    per_layer = (3 * d * d + 3 * d) + (d * d + d) + (d * m + m) \
-        + (m * d + d) + 4 * d
-    return (s["layers"] * per_layer + 2 * s["rows"] * d
-            + s["positions"] * d + 2 * d)
+def family(cfg: dict):
+    """The module of the configuration's family, named by its ``reference``
+    key: ``validate``, ``build_model``, ``train_flags``, ``token_ids`` and
+    the counts ``param_count``, ``matmul_params_read``,
+    ``forward_flops_token``, ``prompt_forward_flops``, ``train_flops_token``
+    and ``decode_iteration_bytes``, each taking the configuration first."""
+    return importlib.import_module(f"benchmark.families.{cfg['reference']}")
 
 
-def matmul_params_read(cfg: dict) -> int:
-    """Parameters a forward pass must read whatever the batch: every
-    layer's matrices, biases and norms, the final norm and the output
-    head. Embedding tables are gathered by row and not counted."""
-    s = dims(cfg)
-    return param_count(cfg) - s["rows"] * s["d"] - s["positions"] * s["d"]
-
-
-def forward_flops_token(cfg: dict, keys: float) -> float:
-    """Forward FLOPs of one token that attends ``keys`` positions."""
-    s = dims(cfg)
-    d, m = s["d"], s["mlp"]
-    per_layer = 2 * d * 3 * d + 2 * d * d + 4 * d * m + 4 * d * keys
-    return s["layers"] * per_layer + 2 * d * s["rows"]
-
-
-def train_flops_token(cfg: dict, seq_len: int) -> float:
-    """Forward + backward FLOPs per trained token (backward = 2 x forward),
-    mean over the positions of a causal sequence of ``seq_len``."""
-    return 3.0 * forward_flops_token(cfg, (seq_len + 1) / 2.0)
-
-
-def prompt_forward_flops(cfg: dict, length: int) -> float:
-    """Forward FLOPs of prefilling ``length`` prompt tokens."""
-    return length * forward_flops_token(cfg, (length + 1) / 2.0)
+def reference(cfg: dict):
+    """The plain reference of the configuration's family."""
+    return importlib.import_module(f"benchmark.reference.{cfg['reference']}")
 
 
 def causal_attention_call(batch: int, heads: int, seq: int, head_dim: int,
@@ -77,13 +45,3 @@ def least_seconds(flops: float, nbytes: float, peaks: dict) -> tuple:
     tf = flops / peaks["flops_per_s"]
     tb = nbytes / peaks["bytes_per_s"]
     return (tf, "flops") if tf >= tb else (tb, "bytes")
-
-
-def decode_iteration_bytes(cfg: dict, live_context_rows: int,
-                           itemsize: int = 2) -> float:
-    """Bytes one decode iteration needs: the weights once, plus the K and
-    V rows of the live context of live slots (not the pool, not the
-    budget)."""
-    s = dims(cfg)
-    kv = 2 * s["layers"] * s["d"] * itemsize * live_context_rows
-    return matmul_params_read(cfg) * itemsize + kv

@@ -2,8 +2,10 @@
 with a toy configuration and toy traffic from ``data/`` put in."""
 
 import copy
+import importlib.util
 import json
 import os
+import sys
 
 from benchmark import run as harness
 
@@ -20,12 +22,12 @@ STANDS_FOR = {"toy-serve-batch": "gpt2l-serve-batch",
               "toy-train": "gpt2s-train-t1024"}
 
 
-def toy_bench(traffic: str):
+def toy_bench(traffic: str, config: str = "toy-gpt2"):
     """(cell, bench): the toy cell reports what the real cell it stands
     for reports."""
     bench = copy.deepcopy(harness.load_benchmark())
     bench["configs"].append({"name": "toy",
-                             "file": "tests/benchmark/data/toy-gpt2.json"})
+                             "file": f"tests/benchmark/data/{config}.json"})
     cell = {"name": "toy." + traffic, "config": "toy", "traffic": traffic,
             "chips": 1, "why": "toy"}
     for m in bench["end_to_end"] + bench["per_layer"]:
@@ -35,14 +37,25 @@ def toy_bench(traffic: str):
     return cell, bench
 
 
-def toy_config() -> dict:
-    with open(os.path.join(DATA, "toy-gpt2.json")) as fh:
+def toy_config(config: str = "toy-gpt2") -> dict:
+    with open(os.path.join(DATA, f"{config}.json")) as fh:
         return json.load(fh)
 
 
+def toy_family(monkeypatch) -> None:
+    """Put ``data/toy_lm.py`` where the ``reference`` key ``toy_lm`` is
+    looked for, as a family and as a reference, for this test."""
+    spec = importlib.util.spec_from_file_location(
+        "bm_toy_lm", os.path.join(DATA, "toy_lm.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    for package in ("families", "reference"):
+        monkeypatch.setitem(sys.modules, f"benchmark.{package}.toy_lm", mod)
+
+
 def run_toy(traffic: str, seed: int, seconds: float, limits: dict,
-            trace: bool = False) -> dict:
-    cell, bench = toy_bench(traffic)
+            trace: bool = False, config: str = "toy-gpt2") -> dict:
+    cell, bench = toy_bench(traffic, config)
     return harness.run_cell(cell, bench, seed, seconds, trace, device=CPU,
                             limits=limits, traffic_dir=DATA)
 

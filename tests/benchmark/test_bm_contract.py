@@ -8,6 +8,8 @@ import sys
 
 import pytest
 
+from benchmark import workmodel
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _fh:
@@ -19,6 +21,20 @@ SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
 CELLS = {w["name"]: w for w in BENCH["workloads"]}
 E2E = {m["name"]: m for m in BENCH["end_to_end"]}
+
+
+# Keys that `reduced` may never name, by what their names say: hidden,
+# feed-forward, expert, latent, state and projection sizes, head sizes,
+# ranks, experts per token, selection and window sizes. Keys that count what
+# is held here (layers, experts, heads, rows of the vocabulary, prediction
+# modules) may be cut. A family whose width keys do not say so lists them
+# itself (``WIDTH_KEYS``).
+WIDTH = re.compile(r"(_dim|_rank|_width|(?<!vocab)_size)$|^d_|topk|per_tok"
+                   r"|window|state|expan|latent|proj")
+
+
+def is_width(key: str, family) -> bool:
+    return bool(WIDTH.search(key)) or key in family.WIDTH_KEYS
 
 
 def _cells_reporting(metric: dict) -> set:
@@ -105,12 +121,12 @@ def test_config_entry_and_its_file(c):
     with open(os.path.join(ROOT, c["file"])) as fh:
         cfg = json.load(fh)
     assert cfg["source"] == c["source"]
-    widths = re.compile(r"(_dim|_rank)$|hidden|intermediate|n_embd|n_inner"
-                        r"|n_head|head")
-    assert not [k for k in c["reduced"] if widths.search(k)]
-    assert cfg["n_embd"] % cfg["n_head"] == 0
-    assert os.path.isfile(os.path.join(
-        ROOT, "benchmark", "reference", cfg["reference"] + ".py"))
+    for part in ("reference", "families"):
+        assert os.path.isfile(os.path.join(
+            ROOT, "benchmark", part, cfg["reference"] + ".py"))
+    family = workmodel.family(cfg)
+    family.validate(cfg)
+    assert not [k for k in c["reduced"] if is_width(k, family)]
 
 
 def _run(cwd, env_extra):

@@ -21,6 +21,12 @@ TRAIN_CTX = {"device": TPU, "seconds": 40.0,
              "window": {"traced": [134.0, 140.0]}}
 
 
+def _at_setups_end(ctx):
+    """The reader's context as ``run_cell`` makes it: with what it read of
+    the constructor's span when set-up ended."""
+    return {**ctx, "init_s": spanreaders.constructor_s(ctx["device"])}
+
+
 def _iteration(key, t0, t1, program, dev=None, dispatch_ms=0.0):
     """One ``serve.iteration`` with its device step, from endpoints in s."""
     it = trace_lib.record("serve.iteration", t0, t1, key=key, live=4,
@@ -100,17 +106,37 @@ def test_every_metric_read_from_the_ring_has_a_case():
                          ids=[r[0] for r in READERS])
 def test_reader_on_hand_made_records(hand_made, metric, ctx, expected):
     read = harness.load_reader(metric)
-    assert read(ctx) == pytest.approx(expected, rel=1e-6)
+    assert read(_at_setups_end(ctx)) == pytest.approx(expected, rel=1e-6)
     # a time from a CPU run is not written under a device metric's name
     off_tpu = {**ctx, "device": {**TPU, "platform": "cpu"}}
-    assert read(off_tpu) is None
+    assert read(_at_setups_end(off_tpu)) is None
 
 
 def test_init_s_reads_the_trainers_constructor_where_there_is_no_engine(
         monkeypatch):
     monkeypatch.setattr(trace_lib, "_ring", collections.deque(maxlen=16))
     trace_lib.record("setup.trainer_init", 30.0, 49.0)
-    assert spanreaders.init_s(TRAIN_CTX) == pytest.approx(19.0)
+    assert spanreaders.init_s(_at_setups_end(TRAIN_CTX)) == \
+        pytest.approx(19.0)
+
+
+def test_init_s_outlives_a_ring_that_turns_over_inside_the_window(
+        monkeypatch):
+    """A serving window opens more spans than the ring holds; what the
+    harness took when set-up ended is still what ``init_s`` reads."""
+    monkeypatch.setattr(trace_lib, "_ring",
+                        collections.deque(maxlen=trace_lib.RING_SPANS))
+    trace_lib.record("setup.engine_init", 50.0, 52.0)
+    ctx = _at_setups_end(SERVE_CTX)
+    for i in range(trace_lib.RING_SPANS + 10):
+        trace_lib.record("serve.iteration", 100.0 + i * 1e-3,
+                         100.0005 + i * 1e-3, key=i, program="decode")
+    assert len(trace_lib.host_spans()) == trace_lib.RING_SPANS
+    assert not [s for s in trace_lib.host_spans()
+                if s.name == "setup.engine_init"]
+    assert harness.load_reader("init_s")(ctx) == pytest.approx(2.0)
+    # read at the close, as before this test, it finds nothing
+    assert harness.load_reader("init_s")(_at_setups_end(SERVE_CTX)) is None
 
 
 @pytest.mark.parametrize("metric,ctx", [(r[0], r[1]) for r in READERS],
@@ -120,10 +146,10 @@ def test_reader_finds_nothing_without_raising(monkeypatch, metric, ctx):
     all): the metric is left out of the line, the run goes on."""
     read = harness.load_reader(metric)
     monkeypatch.setattr(trace_lib, "_ring", collections.deque(maxlen=16))
-    assert read(ctx) is None
-    assert read({**ctx, "window": {}}) is None
+    assert read(_at_setups_end(ctx)) is None
+    assert read(_at_setups_end({**ctx, "window": {}})) is None
     monkeypatch.delattr(trace_lib, "host_spans")
-    assert read(ctx) is None
+    assert read(_at_setups_end(ctx)) is None
 
 
 def test_the_program_imports_no_benchmark():

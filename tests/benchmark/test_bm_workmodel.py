@@ -19,29 +19,31 @@ def _cfg(name):
     ("gpt2-small", 12, 768, 12), ("gpt2-large", 36, 1280, 20)])
 def test_parameters_and_flops_by_hand(name, layers, d, heads):
     cfg = _cfg(name)
+    counts = workmodel.family(cfg)
     rows, pos = 50304, 1024
     per_layer = 12 * d * d + 13 * d      # 4 matrices' worth + biases, norms
     want = layers * per_layer + 2 * rows * d + pos * d + 2 * d
-    assert workmodel.param_count(cfg) == want == cfg["parameters"]
-    assert workmodel.matmul_params_read(cfg) == want - rows * d - pos * d
+    assert counts.param_count(cfg) == want == cfg["parameters"]
+    assert counts.matmul_params_read(cfg) == want - rows * d - pos * d
     keys = 100
     fwd = layers * (24 * d * d + 4 * d * keys) + 2 * d * rows
-    assert workmodel.forward_flops_token(cfg, keys) == fwd
+    assert counts.forward_flops_token(cfg, keys) == fwd
     t = 1024
     mean = layers * (24 * d * d + 4 * d * (t + 1) / 2) + 2 * d * rows
-    assert workmodel.train_flops_token(cfg, t) == pytest.approx(3 * mean)
-    assert workmodel.prompt_forward_flops(cfg, 64) == pytest.approx(
+    assert counts.train_flops_token(cfg, t) == pytest.approx(3 * mean)
+    assert counts.prompt_forward_flops(cfg, 64) == pytest.approx(
         64 * (layers * (24 * d * d + 4 * d * 32.5) + 2 * d * rows))
-    assert workmodel.dims(cfg)["head_dim"] == 64 == d // heads
+    assert counts.dims(cfg)["head_dim"] == 64 == d // heads
 
 
 @pytest.mark.parametrize("name", ["gpt2-small", "gpt2-large"])
 def test_decode_bytes_count_live_rows_not_the_pool(name):
     cfg = _cfg(name)
-    s = workmodel.dims(cfg)
-    none = workmodel.decode_iteration_bytes(cfg, 0)
-    assert none == 2 * workmodel.matmul_params_read(cfg)
-    some = workmodel.decode_iteration_bytes(cfg, 1000)
+    counts = workmodel.family(cfg)
+    s = counts.dims(cfg)
+    none = counts.decode_iteration_bytes(cfg, [])
+    assert none == 2 * counts.matmul_params_read(cfg)
+    some = counts.decode_iteration_bytes(cfg, [700, 250, 50])
     assert some - none == 1000 * 2 * s["layers"] * s["d"] * 2
 
 
