@@ -110,11 +110,13 @@ class CacheBudgetError(ValueError):
 def cache_budget(model: Any, max_len: int | None = None) -> int:
     """Token capacity of one sequence's KV cache (prompt + generated).
 
-    The hard ceiling is ``model.max_len`` — cache slots past the
-    positional table would decode at silently-clamped pos-embed rows
-    (``models/gpt.py`` poisons that case). ``max_len`` optionally caps it
-    further: the serving engine allocates that many slots per decode slot
-    and admits only requests whose whole lifetime fits.
+    The hard ceiling is ``model.max_len``, the most positions the model
+    gives a sequence: the length of a learned position table (cache slots
+    past it would decode at silently-clamped rows; ``models/gpt.py``
+    poisons that case) or the limit a rotary model publishes.
+    ``max_len`` optionally caps it further: the serving engine allocates
+    that many slots per decode slot and admits only requests whose whole
+    lifetime fits.
     """
     budget = int(model.max_len)
     if max_len is not None:

@@ -42,9 +42,15 @@ def _lm(**kw):
     return make_transformer_lm(**kw)
 
 
+def _deepseek_v32(**kw):
+    from distributed_training_tpu.models.deepseek_v32 import make_deepseek_v32
+    return make_deepseek_v32(**kw)
+
+
 _REGISTRY["vit_b16"] = _vit
 _REGISTRY["moe_mlp"] = _moe
 _REGISTRY["transformer_lm"] = _lm
+_REGISTRY["deepseek_v32"] = _deepseek_v32
 
 
 def available_models() -> list[str]:

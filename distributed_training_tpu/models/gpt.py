@@ -27,7 +27,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from distributed_training_tpu.parallel.ring_attention import RingSelfAttention
+from distributed_training_tpu.parallel.ring_attention import (
+    RingSelfAttention,
+    paged_formulation,
+)
 
 
 class QuantFriendlyDense(nn.Dense):
@@ -301,6 +304,22 @@ class TransformerLM(nn.Module):
     # Ignored in decode mode (no backward). The pipeline executor honors
     # it too (PipelinedLM checkpoints each layer inside its stage scan).
     remat: bool = False
+
+    # What the serving engine asks a model about itself (serving/engine.py).
+    # No step counters: nothing here sows into a ``counters`` collection.
+    step_counters = ()
+
+    def paged_lane(self, t_in: int, page_size: int,
+                   kv_dtype: str | None) -> str:
+        """The attention formulation a paged decode call ``t_in`` rows wide
+        takes (``ring_attention.paged_formulation``: kernel or gather)."""
+        return paged_formulation(t_in, self.num_heads,
+                                 self.hidden_dim // self.num_heads,
+                                 page_size, self.dtype, kv_dtype)
+
+    def attended_rows(self, live: int) -> int:
+        """Of ``live`` cached rows a query attends all: dense attention."""
+        return live
 
     @nn.compact
     def __call__(self, tokens, positions=None, train: bool = False,

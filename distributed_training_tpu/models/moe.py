@@ -31,6 +31,7 @@ Noisy gate policies (DeepSpeed names):
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Sequence
 
 import flax.linen as nn
@@ -255,6 +256,164 @@ class MoEMlp(nn.Module):
                    + dense * coef[:, 1:].astype(self.dtype))
 
         return out.reshape(orig_shape)
+
+
+def grouped_sigmoid_route(logits, bias, *, n_group: int, topk_group: int,
+                          top_k: int, scale: float):
+    """Dropless top-k routing by sigmoid scores with a selection bias and
+    groups (the DeepSeek-V3 family's router), in float32.
+
+    ``logits`` [T, E] are the router's outputs, ``bias`` [E] the learned
+    selection bias. ``s = sigmoid(logits)``; selection runs on ``s + bias``:
+    the experts are cut into ``n_group`` equal groups, a group scores the
+    sum of its two highest entries, the ``topk_group`` best groups are
+    kept, and among their experts the ``top_k`` highest are taken. The
+    weights are ``s`` (without the bias) of the chosen, divided by their
+    sum, times ``scale``. Returns ``(experts int32 [T, top_k], weights
+    float32 [T, top_k])``. Ties go to the lower index, as ``lax.top_k``
+    breaks them."""
+    t, e = logits.shape
+    if e % n_group:
+        raise ValueError(f"{n_group} groups do not divide {e} experts")
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    sel = s + bias.astype(jnp.float32)
+    grouped = sel.reshape(t, n_group, e // n_group)
+    group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)         # [T, G]
+    kept = jax.lax.top_k(group_score, topk_group)[1]           # [T, topk_group]
+    keep = jnp.zeros((t, n_group), bool).at[
+        jnp.arange(t)[:, None], kept].set(True)
+    masked = jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(t, e)
+    experts = jax.lax.top_k(masked, top_k)[1]
+    w = jnp.take_along_axis(s, experts, axis=-1)
+    w = w / w.sum(-1, keepdims=True) * scale
+    return experts.astype(jnp.int32), w
+
+
+def _silu_ffn(x, w1, w3, w2):
+    """``(SiLU(x w1) * (x w3)) w2`` in ``x``'s type."""
+    h = jax.nn.silu(jnp.dot(x, w1)) * jnp.dot(x, w3)
+    return jnp.dot(h, w2)
+
+
+class GatedMlp(nn.Module):
+    """Bias-free gated SiLU FFN: ``(SiLU(x W1) * x W3) W2``."""
+
+    hidden_dim: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        d = x.shape[-1]
+        init = nn.initializers.normal(0.02)
+        w1 = self.param("w1", init, (d, self.hidden_dim))
+        w3 = self.param("w3", init, (d, self.hidden_dim))
+        w2 = self.param("w2", init, (self.hidden_dim, d))
+        return _silu_ffn(x.astype(self.dtype), w1.astype(self.dtype),
+                         w3.astype(self.dtype), w2.astype(self.dtype))
+
+
+class HeldExpertsMlp(nn.Module):
+    """An expert layer that is told which experts it holds.
+
+    ``held = (first, count)``: of the ``num_experts`` routed experts this
+    chip holds ``first .. first + count - 1`` (expert parallelism's share
+    of the layer). Every token is routed over ALL ``num_experts``
+    (:func:`grouped_sigmoid_route`); the layer computes the held experts'
+    part of ``sum_i w_i E_i(x)`` for the tokens routed to them, adds the
+    shared expert (replicated: every chip computes it alike), and returns
+    that partial sum. What the absent experts would add is left out and
+    nothing stands in for their chips or the exchange with them.
+
+    Dropless at static shapes: the (token, expert) pairs that landed on a
+    held expert are sorted by expert, and each held expert walks its run
+    of pairs ``block_rows`` at a time (rows gathered, multiplied,
+    scattered back under their routing weights): no block for an expert
+    that got none (its weights are not read), one for the usual few rows,
+    as many as it takes otherwise. No capacity, no drop.
+
+    ``valid`` [T] masks tokens that do not exist (padding rows, empty
+    decode slots): they are routed nowhere and counted nowhere. Sows, in
+    the ``counters`` collection when it is mutable, ``expert_rows`` (pairs
+    that landed on held experts) and ``expert_rows_max`` (on the busiest
+    of them), int32 scalars."""
+
+    num_experts: int
+    held: tuple
+    hidden_dim: int
+    top_k: int
+    n_group: int
+    topk_group: int
+    routed_scale: float
+    shared_experts: int = 1
+    block_rows: int = 128
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, valid=None):
+        shape = x.shape
+        d = shape[-1]
+        x = x.reshape(-1, d).astype(self.dtype)
+        t = x.shape[0]
+        first, count = (int(v) for v in self.held)
+        if not (0 <= first and first + count <= self.num_experts):
+            raise ValueError(f"held {self.held} outside the "
+                             f"{self.num_experts} routed experts")
+        init = nn.initializers.normal(0.02)
+        w_g = self.param("router", init, (d, self.num_experts))
+        b_g = self.param("router_bias", init, (self.num_experts,))
+        w1 = self.param("w1", init, (count, d, self.hidden_dim))
+        w3 = self.param("w3", init, (count, d, self.hidden_dim))
+        w2 = self.param("w2", init, (count, self.hidden_dim, d))
+
+        with jax.named_scope("moe.route"):
+            logits = jnp.dot(x.astype(jnp.float32), w_g.astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+            experts, weights = grouped_sigmoid_route(
+                logits, b_g, n_group=self.n_group,
+                topk_group=self.topk_group, top_k=self.top_k,
+                scale=self.routed_scale)
+            # Pairs by held expert; pairs routed elsewhere, or of tokens
+            # that do not exist, go to the sentinel group ``count``.
+            local = experts - first
+            here = (local >= 0) & (local < count)
+            if valid is not None:
+                here &= valid.reshape(-1)[:, None]
+            local = jnp.where(here, local, count).reshape(-1)
+            rows = (local[:, None] == jnp.arange(count)[None, :]).sum(
+                0, dtype=jnp.int32)                            # [count]
+            order = jnp.argsort(local, stable=True)
+            starts = jnp.cumsum(rows) - rows
+            self.sow("counters", "expert_rows", rows.sum(),
+                     init_fn=lambda: jnp.zeros((), jnp.int32),
+                     reduce_fn=jnp.add)
+            self.sow("counters", "expert_rows_max", rows.max(),
+                     init_fn=lambda: jnp.zeros((), jnp.int32),
+                     reduce_fn=jnp.add)
+
+        with jax.named_scope("moe.experts"):
+            r = min(int(self.block_rows), t)
+            pair_token = jnp.pad(order // self.top_k, (0, r))
+            pair_weight = jnp.pad(weights.reshape(-1)[order], (0, r))
+            w1, w3, w2 = (w.astype(self.dtype) for w in (w1, w3, w2))
+
+            def block(e, i, acc):
+                at = starts[e] + i * r
+                tok = jax.lax.dynamic_slice_in_dim(pair_token, at, r)
+                wgt = jax.lax.dynamic_slice_in_dim(pair_weight, at, r)
+                wgt = jnp.where(i * r + jnp.arange(r) < rows[e], wgt, 0.0)
+                y = _silu_ffn(x[tok], w1[e], w3[e], w2[e])
+                return acc.at[tok].add(y.astype(jnp.float32) * wgt[:, None])
+
+            acc = jnp.zeros((t, d), jnp.float32)
+            for e in range(count):
+                acc = jax.lax.fori_loop(0, (rows[e] + r - 1) // r,
+                                        functools.partial(block, e), acc)
+            out = acc.astype(self.dtype)
+            if self.shared_experts:
+                out = out + GatedMlp(
+                    self.hidden_dim * self.shared_experts, dtype=self.dtype,
+                    name="shared")(x)
+        return out.reshape(shape)
 
 
 class MoEImageClassifier(nn.Module):

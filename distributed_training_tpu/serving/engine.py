@@ -95,6 +95,7 @@ import threading
 import time
 from typing import Any
 
+import flax
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -109,10 +110,7 @@ from distributed_training_tpu.inference.sampler import (
 )
 from distributed_training_tpu.models.gpt import init_decode_cache
 from distributed_training_tpu.observability import trace as trace_lib
-from distributed_training_tpu.parallel.ring_attention import (
-    PagedKV,
-    paged_formulation,
-)
+from distributed_training_tpu.parallel.ring_attention import PagedKV
 from distributed_training_tpu.resilience.errors import SwapError
 from distributed_training_tpu.serving.alerts import (
     AlertEngine,
@@ -162,7 +160,20 @@ from distributed_training_tpu.serving.timeseries import (
 
 
 class Engine:
-    """Continuous-batching serving engine for a :class:`TransformerLM`.
+    """Continuous-batching serving engine for a decoder LM.
+
+    The model is any module with the interface the engine drives:
+    ``apply(tokens, positions, train, decode, mutable, pages)`` writing a
+    ``cache`` collection, ``clone(cache_len, kv_page_size, kv_pages,
+    kv_dtype)``, ``max_len`` (the most positions a sequence may have) and,
+    about itself, ``paged_lane(t_in, page_size, kv_dtype)`` (the name of
+    the attention formulation a paged call that wide takes),
+    ``attended_rows(live)`` (how many of a slot's live rows one query
+    reads) and ``step_counters`` (names of int32 scalars it sows into a
+    ``counters`` collection each step: docs/SERVING.md "What a model
+    tells the engine"). :class:`~distributed_training_tpu.models.gpt.
+    TransformerLM` and :class:`~distributed_training_tpu.models.
+    deepseek_v32.DeepseekV32LM` are the two.
 
     >>> eng = Engine(model, params, ServeConfig(max_batch=8))
     >>> eng.submit(prompt_tokens)
@@ -263,6 +274,12 @@ class Engine:
             temperature=cfg.temperature, top_k=cfg.top_k, top_p=cfg.top_p,
             eos_id=cfg.eos_id, pad_id=cfg.pad_id)
 
+        # int32 scalars the model sows into a ``counters`` collection each
+        # step (an expert layer's routed rows): one more output of the
+        # step programs, fetched in the iteration's sync, for its span.
+        self._step_counters = tuple(model.step_counters)
+        self._mutable = ["cache"] + (["counters"] if self._step_counters
+                                     else [])
         s = cfg.max_batch
         if self.paged:
             ps = int(cfg.kv_page_size)
@@ -305,8 +322,8 @@ class Engine:
             if cache_len > int(model.max_len):
                 raise ValueError(
                     f"spec_k={self.spec_k} on the legacy contiguous "
-                    f"path needs budget + spec_k <= the positional "
-                    f"table (got {self.budget} + {self.spec_k} > "
+                    f"path needs budget + spec_k <= the model's "
+                    f"position limit (got {self.budget} + {self.spec_k} > "
                     f"{model.max_len}); lower max_len or use the paged "
                     f"cache (kv_page_size), whose window padding is "
                     f"validity-masked instead of written")
@@ -451,14 +468,12 @@ class Engine:
             self._slot_shared = [0] * s
             self._slot_seq: list[ActiveSequence | None] = [None] * s
             with trace_lib.span("setup.program_build") as build_span:
-                # Which attention formulation each lane's shapes select
-                # (ring_attention.paged_formulation — the model decides
-                # by the same call when the programs trace).
-                m = self.model
+                # Which attention formulation each lane's shapes select:
+                # the model says (it decides by the same call when the
+                # programs trace).
                 self.lane_formulation = {
-                    lane: paged_formulation(
-                        t_in, m.num_heads, m.hidden_dim // m.num_heads,
-                        self.page_size, m.dtype, cfg.kv_dtype)
+                    lane: self.model.paged_lane(t_in, self.page_size,
+                                                cfg.kv_dtype)
                     for lane, t_in in (("decode", self.spec_k + 1),
                                        ("chunk", self.prefill_chunk))}
                 build_span.attrs.update(
@@ -552,7 +567,7 @@ class Engine:
         logits, vars_out = self.model.apply(
             {"params": params, "cache": cache}, tok,
             positions=pos, train=False, decode=True,
-            mutable=["cache"], pages=pages)
+            mutable=self._mutable, pages=pages)
 
         def lane(rng_s, pos_row, rows):
             def one(pos_s, row):
@@ -563,7 +578,20 @@ class Engine:
 
         t = jax.vmap(lane)(rngs, pos, logits)
         t = jnp.where(valid, t, jnp.int32(self.sample_cfg.pad_id))
-        return vars_out["cache"], t, self._accept_len(tok, t, valid)
+        return (vars_out["cache"], t, self._accept_len(tok, t, valid),
+                self._counted(vars_out))
+
+    def _counted(self, vars_out):
+        """The model's step counters as one int32 vector, each summed
+        over the layers that sowed it; None for a model that has none."""
+        if not self._step_counters:
+            return None
+        flat = flax.traverse_util.flatten_dict(
+            flax.core.unfreeze(vars_out.get("counters", {})))
+        return jnp.stack([
+            sum((v for k, v in flat.items() if k[-1] == name),
+                start=jnp.zeros((), jnp.int32))
+            for name in self._step_counters]).astype(jnp.int32)
 
     def _accept_len(self, tok, t, valid):
         """[B] accepted-draft counts from a verify window (see
@@ -590,14 +618,14 @@ class Engine:
         logits, vars_out = self.model.apply(
             {"params": params, "cache": cache}, toks[None],
             positions=pos[None], train=False, decode=True,
-            mutable=["cache"], pages=pages)
+            mutable=self._mutable, pages=pages)
 
         def row(pos_s, lg):
             return sample_token(jax.random.fold_in(rng, pos_s),
                                 lg[None], self.sample_cfg)[0]
 
         sampled = jax.vmap(row)(pos, logits[0])
-        return vars_out["cache"], sampled
+        return vars_out["cache"], sampled, self._counted(vars_out)
 
     def _fused_impl(self, params, cache, d_tok, d_pos, d_valid, d_rngs,
                     tables, c_tok, c_pos, c_valid, c_table, c_rng):
@@ -608,19 +636,22 @@ class Engine:
         disjoint pages (the chunk's slot is not decoding), so their
         order is arithmetic-free."""
         with jax.named_scope("serve.fused"):
-            cache, c_sampled = self._chunk_step(
+            cache, c_sampled, c_counted = self._chunk_step(
                 params, cache, c_tok, c_pos, c_valid, c_table, c_rng)
-            cache, nxt, accept = self._decode_step(
+            cache, nxt, accept, counted = self._decode_step(
                 params, cache, d_tok, d_pos, d_valid, d_rngs, tables)
-        return cache, nxt, accept, c_sampled
+        out = (cache, nxt, accept, c_sampled)
+        # a model with step counters: the chunk's rows beside the decode's
+        return out if counted is None else out + (counted + c_counted,)
 
     def _decode_only_impl(self, params, cache, d_tok, d_pos, d_valid,
                           d_rngs, tables):
         """Iterations with no prefill pending skip the chunk lane's
         compute entirely (the second compiled program)."""
         with jax.named_scope("serve.decode"):
-            return self._decode_step(params, cache, d_tok, d_pos, d_valid,
-                                     d_rngs, tables)
+            *out, counted = self._decode_step(
+                params, cache, d_tok, d_pos, d_valid, d_rngs, tables)
+        return tuple(out) if counted is None else (*out, counted)
 
     # -- compiled pieces: legacy contiguous slots ----------------------------
     def _prefill_impl(self, params, prompt, true_len, rng):
@@ -1712,13 +1743,20 @@ class Engine:
             pages_live = sum(pages_for(r, self.page_size)
                              for r in rows_live)
             pages_budget = self.cfg.max_batch * self.pages_per_slot
-            it_span.attrs.update(kv_pages_live=pages_live,
-                                 kv_pages_budget=pages_budget)
+            # What the decoding slots' queries read of their live rows
+            # (a model with a learned selection reads fewer than all).
+            rows_dec = rows_live[:len(decoding)]
+            it_span.attrs.update(
+                kv_pages_live=pages_live, kv_pages_budget=pages_budget,
+                kv_rows_live=sum(rows_dec),
+                kv_rows_selected=sum(map(self.model.attended_rows,
+                                         rows_dec)))
             with span("serve.device_step", program=program) as dev_span:
                 with span("serve.dispatch",
                           uploads=10 if chunk_seq is not None else 5):
                     if chunk_seq is not None:
-                        self._cache, nxt, acc, c_sampled = self._fused(
+                        (self._cache, nxt, acc, c_sampled,
+                         *counted) = self._fused(
                             self.params, self._cache, jnp.asarray(d_tok),
                             jnp.asarray(d_pos), jnp.asarray(d_valid),
                             jnp.asarray(self._slot_rng),
@@ -1727,7 +1765,7 @@ class Engine:
                             jnp.asarray(self._tables[chunk_seq.slot][None]),
                             jnp.asarray(self._slot_rng[chunk_seq.slot]))
                     else:
-                        self._cache, nxt, acc = self._decode(
+                        self._cache, nxt, acc, *counted = self._decode(
                             self.params, self._cache, jnp.asarray(d_tok),
                             jnp.asarray(d_pos), jnp.asarray(d_valid),
                             jnp.asarray(self._slot_rng),
@@ -1737,6 +1775,11 @@ class Engine:
                     toks = np.asarray(nxt)
                     # graftlint: disable=hot-path-transfer -- per-slot accept lengths ride the same iteration sync
                     accepts = np.asarray(acc)
+                    if counted:
+                        # graftlint: disable=hot-path-transfer -- the model's step counters, a few int32 in the same iteration sync
+                        counts = np.asarray(counted[0])
+                        it_span.attrs.update(zip(self._step_counters,
+                                                 map(int, counts)))
             # The tokens' landing time: every ledger stamp, TTFT and
             # deadline below reads this one clock value.
             t = dev_span.t1

@@ -194,8 +194,8 @@ class RequestQueue:
             if self.pool_pages is not None:
                 cap = min(cap, self.pool_pages)
             # The token budget stays authoritative (write positions must
-            # fit the positional table) even when page-count rounding
-            # would cover the overflow.
+            # stay under the model's position limit) even when page-count
+            # rounding would cover the overflow.
             if need > cap or total > self.budget:
                 with self._lock:
                     self.rejected += 1

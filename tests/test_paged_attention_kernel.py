@@ -388,3 +388,31 @@ def test_the_kernel_compiles_for_a_v5e_at_gpt2_large_widths(one_chip, t_in):
     assert "tpu_custom_call" in text
     # Nothing of the pool's size is made: the kernel reads it in place.
     assert compiled.memory_analysis().temp_size_in_bytes < rows * width
+
+
+def test_the_masked_attention_kernel_compiles_for_a_v5e_at_deepseek_widths(
+        one_chip):
+    """``ops/masked_attention.py`` at the long-context cell's shapes: a
+    1024-row chunk against a 1024-key block, 128 heads of 128 + 64 / 128."""
+    from distributed_training_tpu.ops import masked_attention as ma
+
+    heads, t, s, nope, rope, v = 128, 1024, 1024, 128, 64, 128
+
+    def shape(dims, d):
+        return jax.ShapeDtypeStruct(dims, d, sharding=one_chip)
+
+    def call(*args):
+        return ma.masked_attention_block(*args, scale=0.1, interpret=False)
+
+    state = jax.eval_shape(lambda: ma.init_state(t, heads, v))
+    compiled = jax.jit(call, donate_argnums=6).lower(
+        shape((heads, t, nope), jnp.bfloat16),
+        shape((heads, t, rope), jnp.bfloat16),
+        shape((s, heads * nope), jnp.bfloat16),
+        shape((s, rope), jnp.bfloat16),
+        shape((s, heads * v), jnp.bfloat16),
+        shape((t, s), jnp.int8),
+        tuple(shape(a.shape, a.dtype) for a in state)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the state is advanced in place and no score reaches memory
+    assert compiled.memory_analysis().temp_size_in_bytes < t * s * 4
