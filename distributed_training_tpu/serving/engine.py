@@ -36,6 +36,15 @@ Shared discipline either way — masks, never shapes:
   only when the pool can commit its worst case). Slot membership is
   boolean masks and page-table contents — shapes never change, nothing
   retraces.
+- **One device step ahead of the host (paged path).** A call to
+  ``step()`` delivers the tokens of one device step and, where it may,
+  has launched the next one first: nothing the host puts into a step
+  depends on a token's value, only on counts, and a slot's incoming
+  token is picked on the device from the previous step's own output
+  (``_iterate_paged``; docs/SERVING.md "The iteration's order"). What
+  changes the world between two steps — a swap barrier, a preemption,
+  a cancel or an expired deadline of a seated request, a drafter —
+  lands the step in flight first.
 - **Lane independence = bitwise determinism.** A slot's row arithmetic
   is identical regardless of which other requests share the batch
   (rows of every position-wise op and of the per-row paged attention —
@@ -91,6 +100,7 @@ zero-tolerance (docs/OBSERVABILITY.md "Latency ledger").
 
 from __future__ import annotations
 
+import dataclasses
 import threading
 import time
 from typing import Any
@@ -157,6 +167,51 @@ from distributed_training_tpu.serving.timeseries import (
     TIMESERIES_DUMP_SAMPLES,
     TelemetryRing,
 )
+
+
+# Where a decode lane's incoming token comes from (``_DeviceStep.d_src``,
+# ``Engine._incoming``): the host's own ``d_tok``; the step before's
+# ``nxt[slot, 0]``; or, for a slot whose final chunk was that step, its
+# ``c_sampled[row]`` as ``_SRC_CHUNK + row``.
+_SRC_HOST, _SRC_NXT, _SRC_CHUNK = 0, 1, 2
+
+
+@dataclasses.dataclass
+class _DeviceStep:
+    """One device step of the paged iteration, from its assembly on the
+    host to the landing of its tokens — which may be one call to
+    :meth:`Engine.step` later (``Engine._in_flight``)."""
+
+    program: str                       # "fused" or "decode"
+    decoding: list                     # the decode lane's sequences
+    d_tok: np.ndarray
+    d_pos: np.ndarray
+    d_valid: np.ndarray
+    d_src: np.ndarray                  # [slots] of _SRC_*
+    useful_by_slot: dict
+    drafted: int
+    chunk_seq: ActiveSequence | None   # the chunk lane's sequence
+    start: int                         # the chunk's first position
+    c: int                             # and its valid rows
+    chunk: tuple                       # (c_tok, c_pos, c_valid), or ()
+    # slot -> the sequence whose pages this step writes
+    lanes: dict
+    # slot -> pages it held when this step was assembled, and how many
+    # of the pool's pages that assembly drew
+    pages_held: dict
+    pages_drawn: int
+    # this step's counters on the span of the iteration that delivers it
+    attrs: dict
+    # what the launch returned: the arrays the next launch reads on the
+    # device are the very ones the host fetches
+    t0: float = 0.0
+    nxt: Any = None
+    acc: Any = None
+    c_sampled: Any = None
+    counted: Any = None
+    # page releases of sequences that left while this step was in flight:
+    # handed to the pool when it has landed
+    held: list = dataclasses.field(default_factory=list)
 
 
 class Engine:
@@ -443,9 +498,21 @@ class Engine:
         # scatter; ring_attention._paged_decode_attend). The CPU backend
         # can't donate (it would only warn noisily).
         donate = jax.default_backend() != "cpu"
+        # The paged iteration runs one device step ahead of the host
+        # (_iterate_paged): the step launched and not yet fetched, the
+        # latch by which an admission pass asks for a preemption with
+        # nothing in flight, and what Engine.stats()["run_ahead_share"]
+        # counts. The legacy path leaves all four as they are here.
+        self._in_flight: _DeviceStep | None = None
+        self._preempt_due = False
+        self._iters_working = 0
+        self._iters_ahead = 0
         if self.paged:
-            # Device state: ONLY the page pool (batch-free). Slot
-            # routing (page tables, write heads, last tokens, RNGs) is
+            # Device state: the page pool (batch-free) and, between two
+            # steps, the last step's own outputs: a slot's incoming
+            # token is the previous step's `nxt` where the host has not
+            # fetched it yet (_incoming). Slot routing (page tables,
+            # write heads, RNGs, and the tokens the host has seen) is
             # host-side numpy, shipped as tiny step inputs — so page
             # allocation and slot membership never touch compiled code,
             # and how much of each slot's table is live is data the
@@ -485,6 +552,11 @@ class Engine:
                 self._decode = jax.jit(
                     self._decode_only_impl,
                     donate_argnums=(1,) if donate else ())
+                # What a launch with no step before it reads in the
+                # previous outputs' place (every source is the host).
+                self._no_tokens = (
+                    jnp.zeros((s, self.spec_width), jnp.int32),
+                    jnp.zeros((self.prefill_chunk,), jnp.int32))
         else:
             # Slot-axis device state. The stacked cache comes from the
             # model's own structure (init_decode_cache), so scatters
@@ -539,13 +611,28 @@ class Engine:
         return total / max(rows, 1)
 
     # -- compiled pieces: paged KV + chunked prefill -------------------------
-    def _decode_step(self, params, cache, tok, pos, valid, rngs, tables):
+    def _incoming(self, tok, src, prev_nxt, prev_sampled):
+        """Row 0 of every decode lane: the host's value, or — where the
+        host had not seen the token when it assembled this step — the
+        step before's own output, picked by ``src`` (``_SRC_*``). The
+        arrays are the ones the host fetches, so the two cannot differ."""
+        row = jnp.clip(src - _SRC_CHUNK, 0, prev_sampled.shape[0] - 1)
+        tok0 = jnp.where(src == _SRC_HOST, tok[:, 0],
+                         jnp.where(src == _SRC_NXT, prev_nxt[:, 0],
+                                   prev_sampled[row]))
+        return tok.at[:, 0].set(tok0)
+
+    def _decode_step(self, params, cache, tok, pos, valid, rngs, tables,
+                     src, prev_nxt, prev_sampled):
         """One verify window for every slot through the paged pool.
 
         ``tok``/``pos``/``valid`` are [B, W] host state (W = spec_k + 1;
         W = 1 is the plain decode step), ``rngs`` [B], ``tables``
         [B, pages_per_slot]. Row 0 of each lane is the slot's incoming
-        token; rows 1..W-1 are its drafter's proposals. Invalid rows
+        token — ``tok``'s, or the previous step's ``prev_nxt`` [B, W] /
+        ``prev_sampled`` [chunk] where ``src`` [B] says so
+        (:meth:`_incoming`); rows 1..W-1 are its drafter's proposals.
+        Invalid rows
         (inactive slots, budget-clamped or short proposals) still
         compute (static shapes) but write the null page and sample pad —
         a freed slot's pool pages stay bitwise intact until the
@@ -563,6 +650,7 @@ class Engine:
         count as mismatches, so accept never crosses the valid width.
         Returns (cache, targets [B, W], accept [B]).
         """
+        tok = self._incoming(tok, src, prev_nxt, prev_sampled)
         pages = PagedKV(table=tables, positions=pos, valid=valid)
         logits, vars_out = self.model.apply(
             {"params": params, "cache": cache}, tok,
@@ -628,29 +716,33 @@ class Engine:
         return vars_out["cache"], sampled, self._counted(vars_out)
 
     def _fused_impl(self, params, cache, d_tok, d_pos, d_valid, d_rngs,
-                    tables, c_tok, c_pos, c_valid, c_table, c_rng):
+                    tables, c_tok, c_pos, c_valid, c_table, c_rng,
+                    d_src, prev_nxt, prev_sampled):
         """The fused iteration: one prefill chunk piggybacks onto the
         decode batch's verify window inside one compiled program
         (Sarathi-Serve), so an admission costs decode ZERO extra
         dispatches and never blocks it. The two sub-applies touch
         disjoint pages (the chunk's slot is not decoding), so their
-        order is arithmetic-free."""
+        order is arithmetic-free. The last three arguments are the
+        decode lane's (:meth:`_decode_step`)."""
         with jax.named_scope("serve.fused"):
             cache, c_sampled, c_counted = self._chunk_step(
                 params, cache, c_tok, c_pos, c_valid, c_table, c_rng)
             cache, nxt, accept, counted = self._decode_step(
-                params, cache, d_tok, d_pos, d_valid, d_rngs, tables)
+                params, cache, d_tok, d_pos, d_valid, d_rngs, tables,
+                d_src, prev_nxt, prev_sampled)
         out = (cache, nxt, accept, c_sampled)
         # a model with step counters: the chunk's rows beside the decode's
         return out if counted is None else out + (counted + c_counted,)
 
     def _decode_only_impl(self, params, cache, d_tok, d_pos, d_valid,
-                          d_rngs, tables):
+                          d_rngs, tables, d_src, prev_nxt, prev_sampled):
         """Iterations with no prefill pending skip the chunk lane's
         compute entirely (the second compiled program)."""
         with jax.named_scope("serve.decode"):
             *out, counted = self._decode_step(
-                params, cache, d_tok, d_pos, d_valid, d_rngs, tables)
+                params, cache, d_tok, d_pos, d_valid, d_rngs, tables,
+                d_src, prev_nxt, prev_sampled)
         return tuple(out) if counted is None else (*out, counted)
 
     # -- compiled pieces: legacy contiguous slots ----------------------------
@@ -811,8 +903,10 @@ class Engine:
 
     @property
     def idle(self) -> bool:
+        """Nothing queued, seated, or launched and not yet delivered."""
         return (len(self.queue) == 0 and self.scheduler.num_active == 0
-                and not self.queue.has_shed_pending)
+                and not self.queue.has_shed_pending
+                and self._in_flight is None)
 
     def _bucket(self, n: int) -> int:
         b = self.cfg.prefill_bucket
@@ -889,8 +983,18 @@ class Engine:
             if adopted or evicted:
                 self.telemetry.on_prefix_pages(inserted=len(adopted),
                                                evicted=evicted)
-        self.pool.free([p for p in pages if p not in adopted],
-                       uncommit=max(self._slot_commit_left[slot], 0))
+        release = [p for p in pages if p not in adopted]
+        uncommit = max(self._slot_commit_left[slot], 0)
+        flight = self._in_flight
+        if flight is not None and flight.lanes.get(slot) is seq:
+            # The step in flight was assembled with this sequence in it
+            # (an EOS or a deadline the host could not foresee) and still
+            # writes one row into its pages: they go back to the pool
+            # when that step has landed (_iterate_paged). What entered
+            # the trie above are full pages that row lies beyond.
+            flight.held.append((release, uncommit))
+        else:
+            self.pool.free(release, uncommit=uncommit)
         self._slot_pages[slot] = []
         self._slot_shared[slot] = 0
         self._slot_commit_left[slot] = 0
@@ -991,6 +1095,15 @@ class Engine:
             if entry is not None:
                 finished.append(
                     self._queue_evict_finish(entry, FINISH_CANCELLED))
+                continue
+            if self._in_flight is not None:
+                # A seated request leaves only with nothing in flight
+                # (_runs_in_order sees the mark and the next call lands
+                # the step first); a mark that raced this pass waits.
+                if any(q.request.uid == uid
+                       for q in self.scheduler.active()):
+                    with self._cancel_lock:
+                        self._cancel_uids.add(uid)
                 continue
             seq = self.scheduler.evict_uid(uid)
             if seq is not None:
@@ -1232,7 +1345,16 @@ class Engine:
                     freeable += 1
             headroom = (self.cfg.tier_reserved_pages
                         if req.priority > 0 else 0)
-            return self.pool.available + freeable >= need + headroom
+            if self.pool.available + freeable < need + headroom:
+                return False
+            if self._in_flight is not None:
+                # The victim has a token in flight: admission stops
+                # here, this call launches nothing and lands the step,
+                # and the next pass — nothing in flight — preempts as
+                # it always did.
+                self._preempt_due = True
+                return False
+            return True
 
         def prefix_probe(entry) -> int:
             # Cache-aware seat ordering (read-only trie walk): among
@@ -1246,6 +1368,7 @@ class Engine:
             return len(self.prefix_cache.probe(
                 toks, max_tokens=self._hit_cap(entry))) * self.page_size
 
+        self._preempt_due = False
         seated = self.scheduler.admit(
             self.queue, can_seat, on_seat=on_seat, on_preempt=on_preempt,
             preempt_helps=preempt_helps,
@@ -1309,9 +1432,13 @@ class Engine:
         t = time.perf_counter()
         self._note_first_token(seq, first, t)
 
-    def _draft_window(self, decoding):
+    def _draft_window(self, decoding, unlanded=()):
         """Assemble the [max_batch, spec_width] verify-window inputs for
         one iteration (host-side numpy, like all slot routing).
+
+        A slot in ``unlanded`` has one token more than the host has seen
+        (the step in flight emits it): its write head counts that token
+        and row 0 of its lane is left to the device (:meth:`_incoming`).
 
         Row 0 of a decoding slot's lane is its incoming token at write
         head ``p``; rows 1..useful are its drafter's proposals at
@@ -1333,7 +1460,8 @@ class Engine:
         useful_by_slot: dict[int, int] = {}
         drafted = 0
         for seq in decoding:
-            p = seq.request.prompt.size + len(seq.tokens) - 1
+            lag = seq.slot in unlanded
+            p = seq.request.prompt.size + len(seq.tokens) + lag - 1
             useful = 0
             if self.spec_k:
                 cap = seq.request.max_new_tokens - len(seq.tokens) - 1
@@ -1348,7 +1476,8 @@ class Engine:
                     np.int32).reshape(-1)
                 useful = min(useful, props.size)
                 d_tok[seq.slot, 1:1 + useful] = props[:useful]
-            d_tok[seq.slot, 0] = seq.tokens[-1]
+            if not lag:
+                d_tok[seq.slot, 0] = seq.tokens[-1]
             win_pos = p + np.arange(w)
             if self.paged:
                 win_pos = np.minimum(win_pos, self._l_all - 1)
@@ -1632,8 +1761,13 @@ class Engine:
         decode, evict.
 
         Returns the requests that finished this iteration. Safe to call
-        when idle (records an excluded gap and returns [])."""
-        self._apply_pending_swap()
+        when idle (records an excluded gap and returns []). On the paged
+        path a call delivers the tokens of one device step and, where it
+        may, has launched the next one first (:meth:`_iterate_paged`);
+        the swap barrier waits for a call that enters with nothing in
+        flight."""
+        if self._in_flight is None:
+            self._apply_pending_swap()
         return self._step_paged() if self.paged else self._step_legacy()
 
     def _step_paged(self) -> list[FinishedRequest]:
@@ -1647,16 +1781,196 @@ class Engine:
                             track="engine") as it_span:
             return self._iterate_paged(it, it_span)
 
+    def _runs_in_order(self, deadlines: bool) -> bool:
+        """True where the host is about to change what a step in flight
+        would have been assembled from, so that none may be: a drafter
+        reads the host's tokens, a swap barrier changes the weights, a
+        cancelled or an expired seated request frees a slot with a token
+        on its way (a preemption too: ``_preempt_due``, which the
+        admission pass sets). A call that finds this true with a step in
+        flight only lands it; with none it launches, fetches and commits
+        one step like the engine before it ran ahead."""
+        if self.spec_k:
+            return True
+        with self._swap_lock:
+            if self._pending_swap is not None:
+                return True
+        with self._cancel_lock:
+            marked = set(self._cancel_uids)
+        if not marked and not deadlines:
+            return False
+        now = time.perf_counter()
+        return any(q.request.uid in marked
+                   or (deadlines and q.finish_reason(None, now) is not None)
+                   for q in self.scheduler.active())
+
+    def _plan_step(self, prev: _DeviceStep | None) -> _DeviceStep | None:
+        """Assemble the device step that follows ``prev`` — a step whose
+        tokens have not landed: the host then works from counts (a slot
+        of ``prev``'s decode lane will hold one token more, its chunk's
+        slot ``prev.c`` positions more) — or, with None, the state as
+        committed. None when no lane would run."""
+        s = self.cfg.max_batch
+        allocated = self.pool.num_allocated
+        src: dict[int, int] = {}        # slot -> where its token comes from
+        decoding, prefilling, filled = [], [], {}
+        for seq in self.scheduler.active():
+            n, pos = len(seq.tokens), seq.prefill_pos
+            if prev is not None and prev.lanes.get(seq.slot) is seq:
+                if seq is prev.chunk_seq:
+                    pos = prev.start + prev.c
+                    if pos == seq.prefill_tokens.size and not n:
+                        # the final chunk's last valid row is its first
+                        # token (a resumption's is already the host's)
+                        n, src[seq.slot] = 1, _SRC_CHUNK + prev.c - 1
+                else:
+                    n, src[seq.slot] = n + 1, _SRC_NXT
+                if n >= seq.request.max_new_tokens:
+                    # The token in flight is its last: it takes no part
+                    # in this step, and its slot and pages are free when
+                    # that token lands — its successor is seated one
+                    # iteration later than with nothing in flight.
+                    continue
+            if pos < seq.prefill_tokens.size or not n:
+                prefilling.append(seq)
+                filled[seq.slot] = pos
+            else:
+                decoding.append(seq)
+        # Oldest prefilling request first (seat order == arrival
+        # order): one chunk per iteration keeps the fused step's
+        # shape fixed and admission FIFO-fair.
+        chunk_seq = min(prefilling, key=lambda q: q.request.uid,
+                        default=None)
+        if chunk_seq is None and not decoding:
+            return None
+        # Verify-window assembly (plain one-token decode when
+        # spec_k=0): incoming token + drafts per decoding slot;
+        # pages ensured only for the VALID width, so speculation
+        # draws nothing beyond the admission commitment. A row
+        # assembled for a request whose token in flight turns out to be
+        # an EOS writes inside that request's own commitment (it was not
+        # at its length limit) and is dropped when it lands.
+        d_tok, d_pos, d_valid, useful_by_slot, drafted = \
+            self._draft_window(decoding, src)
+        d_src = np.zeros((s,), np.int32)
+        rows_live = []
+        for seq in decoding:
+            d_src[seq.slot] = src.get(seq.slot, _SRC_HOST)
+            # Write positions of this window = tokens already
+            # cached (prompt + generated minus the uncached last)
+            # through the last valid draft row.
+            rows = (seq.request.prompt.size + len(seq.tokens)
+                    + (seq.slot in src) + useful_by_slot[seq.slot])
+            self._ensure_pages(seq.slot, rows)
+            rows_live.append(rows)
+        start = c = 0
+        chunk = ()
+        if chunk_seq is not None:
+            # prefill_tokens == the prompt for a fresh seat; for
+            # a resumption it carries prompt + emitted-minus-last,
+            # so the re-prefill rebuilds exactly the cache prefix
+            # the preemption freed (same positions, same fold_in
+            # RNG).
+            pre_toks = chunk_seq.prefill_tokens
+            start = filled[chunk_seq.slot]
+            c = min(self.prefill_chunk, pre_toks.size - start)
+            self._ensure_pages(chunk_seq.slot, start + c)
+            cw = self.prefill_chunk
+            c_tok = np.full((cw,), self.sample_cfg.pad_id, np.int32)
+            c_pos = np.zeros((cw,), np.int32)
+            c_valid = np.zeros((cw,), bool)
+            c_tok[:c] = pre_toks[start:start + c]
+            c_pos[:c] = np.arange(start, start + c)
+            c_valid[:c] = True
+            chunk = (c_tok, c_pos, c_valid)
+        # What this step's attention has to read: the pages the live
+        # slots' positions cover (each decoding slot through its
+        # window's last valid row, the chunk's slot through the chunk's
+        # last row), beside the fixed budget the gather formulation
+        # reads whatever is live; and what the decoding slots' queries
+        # read of their live rows (a model with a learned selection
+        # reads fewer than all).
+        pages_live = sum(pages_for(r, self.page_size) for r in rows_live)
+        if chunk_seq is not None:
+            pages_live += pages_for(start + c, self.page_size)
+        lanes = {q.slot: q for q in decoding}
+        if chunk_seq is not None:
+            lanes[chunk_seq.slot] = chunk_seq
+        return _DeviceStep(
+            program="fused" if chunk_seq is not None else "decode",
+            decoding=decoding, d_tok=d_tok, d_pos=d_pos, d_valid=d_valid,
+            d_src=d_src, useful_by_slot=useful_by_slot, drafted=drafted,
+            chunk_seq=chunk_seq, start=start, c=c, chunk=chunk,
+            lanes=lanes,
+            pages_held={slot: len(self._slot_pages[slot])
+                        for slot in lanes},
+            pages_drawn=self.pool.num_allocated - allocated,
+            attrs=dict(
+                kv_pages_live=pages_live,
+                kv_pages_budget=s * self.pages_per_slot,
+                kv_rows_live=sum(rows_live),
+                kv_rows_selected=sum(map(self.model.attended_rows,
+                                         rows_live))))
+
+    def _launch(self, step: _DeviceStep, prev: _DeviceStep | None) -> None:
+        """Upload ``step``'s inputs and launch its program behind
+        ``prev``'s on the device; nothing is fetched. ``prev``'s outputs
+        go in as they came out: the device picks a slot's incoming token
+        from them where the host could not (``d_src``)."""
+        prev_nxt, prev_sampled = self._no_tokens
+        if prev is not None:
+            prev_nxt = prev.nxt
+            if prev.c_sampled is not None:
+                prev_sampled = prev.c_sampled
+        step.t0 = time.perf_counter()
+        lane = (jnp.asarray(step.d_tok), jnp.asarray(step.d_pos),
+                jnp.asarray(step.d_valid), jnp.asarray(self._slot_rng),
+                jnp.asarray(self._tables))
+        came_before = (jnp.asarray(step.d_src), prev_nxt, prev_sampled)
+        if step.chunk_seq is not None:
+            slot = step.chunk_seq.slot
+            (self._cache, step.nxt, step.acc, step.c_sampled,
+             *counted) = self._fused(
+                self.params, self._cache, *lane,
+                *map(jnp.asarray, step.chunk),
+                jnp.asarray(self._tables[slot][None]),
+                jnp.asarray(self._slot_rng[slot]), *came_before)
+        else:
+            self._cache, step.nxt, step.acc, *counted = self._decode(
+                self.params, self._cache, *lane, *came_before)
+        if counted:
+            step.counted = counted[0]
+        # What the host will fetch starts for the host the moment the
+        # step ends, not when the host asks: by then the copy has landed.
+        for out in (step.nxt, step.acc, step.c_sampled, step.counted):
+            if out is not None:
+                out.copy_to_host_async()
+
     def _iterate_paged(self, it: int, it_span) -> list[FinishedRequest]:
+        """One call's work, one device step ahead of the host where it
+        may be: entered with step *k* in flight, assemble and launch
+        *k + 1*, and only then fetch, commit and deliver *k*'s tokens —
+        the chip starts *k + 1* the moment *k* ends, and this call's
+        commit and finish and the next call's admit, assemble and
+        dispatch run beside it. Entered with nothing in flight, launch
+        *k* first (then *k + 1*, then fetch *k*): the in-order iteration
+        is this one where :meth:`_runs_in_order` lets nothing stay in
+        flight."""
         span = trace_lib.span
         eos = self.sample_cfg.eos_id
         deadlines = (self.cfg.ttft_deadline_ms is not None
                      or self.cfg.deadline_ms is not None)
         finished: list[FinishedRequest] = []
+        step = self._in_flight
+        # A step in flight and the world about to change: land it, and
+        # act in the next call on a state with nothing on its way.
+        in_order = self._runs_in_order(deadlines)
+        land_only = step is not None and in_order
         with span("serve.admit"):
-            if deadlines:
-                self._expire_queue(finished, time.perf_counter())
-            self._cancel_pass(finished)
+            if not land_only:
+                if deadlines:
+                    self._expire_queue(finished, time.perf_counter())
+                self._cancel_pass(finished)
 
             had_work = not self.idle
             if had_work:
@@ -1665,10 +1979,13 @@ class Engine:
             # seat in tier-strict tenant-fair order when the pool can
             # commit their worst case; a blocked higher tier preempts the
             # worst lower-tier active sequence instead of waiting behind
-            # it. Seating costs NO device work here; the prompt (or a
-            # resumption's carried prefix) prefills chunk-by-chunk below,
-            # riding the decode iterations.
-            self._admit_pass(finished)
+            # it (with a step in flight: at the next call, once it has
+            # landed). Seating costs NO device work here; the prompt (or
+            # a resumption's carried prefix) prefills chunk-by-chunk,
+            # riding the decode iterations. A slot whose last token is
+            # in flight is free at the next call's pass.
+            if not land_only:
+                self._admit_pass(finished)
             # Head-of-line blocking: anything still queued after the
             # admission pass is blocked on a slot OR on pool pages until
             # the next boundary — bill the rest of this iteration as
@@ -1676,221 +1993,189 @@ class Engine:
             # from "all slots busy" to "cannot seat").
             blocked_t0 = (time.perf_counter() if len(self.queue) > 0
                           else None)
+            live = self.scheduler.num_active
 
-            active_seqs = self.scheduler.active()
-            decoding = [s for s in active_seqs if not s.prefilling]
-            prefilling = [s for s in active_seqs if s.prefilling]
-            # Oldest prefilling request first (seat order == arrival
-            # order): one chunk per iteration keeps the fused step's
-            # shape fixed and admission FIFO-fair.
-            chunk_seq = min(prefilling, key=lambda s: s.request.uid,
-                            default=None)
-            program = ("fused" if chunk_seq is not None
-                       else "decode" if decoding else "idle")
-            it_span.attrs.update(live=len(active_seqs),
-                                 queued=len(self.queue), program=program)
-
-        if program != "idle":
+        launches: list[_DeviceStep] = []
+        prev, successor = step, None
+        if step is not None or live:
             with span("serve.assemble") as asm_span:
-                # Verify-window assembly (plain one-token decode when
-                # spec_k=0): incoming token + drafts per decoding slot;
-                # pages ensured only for the VALID width, so speculation
-                # draws nothing beyond the admission commitment.
-                d_tok, d_pos, d_valid, useful_by_slot, drafted = \
-                    self._draft_window(decoding)
-                for seq in decoding:
-                    # Write positions of this window = tokens already
-                    # cached (prompt + generated minus the uncached last)
-                    # through the last valid draft row.
-                    p = seq.request.prompt.size + len(seq.tokens) - 1
-                    self._ensure_pages(
-                        seq.slot, p + useful_by_slot[seq.slot] + 1)
-                if self.spec_k and decoding:
+                if step is None:
+                    step = self._plan_step(None)
+                    if step is not None:
+                        launches.append(step)
+                if (step is not None and not in_order
+                        and not self._preempt_due):
+                    successor = self._plan_step(step)
+                    if successor is not None:
+                        launches.append(successor)
+                if self.spec_k and step is not None and step.decoding:
                     # Proposal assembly (host); its verification is the
                     # device step's (serve.verify, below).
                     trace_lib.record(
                         "serve.draft", asm_span.t0, time.perf_counter(),
-                        tokens=drafted, slots=len(decoding))
-                c = 0
-                if chunk_seq is not None:
-                    # prefill_tokens == the prompt for a fresh seat; for
-                    # a resumption it carries prompt + emitted-minus-last,
-                    # so the re-prefill rebuilds exactly the cache prefix
-                    # the preemption freed (same positions, same fold_in
-                    # RNG).
-                    pre_toks = chunk_seq.prefill_tokens
-                    n = pre_toks.size
-                    start = chunk_seq.prefill_pos
-                    c = min(self.prefill_chunk, n - start)
-                    self._ensure_pages(chunk_seq.slot, start + c)
-                    cw = self.prefill_chunk
-                    c_tok = np.full((cw,), self.sample_cfg.pad_id,
-                                    np.int32)
-                    c_pos = np.zeros((cw,), np.int32)
-                    c_valid = np.zeros((cw,), bool)
-                    c_tok[:c] = pre_toks[start:start + c]
-                    c_pos[:c] = np.arange(start, start + c)
-                    c_valid[:c] = True
-            # What this iteration's attention has to read: the pages the
-            # live slots' positions cover (each decoding slot through its
-            # window's last valid row, the chunk's slot through the
-            # chunk's last row), beside the fixed budget the gather
-            # formulation reads whatever is live.
-            rows_live = [q.request.prompt.size + len(q.tokens)
-                         + useful_by_slot[q.slot] for q in decoding]
-            if chunk_seq is not None:
-                rows_live.append(chunk_seq.prefill_pos + c)
-            pages_live = sum(pages_for(r, self.page_size)
-                             for r in rows_live)
-            pages_budget = self.cfg.max_batch * self.pages_per_slot
-            # What the decoding slots' queries read of their live rows
-            # (a model with a learned selection reads fewer than all).
-            rows_dec = rows_live[:len(decoding)]
-            it_span.attrs.update(
-                kv_pages_live=pages_live, kv_pages_budget=pages_budget,
-                kv_rows_live=sum(rows_dec),
-                kv_rows_selected=sum(map(self.model.attended_rows,
-                                         rows_dec)))
-            with span("serve.device_step", program=program) as dev_span:
-                with span("serve.dispatch",
-                          uploads=10 if chunk_seq is not None else 5):
-                    if chunk_seq is not None:
-                        (self._cache, nxt, acc, c_sampled,
-                         *counted) = self._fused(
-                            self.params, self._cache, jnp.asarray(d_tok),
-                            jnp.asarray(d_pos), jnp.asarray(d_valid),
-                            jnp.asarray(self._slot_rng),
-                            jnp.asarray(self._tables), jnp.asarray(c_tok),
-                            jnp.asarray(c_pos), jnp.asarray(c_valid),
-                            jnp.asarray(self._tables[chunk_seq.slot][None]),
-                            jnp.asarray(self._slot_rng[chunk_seq.slot]))
-                    else:
-                        self._cache, nxt, acc, *counted = self._decode(
-                            self.params, self._cache, jnp.asarray(d_tok),
-                            jnp.asarray(d_pos), jnp.asarray(d_valid),
-                            jnp.asarray(self._slot_rng),
-                            jnp.asarray(self._tables))
+                        tokens=step.drafted, slots=len(step.decoding))
+        # `program` and the step's counters are those of the step whose
+        # tokens this call delivers; `ahead` says whether its successor
+        # was launched before they were fetched.
+        it_span.attrs.update(
+            live=live, queued=len(self.queue),
+            program="idle" if step is None else step.program)
+        if step is not None:
+            ahead = int(successor is not None)
+            self._iters_working += 1
+            self._iters_ahead += ahead
+            it_span.attrs.update(step.attrs, ahead=ahead)
+            with span("serve.device_step",
+                      program=step.program) as dev_span:
+                if launches:
+                    with span("serve.dispatch",
+                              uploads=sum(11 if q.chunk_seq is not None
+                                          else 6 for q in launches)):
+                        for q in launches:
+                            self._launch(q, prev)
+                            prev = q
                 with span("serve.token_wait"):
-                    # graftlint: disable=hot-path-transfer -- THE per-iteration sync: tokens must land (docs/SERVING.md)
-                    toks = np.asarray(nxt)
-                    # graftlint: disable=hot-path-transfer -- per-slot accept lengths ride the same iteration sync
-                    accepts = np.asarray(acc)
-                    if counted:
-                        # graftlint: disable=hot-path-transfer -- the model's step counters, a few int32 in the same iteration sync
-                        counts = np.asarray(counted[0])
+                    # graftlint: disable=hot-path-transfer -- the iteration's one wait for the device: this step's tokens must land (docs/SERVING.md); its successor is already queued behind it
+                    toks = np.asarray(step.nxt)
+                    # graftlint: disable=hot-path-transfer -- per-slot accept lengths ride the same fetch
+                    accepts = np.asarray(step.acc)
+                    if step.counted is not None:
+                        # graftlint: disable=hot-path-transfer -- the model's step counters, a few int32 in the same fetch
+                        counts = np.asarray(step.counted)
                         it_span.attrs.update(zip(self._step_counters,
                                                  map(int, counts)))
+            self._in_flight = successor
             # The tokens' landing time: every ledger stamp, TTFT and
             # deadline below reads this one clock value.
             t = dev_span.t1
             with span("serve.commit"):
-                emitted, accepted = self._apply_accepts(
-                    decoding, toks, accepts, useful_by_slot, t)
-                if self.spec_k:
-                    # Host-side accept/rewind bookkeeping cost, attributed
-                    # explicitly like admission_blocked_s/swap_blocked_s —
-                    # and billed to each decoding request's ledger as
-                    # 'spec_rollback' (the batch shares the wall window).
-                    t_roll = time.perf_counter()
-                    for seq in decoding:
-                        if seq.request.ledger is not None:
-                            seq.request.ledger.stamp(CAUSE_SPEC_ROLLBACK,
-                                                     t_roll)
-                    self.telemetry.on_spec(
-                        drafted=drafted, accepted=accepted,
-                        rollback_s=t_roll - t)
-                    if decoding:
-                        # The batched target dispatch that verified the
-                        # drafts; the per-slot accept marks land in
-                        # _apply_accepts.
-                        trace_lib.record(
-                            "serve.verify", dev_span.t0, t,
-                            drafted=drafted, accepted=accepted)
-                self.telemetry.on_decode(lanes=len(decoding),
-                                         tokens=emitted)
-                self.telemetry.on_tokens(emitted, t)
-                if chunk_seq is not None:
-                    start = chunk_seq.prefill_pos
-                    chunk_seq.prefill_pos = start + c
-                    # Ledger chunk boundary: this iteration's span (chunk-
-                    # lane wait included) and the cache positions the
-                    # chunk wrote. Positions the chunk REwrites (the
-                    # sequence's recompute debt from preemptions/crashes)
-                    # bill to 'recompute'; first-time writes bill to
-                    # 'prefill' — so the token split mirrors the engine's
-                    # recompute counters exactly. The wall span takes the
-                    # chunk's dominant cause.
-                    led = chunk_seq.request.ledger
-                    if led is not None:
-                        rec = min(c, chunk_seq.recompute_owed)
-                        chunk_seq.recompute_owed -= rec
-                        # Recovery-attribution share never exceeds the
-                        # remaining debt (prefix-hit credit bookkeeping).
-                        chunk_seq.recovery_owed = min(
-                            chunk_seq.recovery_owed,
-                            chunk_seq.recompute_owed)
-                        if rec:
-                            led.add_tokens(CAUSE_RECOMPUTE, rec)
-                        if c - rec:
-                            led.add_tokens(CAUSE_PREFILL, c - rec)
-                        led.stamp(CAUSE_RECOMPUTE if rec * 2 >= c
-                                  else CAUSE_PREFILL, t)
-                    trace_lib.record(
-                        "serve.prefill_chunk", dev_span.t0, t,
-                        key=chunk_seq.request.uid,
-                        track=f"slot {chunk_seq.slot}",
-                        trace=chunk_seq.request.trace_id,
-                        # graftlint: disable=hot-path-transfer -- host ints for JSON trace args
-                        start=int(start), tokens=int(c))
-                    if (chunk_seq.prefill_pos
-                            == chunk_seq.prefill_tokens.size):
-                        if chunk_seq.tokens:
-                            # Resumed mid-decode: the final chunk's sample
-                            # recomputes the last emitted token bitwise
-                            # (same logits row, same fold_in position) —
-                            # it was already emitted before the
-                            # preemption, so nothing lands; the slot just
-                            # resumes decoding with it as the incoming
-                            # token.
-                            pass
-                        else:
-                            # Final chunk: its last valid row is the
-                            # request's first token (same RNG fold and
-                            # logits row as a full-prompt prefill).
-                            # graftlint: disable=hot-path-transfer -- the deliberate sync: the chunked-path TTFT measurement point
-                            first = int(np.asarray(c_sampled)[c - 1])
-                            self._note_first_token(chunk_seq, first, t)
-                # KV utilization, host-side only: reserved = pages
-                # actually held by occupied slots (the paged win — compare
-                # the legacy path's active × full budget), written = live
-                # cache positions, both reconstructed without a device
-                # read.
-                counted = decoding + ([chunk_seq] if chunk_seq is not None
-                                      else [])
-                reserved = sum(len(self._slot_pages[q.slot])
-                               for q in counted) * self.page_size
-                written = sum(q.request.prompt.size + len(q.tokens) - 1
-                              for q in decoding)
-                if chunk_seq is not None:
-                    written += chunk_seq.prefill_pos
-                self.telemetry.on_kv(
-                    reserved=reserved, written=written,
-                    active=len(counted), slots=self.cfg.max_batch,
-                    pages_allocated=self.pool.num_allocated,
-                    pages_total=self.pool.num_pages,
-                    pages_live=pages_live, pages_budget=pages_budget)
+                self._commit_step(step, toks, accepts, t)
                 if blocked_t0 is not None:
                     self.telemetry.on_admission_blocked(t - blocked_t0)
-                if self.trace is not None:
-                    self.trace.counter("active_slots", len(counted))
-                    self.trace.counter("kv_written_tokens", written)
-                    self.trace.counter("kv_pages_allocated",
-                                       self.pool.num_allocated)
                 finished.extend(self.scheduler.evict_finished(
                     eos, now=t if deadlines else None))
 
         with span("serve.finish"):
             return self._finish_iteration(it, had_work, finished)
+
+    def _commit_step(self, step: _DeviceStep, toks, accepts,
+                     t: float) -> None:
+        """Land one device step on the host's state at landing time
+        ``t``: tokens, chunk progress, first token, ledgers, KV
+        accounting. A lane whose sequence has left its slot since the
+        step was assembled (an EOS or a deadline in the step before it)
+        computed a row nobody waits for: it is dropped, and the pages it
+        wrote go back to the pool now that the step has landed."""
+        for pages, uncommit in step.held:
+            self.pool.free(pages, uncommit=uncommit)
+        decoding = [q for q in step.decoding
+                    if self._slot_seq[q.slot] is q]
+        chunk_seq = step.chunk_seq
+        if chunk_seq is not None \
+                and self._slot_seq[chunk_seq.slot] is not chunk_seq:
+            chunk_seq = None
+        emitted, accepted = self._apply_accepts(
+            decoding, toks, accepts, step.useful_by_slot, t)
+        if self.spec_k:
+            # Host-side accept/rewind bookkeeping cost, attributed
+            # explicitly like admission_blocked_s/swap_blocked_s —
+            # and billed to each decoding request's ledger as
+            # 'spec_rollback' (the batch shares the wall window).
+            t_roll = time.perf_counter()
+            for seq in decoding:
+                if seq.request.ledger is not None:
+                    seq.request.ledger.stamp(CAUSE_SPEC_ROLLBACK, t_roll)
+            self.telemetry.on_spec(
+                drafted=step.drafted, accepted=accepted,
+                rollback_s=t_roll - t)
+            if decoding:
+                # The batched target dispatch that verified the
+                # drafts; the per-slot accept marks land in
+                # _apply_accepts.
+                trace_lib.record(
+                    "serve.verify", step.t0, t,
+                    drafted=step.drafted, accepted=accepted)
+        self.telemetry.on_decode(lanes=len(decoding), tokens=emitted)
+        self.telemetry.on_tokens(emitted, t)
+        if chunk_seq is not None:
+            start, c = step.start, step.c
+            chunk_seq.prefill_pos = start + c
+            # Ledger chunk boundary: this iteration's span (chunk-
+            # lane wait included) and the cache positions the
+            # chunk wrote. Positions the chunk REwrites (the
+            # sequence's recompute debt from preemptions/crashes)
+            # bill to 'recompute'; first-time writes bill to
+            # 'prefill' — so the token split mirrors the engine's
+            # recompute counters exactly. The wall span takes the
+            # chunk's dominant cause.
+            led = chunk_seq.request.ledger
+            if led is not None:
+                rec = min(c, chunk_seq.recompute_owed)
+                chunk_seq.recompute_owed -= rec
+                # Recovery-attribution share never exceeds the
+                # remaining debt (prefix-hit credit bookkeeping).
+                chunk_seq.recovery_owed = min(
+                    chunk_seq.recovery_owed,
+                    chunk_seq.recompute_owed)
+                if rec:
+                    led.add_tokens(CAUSE_RECOMPUTE, rec)
+                if c - rec:
+                    led.add_tokens(CAUSE_PREFILL, c - rec)
+                led.stamp(CAUSE_RECOMPUTE if rec * 2 >= c
+                          else CAUSE_PREFILL, t)
+            trace_lib.record(
+                "serve.prefill_chunk", step.t0, t,
+                key=chunk_seq.request.uid,
+                track=f"slot {chunk_seq.slot}",
+                trace=chunk_seq.request.trace_id,
+                # graftlint: disable=hot-path-transfer -- host ints for JSON trace args
+                start=int(start), tokens=int(c))
+            if (chunk_seq.prefill_pos
+                    == chunk_seq.prefill_tokens.size):
+                if chunk_seq.tokens:
+                    # Resumed mid-decode: the final chunk's sample
+                    # recomputes the last emitted token bitwise
+                    # (same logits row, same fold_in position) —
+                    # it was already emitted before the
+                    # preemption, so nothing lands; the slot just
+                    # resumes decoding with it as the incoming
+                    # token.
+                    pass
+                else:
+                    # Final chunk: its last valid row is the
+                    # request's first token (same RNG fold and
+                    # logits row as a full-prompt prefill).
+                    # graftlint: disable=hot-path-transfer -- the deliberate sync: the chunked-path TTFT measurement point
+                    first = int(np.asarray(step.c_sampled)[c - 1])
+                    self._note_first_token(chunk_seq, first, t)
+        # KV utilization, host-side only: reserved = pages
+        # held by occupied slots as this step was assembled (the paged
+        # win — compare the legacy path's active × full budget),
+        # written = live cache positions, both reconstructed without a
+        # device read. The pool's allocation is counted as this step
+        # needed it: pages the step ahead has drawn count with that one.
+        counted = decoding + ([chunk_seq] if chunk_seq is not None
+                              else [])
+        reserved = sum(step.pages_held[q.slot]
+                       for q in counted) * self.page_size
+        written = sum(q.request.prompt.size + len(q.tokens) - 1
+                      for q in decoding)
+        if chunk_seq is not None:
+            written += chunk_seq.prefill_pos
+        self.telemetry.on_kv(
+            reserved=reserved, written=written,
+            active=len(counted), slots=self.cfg.max_batch,
+            pages_allocated=self.pool.num_allocated - (
+                self._in_flight.pages_drawn
+                if self._in_flight is not None else 0),
+            pages_total=self.pool.num_pages,
+            pages_live=step.attrs["kv_pages_live"],
+            pages_budget=step.attrs["kv_pages_budget"])
+        if self.trace is not None:
+            self.trace.counter("active_slots", len(counted))
+            self.trace.counter("kv_written_tokens", written)
+            self.trace.counter("kv_pages_allocated",
+                               self.pool.num_allocated)
 
     def _step_legacy(self) -> list[FinishedRequest]:
         it = self._iteration
@@ -2271,6 +2556,10 @@ class Engine:
         self.recovery_report = report
         if self.journal is None:
             return report
+        if self._in_flight is not None:
+            raise RuntimeError(
+                "recover() replays the journal before the engine serves: "
+                "a device step is in flight")
         self._recovering = True
         try:
             state = self.journal.recover()
@@ -2551,6 +2840,11 @@ class Engine:
         stats["alerts_active"] = len(self.alerts.active)
         stats["incidents_captured"] = (
             self.incidents.captured if self.incidents is not None else 0)
+        # Of the working iterations, those whose step had its successor
+        # launched before its tokens were fetched (_iterate_paged).
+        stats["run_ahead_share"] = (
+            self._iters_ahead / self._iters_working
+            if self._iters_working else 0.0)
         return stats
 
     def reset_stats(self) -> None:
@@ -2588,6 +2882,7 @@ class Engine:
         self.timeseries = TelemetryRing(self.cfg.timeseries_capacity,
                                         self.cfg.sample_every)
         self._iteration = 0
+        self._iters_working = self._iters_ahead = 0
 
     def _control_room_sections(self) -> dict[str, Any]:
         """The ``alerts`` + ``timeseries`` top-level sections flight

@@ -249,8 +249,10 @@ class TestProgramSpans:
         assert names["train.batch_wait"] == 5      # four batches, the end
         assert names["train.metrics_fetch"] == 2
         assert names["serve.iteration"] >= 3
-        assert names["serve.device_step"] == names["serve.dispatch"] \
-            == names["serve.token_wait"] >= 3
+        assert names["serve.device_step"] == names["serve.token_wait"] >= 3
+        # one dispatch span a call for the step(s) it launches ahead; the
+        # call that lands a stream's last step launches nothing
+        assert 3 <= names["serve.dispatch"] <= names["serve.device_step"]
 
     @pytest.mark.parametrize("parent", sorted(NESTING))
     def test_children_lie_inside_their_parents_on_that_clock(

@@ -446,6 +446,7 @@ class TestCrashRecovery:
             eng.step()
         assert len(eng.scheduler.sequence(0).tokens) >= 1
         high = eng.submit(prompts[1], priority=0, max_new_tokens=4)
+        eng.step()  # lands the step in flight: the preemption waits
         eng.step()  # the preemption pass: low requeues mid-flight
         assert eng.stats()["requests_preempted"] == 1
         eng.journal.persist()

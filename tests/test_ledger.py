@@ -325,7 +325,8 @@ class TestChaosCompositions:
         eng.step()  # one 4-token chunk written, 12 to go
         seq = eng.scheduler.sequence(0)
         assert seq.prefilling and 0 < seq.prefill_pos < 16
-        written = seq.prefill_pos
+        # the chunk in flight lands before the victim leaves
+        written = seq.prefill_pos + eng.prefill_chunk
         eng.submit(rng.randint(0, VOCAB, size=3).astype(np.int32),
                    priority=0, max_new_tokens=2)
         done = eng.run()

@@ -201,7 +201,11 @@ class TestDrainAndDeadlines:
         for _ in range(4):
             eng.step()
         eng.submit(prompts[1], priority=0, max_new_tokens=4)
-        # Force the preemption pass (the high arrival preempts low).
+        # Force the preemption pass (the high arrival preempts low): the
+        # engine runs one device step ahead, so the first call lands
+        # the step in flight and the second, with nothing on its way,
+        # preempts.
+        eng.step()
         eng.step()
         assert eng.stats()["requests_preempted"] == 1
         done = {f.uid: f for f in eng.drain()}
@@ -227,10 +231,11 @@ class TestDrainAndDeadlines:
         low = eng.submit(prompts[0], priority=1)
         for _ in range(3):
             eng.step()
+        eng.submit(prompts[1], priority=0, max_new_tokens=8)
+        eng.step()  # lands the step in flight: the preemption waits
         emitted_before = len(eng.scheduler.sequence(0).tokens)
         assert emitted_before >= 1
-        eng.submit(prompts[1], priority=0, max_new_tokens=8)
-        eng.step()  # preempts low
+        eng.step()  # nothing in flight: preempts low
         assert eng.stats()["requests_preempted"] == 1
         entry = eng.queue.peek()
         assert isinstance(entry, ActiveSequence)
@@ -538,7 +543,11 @@ class TestServeBenchOverloadCli:
         rate under the deterministic --virtual-dt drive. Tier 0 must
         finish everything it submitted un-shed while tier 1 absorbs the
         shed/preempt pressure, and the SLA line must carry the per-tier
-        keys the bench gate diffs."""
+        keys the bench gate diffs. A call releases 1.8 ms of arrivals
+        (2 before the engine ran one step ahead): a request of 8 tokens
+        now holds its slot nine calls, not eight — its successor is
+        seated one iteration after its last token lands — and at 2 ms
+        the best-effort tier is shed before any of it is served."""
         from conftest import load_cli_module
 
         bench = load_cli_module("tools/serve_bench.py")
@@ -548,7 +557,7 @@ class TestServeBenchOverloadCli:
             "--num-heads", "2", "--hidden-dim", "32",
             "--model-max-len", "64", "--prompt-len", "8",
             "--max-new-tokens", "8", "--prefill-chunk", "8",
-            "--scenario", "two_tier_burst", "--virtual-dt", "2",
+            "--scenario", "two_tier_burst", "--virtual-dt", "1.8",
             "--max-queue-depth", "6"])
         assert bench.main() == 0
         stats = json.loads(

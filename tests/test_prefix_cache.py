@@ -329,7 +329,11 @@ class TestPreemptAndRestore:
                               temperature=temp, max_new_tokens=8,
                               prefix_cache=prefix_cache)
             low = eng.submit(PREAMBLE, priority=1, max_new_tokens=8)
-            for _ in range(8):  # finish prefill, emit a few tokens
+            # finish prefill, emit a few tokens: seven calls deliver seven
+            # steps and leave the eighth in flight, which lands before
+            # the preemption — the victim leaves where eight in-order
+            # calls left it
+            for _ in range(7):
                 eng.step()
             assert len(eng.scheduler.sequence(0).tokens) >= 1
             high = eng.submit(np.asarray([2, 4, 6], np.int32),
@@ -368,6 +372,7 @@ class TestSwapFlush:
         eng.step()
         assert eng.stats()["prefix_cache_hit_tokens"] == 20
         eng.arm_swap(params2, epoch=1)
+        eng.step()  # lands the step in flight: the barrier waits
         eng.step()  # barrier: trie flushed, epoch bumped
         assert eng.prefix_cache.num_pages == 0
         fins = eng.run()  # old-epoch sequence finishes under new weights
