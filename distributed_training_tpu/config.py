@@ -14,6 +14,7 @@ DeepSpeed-style JSON dict.
 
 from __future__ import annotations
 
+import argparse
 import dataclasses
 import warnings
 from typing import Any, Mapping, Sequence
@@ -428,6 +429,15 @@ class ChaosConfig:
                 f"{self.torn_truncate_bytes}")
 
 
+def kv_page_size_arg(text: str) -> int:
+    """argparse ``type`` of the serving CLIs' ``--kv-page-size``: a value
+    :class:`ServeConfig` refuses is a parser error in its words."""
+    try:
+        return ServeConfig(kv_page_size=int(text)).kv_page_size
+    except ValueError as e:
+        raise argparse.ArgumentTypeError(str(e)) from None
+
+
 @dataclasses.dataclass(frozen=True)
 class ServeConfig:
     """Continuous-batching inference engine knobs (``serving/``).
@@ -460,29 +470,21 @@ class ServeConfig:
     # each slot holds a static-shape page table; pages allocate on
     # demand as the write head advances, so a request only ever holds
     # ceil(written/kv_page_size) pages instead of the full max_len
-    # budget. None → the legacy contiguous per-slot reservation (and the
-    # legacy bucketed batch-1 prefill below). Trade-off: smaller pages
+    # budget. Trade-off: smaller pages
     # track the write head tighter (reserved/written → 1) but mean more
     # table entries and a finer-grained gather; larger pages amortize
     # both at the cost of tail-page waste ~ page_size/2 per sequence.
-    kv_page_size: int | None = 8
+    kv_page_size: int = 8
     # Pool size in pages. None → max_batch × ceil(budget/kv_page_size)
-    # (exactly the legacy capacity, no oversubscription); smaller values
+    # (every slot's full budget, no oversubscription); smaller values
     # oversubscribe — admission then gates on committed pages, so a
     # burst of long requests queues instead of overflowing.
     kv_pages: int | None = None
-    # Chunked prefill (Sarathi-style; paged mode only): prompts prefill
+    # Chunked prefill (Sarathi-style): prompts prefill
     # in fixed-size chunks that ride along with decode iterations in ONE
     # fused compiled step, so admission never serializes ahead of
     # decode. One chunk (oldest prefilling request first) per iteration.
     prefill_chunk: int = 64
-    # LEGACY prefill path (kv_page_size=None): prompts pad up to a
-    # multiple of this for batch-1 prefill, so the engine compiles at
-    # most max_len/prefill_bucket prefill programs instead of one per
-    # distinct prompt length. Pad K/V writes are zeroed and the write
-    # head rewound to the true length, so padding never changes a
-    # single emitted token (pinned by tests/test_serving.py).
-    prefill_bucket: int = 64
     # SLA telemetry: flight-recorder ring size (one entry per decode
     # iteration) and iterations between metric flushes into it.
     ring_size: int = 4096
@@ -546,7 +548,7 @@ class ServeConfig:
     tenant_weights: dict | None = None
     # Overload headroom reserved for tier 0: requests of priority > 0
     # only seat while MORE than tier_reserved_slots slots are free, and
-    # (paged engine) only while committing them would leave at least
+    # only while committing them would leave at least
     # tier_reserved_pages pool pages uncommitted — so a high-tier
     # arrival finds capacity without even needing a preemption. Tier 0
     # ignores both reserves.
@@ -590,10 +592,7 @@ class ServeConfig:
     # prefills only that tail — shared system prompts and few-shot
     # preambles prefill ONCE. Bitwise-neutral by construction: a hit
     # changes prefill work, never a token (pinned by
-    # tests/test_prefix_cache.py). Requires the paged cache
-    # (kv_page_size set); the Engine refuses the combination with the
-    # legacy contiguous path, whose monolithic slot reservation has
-    # nothing to alias.
+    # tests/test_prefix_cache.py).
     prefix_cache: bool = False
     # Cap on pages the trie may hold (None = bounded only by the pool;
     # LRU leaves evict past the cap). Smaller caps bound the resident
@@ -619,8 +618,7 @@ class ServeConfig:
     # KV bytes/token vs fp32, so the same kv_pages HBM holds ~4x the
     # tokens; prefix-cache/preemption/journal/speculation operate on
     # quantized pages unchanged (content addressing is host-token-
-    # keyed). Requires the paged cache (kv_page_size set): the legacy
-    # contiguous path keeps full-precision slots.
+    # keyed).
     kv_dtype: str | None = None
     # Serving control room (serving/timeseries.py + serving/alerts.py;
     # docs/OBSERVABILITY.md "Serving SLO alerting & incident capture").
@@ -661,29 +659,18 @@ class ServeConfig:
         if self.max_new_tokens < 1:
             raise ValueError(
                 f"max_new_tokens must be >= 1, got {self.max_new_tokens}")
-        if self.prefill_bucket < 1:
+        if not isinstance(self.kv_page_size, int) or self.kv_page_size < 1:
             raise ValueError(
-                f"prefill_bucket must be >= 1, got {self.prefill_bucket}")
-        if self.kv_page_size is not None and self.kv_page_size < 1:
+                f"kv_page_size must be an integer >= 1, got "
+                f"{self.kv_page_size!r}: the contiguous-slot engine "
+                f"(kv_page_size=None, --kv-page-size 0) was removed in "
+                f"PR 29")
+        if self.kv_pages is not None and self.kv_pages < 1:
             raise ValueError(
-                f"kv_page_size must be >= 1 (or None for the legacy "
-                f"contiguous cache), got {self.kv_page_size}")
-        if self.kv_pages is not None:
-            if self.kv_page_size is None:
-                raise ValueError(
-                    "kv_pages requires kv_page_size (the legacy "
-                    "contiguous cache has no page pool)")
-            if self.kv_pages < 1:
-                raise ValueError(
-                    f"kv_pages must be >= 1, got {self.kv_pages}")
+                f"kv_pages must be >= 1, got {self.kv_pages}")
         if self.prefill_chunk < 1:
             raise ValueError(
                 f"prefill_chunk must be >= 1, got {self.prefill_chunk}")
-        if self.prefix_cache and self.kv_page_size is None:
-            raise ValueError(
-                "prefix_cache requires the paged KV cache (set "
-                "kv_page_size): the legacy contiguous slot reservation "
-                "has no pages to alias across requests")
         if self.prefix_cache_pages is not None \
                 and self.prefix_cache_pages < 1:
             raise ValueError(
@@ -744,11 +731,6 @@ class ServeConfig:
             raise ValueError(
                 f"kv_dtype must be None (model dtype) or 'int8', "
                 f"got {self.kv_dtype!r}")
-        if self.kv_dtype is not None and self.kv_page_size is None:
-            raise ValueError(
-                "kv_dtype requires the paged KV cache (set "
-                "kv_page_size): the legacy contiguous path keeps "
-                "full-precision slots")
         if self.sample_every < 1:
             raise ValueError(
                 f"sample_every must be >= 1, got {self.sample_every}")

@@ -411,9 +411,8 @@ def init_decode_cache(model: "TransformerLM", params: Any,
     Contiguous layout (``kv_page_size=None``): per block,
     ``cached_key``/``cached_value`` [B, cache_len, H, hd] plus the scalar
     ``cache_index`` write head. A zero cache with index 0 is exactly the
-    state a prefill starts from, so the legacy serving path stacks one of
-    these per decode slot and scatters freshly-prefilled caches into
-    freed slots without ever tracing a throwaway forward.
+    state a prefill starts from (``inference/sampler.py::Generator``,
+    the reference every serving test compares to, runs on this layout).
 
     Paged layout (``kv_page_size`` set): per block, the batch-free flat
     pools ``key_pages``/``value_pages`` [kv_pages * kv_page_size, H·hd]

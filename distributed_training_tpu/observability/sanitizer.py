@@ -2,11 +2,10 @@
 
 The whole static-shape discipline (docs/ARCHITECTURE.md, the serving
 engine's "masks, never shapes" rule) exists so each hot loop runs a
-KNOWN, FIXED set of compiled programs: the paged engine's fused
+KNOWN, FIXED set of compiled programs: the serving engine's fused
 chunk+decode step plus its decode-only sibling (2 programs, one shape
-each — docs/SERVING.md "compiled-program inventory"), the legacy
-engine's prefill/admit/decode trio (3 programs; prefill holds one shape
-per bucket actually touched), a trainer's single step function. A
+each — docs/SERVING.md "compiled-program inventory"), a trainer's
+single step function. A
 silent retrace — a shape that varies per call, a weakly-typed scalar, a
 donated buffer that changed layout — keeps every test green while the
 TPU spends its time compiling instead of computing. This module is the
@@ -144,50 +143,35 @@ def jit_cache_size(fn) -> int | None:
     return int(get())
 
 
-# The documented serving inventory (docs/SERVING.md): program counts
-# per engine mode, and the per-program shape pins. Legacy prefill is
-# bucketed — one shape per prompt bucket actually served — so its shape
-# count is workload-dependent and pinned by the caller. Speculation
-# (serving/speculative.py) leaves both counts alone — the verify window
+# The documented serving inventory (docs/SERVING.md): the fused step
+# and the decode-only step, one shape each. Speculation
+# (serving/speculative.py) leaves the count alone — the verify window
 # IS the decode program at a wider fixed shape — except a GPT drafter,
 # which contributes exactly one extra single-shape 'draft' program.
 PAGED_PROGRAMS = 2
-LEGACY_PROGRAMS = 3
-_MULTI_SHAPE_OK = {"prefill"}
 
 
-def check_engine_inventory(engine, *, prefill_shapes: int | None = None
-                           ) -> dict:
+def check_engine_inventory(engine) -> dict:
     """Pin a serving engine's compiled programs against the docs.
 
     Checks (via ``Engine.compiled_programs()``): the program COUNT is
-    exactly 2 (paged) / 3 (legacy) — plus the drafter's ``draft``
-    program when one reports it — and every program that has run holds
-    exactly one compiled shape, except legacy ``prefill``, whose bucket
-    count is pinned by ``prefill_shapes`` when given. Returns the
-    observed ``{name: shapes}`` inventory for logging.
+    exactly 2 — plus the drafter's ``draft`` program when one reports
+    it — and every program that has run holds exactly one compiled
+    shape. Returns the observed ``{name: shapes}`` inventory for logging.
     """
     progs = engine.compiled_programs()
-    expected = PAGED_PROGRAMS if engine.paged else LEGACY_PROGRAMS
-    expected += 1 if "draft" in progs else 0
-    mode = "paged" if engine.paged else "legacy"
+    expected = PAGED_PROGRAMS + ("draft" in progs)
     if len(progs) != expected:
         raise RecompileError(
-            f"{mode} engine has {len(progs)} compiled programs "
+            f"engine has {len(progs)} compiled programs "
             f"{sorted(progs)}, inventory pins {expected} "
             f"(docs/SERVING.md)")
     for name, shapes in sorted(progs.items()):
         if shapes is None:
             continue  # cache introspection unavailable on this jax
-        if name in _MULTI_SHAPE_OK:
-            if prefill_shapes is not None and shapes != prefill_shapes:
-                raise RecompileError(
-                    f"{mode} engine program '{name}' compiled {shapes} "
-                    f"shape(s), expected {prefill_shapes} (one per "
-                    f"prompt bucket served)")
-        elif shapes > 1:
+        if shapes > 1:
             raise RecompileError(
-                f"{mode} engine program '{name}' compiled {shapes} "
+                f"engine program '{name}' compiled {shapes} "
                 f"shapes — the inventory pins one trace per program "
                 f"(retrace leak; docs/SERVING.md)")
     return progs

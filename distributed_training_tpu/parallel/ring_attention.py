@@ -44,8 +44,8 @@ class PagedKV(NamedTuple):
       reserved null page (never handed out by the allocator) — reads of
       it are causally masked, writes to it are discarded garbage.
     - ``positions`` int32 [B, T_in]: each incoming token's global write
-      position (the engine's host-side write heads; the legacy path's
-      ``cache_index`` counter, externalized).
+      position (the engine's host-side write heads; the contiguous
+      layout's ``cache_index`` counter, externalized).
     - ``valid`` bool [B, T_in]: tokens that really exist. Invalid lanes
       (inactive decode slots, chunk padding) write to the null page and
       their outputs are discarded host-side — masks, never shapes.
@@ -396,7 +396,7 @@ class RingSelfAttention(nn.Module):
         if self.kv_dtype is not None:
             raise ValueError(
                 "kv_dtype requires the paged cache (kv_page_size set); "
-                "the legacy contiguous path keeps full-precision slots")
+                "the contiguous layout keeps full-precision slots")
         if self.cache_len is None:
             raise ValueError("decode=True requires cache_len")
         if not self.causal:

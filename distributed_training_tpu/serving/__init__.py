@@ -28,10 +28,9 @@ restated for XLA's static-shape world:
   emitted tokens and continues the same RNG stream — bitwise identical
   to an uninterrupted run), and EOS/length/deadline eviction at
   iteration boundaries; active masks instead of shape changes.
-- :mod:`engine` — paged KV + chunked prefill by default (a fused
+- :mod:`engine` — paged KV + chunked prefill (a fused
   prefill-chunk+decode step and a decode-only step over one shared page
-  pool), the legacy contiguous slot-axis trio behind
-  ``kv_page_size=None``, and the admit→prefill→decode→evict loop.
+  pool) and the admit→prefill→decode→evict loop.
 - :mod:`speculative` — draft-and-verify speculative decoding: a per-slot
   drafter (prompt-lookup n-gram by default, or a GPT draft model)
   proposes ``spec_k`` tokens and the engine's decode step widens to a

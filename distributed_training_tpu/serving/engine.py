@@ -3,11 +3,10 @@
 The training stack's decode loop (``inference/sampler.py``) compiles one
 ``generate`` program per prompt: great latency for one user, zero
 batching across users. This engine turns the same
-``RingSelfAttention`` KV cache into a multi-tenant server. Two cache
-managements exist, selected by ``ServeConfig.kv_page_size``:
+``RingSelfAttention`` KV cache into a multi-tenant server.
 
-**Paged KV + chunked prefill (default; docs/SERVING.md "Paged KV
-cache").** KV memory is one fixed pool of ``kv_page_size``-token pages
+**Paged KV + chunked prefill (docs/SERVING.md "Paged KV cache").** KV
+memory is one fixed pool of ``ServeConfig.kv_page_size``-token pages
 per layer (PagedAttention's layout); each decode slot holds a
 static-shape page table mapping logical pages → physical pages, pages
 allocate on demand as the write head advances, and admission commits a
@@ -20,23 +19,16 @@ decode-only step — two programs, one shape each, regardless of prompt
 mix (the chunk lane is always ``[1, prefill_chunk]``, padded rows write
 the pool's null page).
 
-**Legacy contiguous slots (``kv_page_size=None``).** The per-sequence
-cache pytree gains a leading slot axis (``[max_batch, 1, cache_len, H,
-hd]``); admission runs one bucketed batch-1 prefill and a slot-scatter,
-and decode ``vmap``s the single-sequence path — three compiled programs
-(bucketed prefill family, scatter, decode), every slot reserving the
-full budget.
-
-Shared discipline either way — masks, never shapes:
+The discipline — masks, never shapes:
 
 - **Iteration-level scheduling.** At each iteration boundary the
   :class:`SlotScheduler` evicts finished sequences (EOS / length budget
   / deadline) and refills freed slots FIFO from the
-  :class:`RequestQueue` (page-aware in paged mode: the queue head seats
-  only when the pool can commit its worst case). Slot membership is
+  :class:`RequestQueue` (page-aware: the queue head seats only when
+  the pool can commit its worst case). Slot membership is
   boolean masks and page-table contents — shapes never change, nothing
   retraces.
-- **One device step ahead of the host (paged path).** A call to
+- **One device step ahead of the host.** A call to
   ``step()`` delivers the tokens of one device step and, where it may,
   has launched the next one first: nothing the host puts into a step
   depends on a token's value, only on counts, and a slot's incoming
@@ -109,7 +101,6 @@ import flax
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from distributed_training_tpu.config import ServeConfig
 from distributed_training_tpu.inference.sampler import (
@@ -178,7 +169,7 @@ _SRC_HOST, _SRC_NXT, _SRC_CHUNK = 0, 1, 2
 
 @dataclasses.dataclass
 class _DeviceStep:
-    """One device step of the paged iteration, from its assembly on the
+    """One device step of the iteration, from its assembly on the
     host to the landing of its tokens — which may be one call to
     :meth:`Engine.step` later (``Engine._in_flight``)."""
 
@@ -240,12 +231,11 @@ class Engine:
 
     ``trace`` (an :class:`~distributed_training_tpu.observability.trace.
     TraceSession`, or None = off) draws the engine on a Perfetto
-    timeline: the paged path's ``serve.iteration`` spans and their
+    timeline: the ``serve.iteration`` spans and their
     phases on an 'engine' track (``observability/trace.py::span``), a
     queue-depth counter series, admission marks on a 'queue' track, and
     — the Orca view — one track PER DECODE SLOT carrying each request's
-    serve.queued → serve.prefill (per-chunk spans in paged mode) →
-    decode lifecycle
+    serve.queued → serve.prefill (per-chunk spans) → decode lifecycle
     and finish marks. All timestamps come from the same ``perf_counter``
     clock as :class:`ServeTelemetry`, so span-derived latencies equal
     the SLA numbers exactly (pinned by tests/test_trace.py).
@@ -262,7 +252,6 @@ class Engine:
             raise ValueError(
                 f"cache budget {self.budget} cannot hold a prompt token "
                 f"plus a generated token")
-        self.paged = cfg.kv_page_size is not None
         # Quantized execution (serving/quantize.py; docs/SERVING.md
         # "Quantized execution"): per-channel int8 matmul weights,
         # quantized ONCE here — construction is off the hot path by
@@ -336,53 +325,27 @@ class Engine:
         self._mutable = ["cache"] + (["counters"] if self._step_counters
                                      else [])
         s = cfg.max_batch
-        if self.paged:
-            ps = int(cfg.kv_page_size)
-            self.page_size = ps
-            self.pages_per_slot = pages_for(self.budget, ps)
-            self.pool_pages = (int(cfg.kv_pages) if cfg.kv_pages is not None
-                               else s * self.pages_per_slot)
-            self.pool = PagePool(self.pool_pages, ps)
-            # +1 physical page: the device pool keeps page 0 as the null
-            # page (masked writes, unallocated table entries); the
-            # allocator serves ids 1..pool_pages.
-            self.model = model.clone(cache_len=self.budget,
-                                     kv_page_size=ps,
-                                     kv_pages=self.pool_pages + 1,
-                                     kv_dtype=cfg.kv_dtype)
-            # A chunk wider than the longest admissible prompt is pure
-            # padding compute.
-            self.prefill_chunk = min(int(cfg.prefill_chunk),
-                                     max(self.budget - 1, 1))
-            # Gather width of one slot's page-table view; verify-window
-            # padding rows clamp their positions under this so the
-            # per-row overflow poison never fires on a masked lane.
-            self._l_all = self.pages_per_slot * ps
-        else:
-            if cfg.prefix_cache:
-                raise ValueError(
-                    "prefix_cache requires the paged KV cache "
-                    "(kv_page_size): the legacy contiguous slot "
-                    "reservation has no pages to alias across requests")
-            self.page_size = None
-            self.pool = None
-            # One clone with the serving cache length; every compiled
-            # program below derives its shapes from it. Speculation
-            # needs spec_k slack positions past the admission budget:
-            # the contiguous write (dynamic_update_slice) lands ALL
-            # spec_width rows — padding included — so a full window
-            # starting at the last admissible write head must fit, or
-            # the cache's overflow poison fires on a legal request.
-            cache_len = self.budget + self.spec_k
-            if cache_len > int(model.max_len):
-                raise ValueError(
-                    f"spec_k={self.spec_k} on the legacy contiguous "
-                    f"path needs budget + spec_k <= the model's "
-                    f"position limit (got {self.budget} + {self.spec_k} > "
-                    f"{model.max_len}); lower max_len or use the paged "
-                    f"cache (kv_page_size), whose window padding is "
-                    f"validity-masked instead of written")
-            self.model = model.clone(cache_len=cache_len)
+        ps = int(cfg.kv_page_size)
+        self.page_size = ps
+        self.pages_per_slot = pages_for(self.budget, ps)
+        self.pool_pages = (int(cfg.kv_pages) if cfg.kv_pages is not None
+                           else s * self.pages_per_slot)
+        self.pool = PagePool(self.pool_pages, ps)
+        # +1 physical page: the device pool keeps page 0 as the null
+        # page (masked writes, unallocated table entries); the
+        # allocator serves ids 1..pool_pages.
+        self.model = model.clone(cache_len=self.budget,
+                                 kv_page_size=ps,
+                                 kv_pages=self.pool_pages + 1,
+                                 kv_dtype=cfg.kv_dtype)
+        # A chunk wider than the longest admissible prompt is pure
+        # padding compute.
+        self.prefill_chunk = min(int(cfg.prefill_chunk),
+                                 max(self.budget - 1, 1))
+        # Gather width of one slot's page-table view; verify-window
+        # padding rows clamp their positions under this so the
+        # per-row overflow poison never fires on a masked lane.
+        self._l_all = self.pages_per_slot * ps
 
         # Radix-tree prefix cache (serving/prefix_cache.py): finished
         # sequences' written page chains stay indexed; a seat whose
@@ -393,7 +356,7 @@ class Engine:
         # so old-weight KV can never seed a new-epoch request.
         self.prefix_cache = (PrefixCache(self.page_size,
                                          max_pages=cfg.prefix_cache_pages)
-                             if self.paged and cfg.prefix_cache else None)
+                             if cfg.prefix_cache else None)
         self._kv_epoch = 0
         self.queue = RequestQueue(
             self.budget, default_max_new_tokens=cfg.max_new_tokens,
@@ -401,7 +364,7 @@ class Engine:
             ttft_deadline_ms=cfg.ttft_deadline_ms,
             deadline_ms=cfg.deadline_ms, trace=trace,
             page_size=self.page_size,
-            pool_pages=self.pool_pages if self.paged else None,
+            pool_pages=self.pool_pages,
             num_tiers=cfg.num_tiers, tenant_quota=cfg.tenant_quota,
             tenant_weights=cfg.tenant_weights)
         self.scheduler = SlotScheduler(
@@ -492,99 +455,70 @@ class Engine:
         self._cancel_uids: set[int] = set()
 
         # Donation keeps one cache resident instead of two per decode
-        # step — and on the paged path lets every layer write its new
+        # step — and lets every layer write its new
         # rows into the donated pool in place (the pool is held row-major,
         # [rows, H·hd], so no relayout stands between the buffer and the
         # scatter; ring_attention._paged_decode_attend). The CPU backend
         # can't donate (it would only warn noisily).
         donate = jax.default_backend() != "cpu"
-        # The paged iteration runs one device step ahead of the host
+        # The iteration runs one device step ahead of the host
         # (_iterate_paged): the step launched and not yet fetched, the
         # latch by which an admission pass asks for a preemption with
         # nothing in flight, and what Engine.stats()["run_ahead_share"]
-        # counts. The legacy path leaves all four as they are here.
+        # counts.
         self._in_flight: _DeviceStep | None = None
         self._preempt_due = False
         self._iters_working = 0
         self._iters_ahead = 0
-        if self.paged:
-            # Device state: the page pool (batch-free) and, between two
-            # steps, the last step's own outputs: a slot's incoming
-            # token is the previous step's `nxt` where the host has not
-            # fetched it yet (_incoming). Slot routing (page tables,
-            # write heads, RNGs, and the tokens the host has seen) is
-            # host-side numpy, shipped as tiny step inputs — so page
-            # allocation and slot membership never touch compiled code,
-            # and how much of each slot's table is live is data the
-            # decode lane's attention kernel reads, not a shape.
-            with trace_lib.span("setup.cache_alloc"):
-                self._cache = init_decode_cache(self.model, params,
-                                                batch_size=1)
-            self._tables = np.zeros((s, self.pages_per_slot), np.int32)
-            self._slot_rng = np.zeros(
-                (s,) + self._base_rng.shape,
-                np.asarray(self._base_rng).dtype)
-            self._slot_pages: list[list[int]] = [[] for _ in range(s)]
-            self._slot_commit_left = [0] * s
-            # Prefix-cache routing (serving/prefix_cache.py): how many
-            # LEADING entries of each slot's page list are ALIASED trie
-            # pages (the sequence holds a reference, never writes them),
-            # and the seated sequence itself — the engine needs its
-            # written token stream and KV epoch at page-release time to
-            # decide what enters the trie.
-            self._slot_shared = [0] * s
-            self._slot_seq: list[ActiveSequence | None] = [None] * s
-            with trace_lib.span("setup.program_build") as build_span:
-                # Which attention formulation each lane's shapes select:
-                # the model says (it decides by the same call when the
-                # programs trace).
-                self.lane_formulation = {
-                    lane: self.model.paged_lane(t_in, self.page_size,
-                                                cfg.kv_dtype)
-                    for lane, t_in in (("decode", self.spec_k + 1),
-                                       ("chunk", self.prefill_chunk))}
-                build_span.attrs.update(
-                    decode_lane=self.lane_formulation["decode"],
-                    chunk_lane=self.lane_formulation["chunk"])
-                self._fused = jax.jit(
-                    self._fused_impl,
-                    donate_argnums=(1,) if donate else ())
-                self._decode = jax.jit(
-                    self._decode_only_impl,
-                    donate_argnums=(1,) if donate else ())
-                # What a launch with no step before it reads in the
-                # previous outputs' place (every source is the host).
-                self._no_tokens = (
-                    jnp.zeros((s, self.spec_width), jnp.int32),
-                    jnp.zeros((self.prefill_chunk,), jnp.int32))
-        else:
-            # Slot-axis device state. The stacked cache comes from the
-            # model's own structure (init_decode_cache), so scatters
-            # from prefill results are structure-identical by
-            # construction.
-            single = init_decode_cache(self.model, params, batch_size=1)
-            self._cache = jax.tree.map(
-                lambda leaf: jnp.zeros((s,) + leaf.shape, leaf.dtype),
-                single)
-            self._tok = jnp.zeros((s,), jnp.int32)  # last token/slot
-            self._pos = jnp.zeros((s,), jnp.int32)  # cache write head/slot
-            self._rngs = jnp.zeros((s,) + self._base_rng.shape,
-                                   self._base_rng.dtype)
-            self._prefill = jax.jit(self._prefill_impl)
-            self._admit = jax.jit(
-                self._admit_impl,
-                donate_argnums=(0, 1, 2, 3) if donate else ())
-            # Speculation swaps the decode program for the verify-window
-            # variant (host-authoritative write heads, W-wide lanes);
-            # the inventory stays three programs either way.
-            if self.spec_k:
-                self._decode = jax.jit(
-                    self._verify_legacy_impl,
-                    donate_argnums=(1,) if donate else ())
-            else:
-                self._decode = jax.jit(
-                    self._decode_impl,
-                    donate_argnums=(1, 2, 3) if donate else ())
+        # Device state: the page pool (batch-free) and, between two
+        # steps, the last step's own outputs: a slot's incoming
+        # token is the previous step's `nxt` where the host has not
+        # fetched it yet (_incoming). Slot routing (page tables,
+        # write heads, RNGs, and the tokens the host has seen) is
+        # host-side numpy, shipped as tiny step inputs — so page
+        # allocation and slot membership never touch compiled code,
+        # and how much of each slot's table is live is data the
+        # decode lane's attention kernel reads, not a shape.
+        with trace_lib.span("setup.cache_alloc"):
+            self._cache = init_decode_cache(self.model, params,
+                                            batch_size=1)
+        self._tables = np.zeros((s, self.pages_per_slot), np.int32)
+        self._slot_rng = np.zeros(
+            (s,) + self._base_rng.shape,
+            np.asarray(self._base_rng).dtype)
+        self._slot_pages: list[list[int]] = [[] for _ in range(s)]
+        self._slot_commit_left = [0] * s
+        # Prefix-cache routing (serving/prefix_cache.py): how many
+        # LEADING entries of each slot's page list are ALIASED trie
+        # pages (the sequence holds a reference, never writes them),
+        # and the seated sequence itself — the engine needs its
+        # written token stream and KV epoch at page-release time to
+        # decide what enters the trie.
+        self._slot_shared = [0] * s
+        self._slot_seq: list[ActiveSequence | None] = [None] * s
+        with trace_lib.span("setup.program_build") as build_span:
+            # Which attention formulation each lane's shapes select:
+            # the model says (it decides by the same call when the
+            # programs trace).
+            self.lane_formulation = {
+                lane: self.model.paged_lane(t_in, self.page_size,
+                                            cfg.kv_dtype)
+                for lane, t_in in (("decode", self.spec_k + 1),
+                                   ("chunk", self.prefill_chunk))}
+            build_span.attrs.update(
+                decode_lane=self.lane_formulation["decode"],
+                chunk_lane=self.lane_formulation["chunk"])
+            self._fused = jax.jit(
+                self._fused_impl,
+                donate_argnums=(1,) if donate else ())
+            self._decode = jax.jit(
+                self._decode_only_impl,
+                donate_argnums=(1,) if donate else ())
+            # What a launch with no step before it reads in the
+            # previous outputs' place (every source is the host).
+            self._no_tokens = (
+                jnp.zeros((s, self.spec_width), jnp.int32),
+                jnp.zeros((self.prefill_chunk,), jnp.int32))
 
         # Quantization gauges ride the telemetry from birth:
         # kv_bytes_per_token is measured off the REAL device cache tree
@@ -598,19 +532,13 @@ class Engine:
 
     def _kv_bytes_per_token(self) -> float:
         """Device-cache bytes per storable KV token position, measured
-        from the actual cache pytree: paged pools divide by physical
-        pool rows (so int8 pages + their fp32 scale planes both count),
-        the legacy contiguous cache by slots × cache length (its scalar
-        write heads are noise but counted for honesty)."""
+        from the actual cache pytree and divided by the physical pool
+        rows (so int8 pages + their fp32 scale planes both count)."""
         total = sum(int(leaf.nbytes)
                     for leaf in jax.tree_util.tree_leaves(self._cache))
-        if self.paged:
-            rows = (self.pool_pages + 1) * self.page_size
-        else:
-            rows = self.cfg.max_batch * (self.budget + self.spec_k)
-        return total / max(rows, 1)
+        return total / ((self.pool_pages + 1) * self.page_size)
 
-    # -- compiled pieces: paged KV + chunked prefill -------------------------
+    # -- compiled pieces ----------------------------------------------------
     def _incoming(self, tok, src, prev_nxt, prev_sampled):
         """Row 0 of every decode lane: the host's value, or — where the
         host had not seen the token when it assembled this step — the
@@ -745,120 +673,6 @@ class Engine:
                 d_src, prev_nxt, prev_sampled)
         return tuple(out) if counted is None else (*out, counted)
 
-    # -- compiled pieces: legacy contiguous slots ----------------------------
-    def _prefill_impl(self, params, prompt, true_len, rng):
-        """[1, Lb] padded prompt → (single-sequence cache, first token).
-
-        Retraces once per padded length Lb (bucketed by the caller). The
-        pad positions' K/V writes are zeroed and the write head rewound to
-        ``true_len``: the cache leaves the call exactly as an unpadded
-        prefill would have left it, so decode math downstream is
-        bitwise-independent of the bucket size.
-        """
-        lb = prompt.shape[1]
-        positions = jnp.arange(lb)[None, :]
-        logits, vars_out = self.model.apply(
-            {"params": params}, prompt, positions=positions,
-            train=False, decode=True, mutable=["cache"])
-
-        def fix(leaf):
-            if leaf.ndim == 0:  # per-block cache_index write head
-                return true_len.astype(leaf.dtype)
-            # [1, cache_len, H, hd]: zero every position >= true_len.
-            pos_ax = jnp.arange(leaf.shape[1]).reshape(
-                (1, -1) + (1,) * (leaf.ndim - 2))
-            return jnp.where(pos_ax >= true_len,
-                             jnp.zeros((), leaf.dtype), leaf)
-
-        cache = jax.tree.map(fix, vars_out["cache"])
-        last = lax.dynamic_slice_in_dim(logits, true_len - 1, 1, axis=1)
-        tok = sample_token(jax.random.fold_in(rng, true_len - 1),
-                           last[:, 0, :], self.sample_cfg)[0]
-        return cache, tok
-
-    def _admit_impl(self, cache, tok, pos, rngs, slot, new_cache,
-                    first_tok, true_len, rng):
-        """Scatter one prefilled sequence into decode slot ``slot``."""
-        cache = jax.tree.map(
-            lambda big, small: lax.dynamic_update_index_in_dim(
-                big, small, slot, 0),
-            cache, new_cache)
-        tok = tok.at[slot].set(first_tok)
-        pos = pos.at[slot].set(true_len)
-        rngs = rngs.at[slot].set(rng)
-        return cache, tok, pos, rngs
-
-    def _decode_impl(self, params, cache, tok, pos, active, rngs):
-        """One token for every active slot; inactive lanes are frozen.
-
-        The vmap gives each slot its own scalar ``cache_index`` trajectory
-        — the per-slot cache length counter that lets sequences of
-        different ages share one compiled step. Inactive lanes still
-        compute (vmap has no ragged skip) but their cache/pos/token
-        updates are discarded by the mask select, so a freed slot stays
-        bitwise intact until the next admission overwrites it.
-        """
-
-        def lane(cache_s, tok_s, pos_s, rng_s):
-            logits, vars_out = self.model.apply(
-                {"params": params, "cache": cache_s},
-                tok_s[None, None], positions=pos_s[None, None],
-                train=False, decode=True, mutable=["cache"])
-            nxt = sample_token(jax.random.fold_in(rng_s, pos_s),
-                               logits[:, -1, :], self.sample_cfg)[0]
-            return vars_out["cache"], nxt
-
-        new_cache, nxt = jax.vmap(lane)(cache, tok, pos, rngs)
-
-        def keep(new, old):
-            mask = active.reshape((-1,) + (1,) * (new.ndim - 1))
-            return jnp.where(mask, new, old)
-
-        new_cache = jax.tree.map(keep, new_cache, cache)
-        nxt = jnp.where(active, nxt, jnp.int32(self.sample_cfg.pad_id))
-        pos = jnp.where(active, pos + 1, pos)
-        return new_cache, nxt, pos
-
-    def _verify_legacy_impl(self, params, cache, tok, pos0, valid, rngs):
-        """Speculative verify window on the contiguous slot cache:
-        ``tok``/``valid`` [B, W], ``pos0`` [B] (each lane's write head,
-        host-authoritative). Forcing each lane's ``cache_index`` to the
-        host head IS the speculative rewind: a rejected suffix simply
-        never advances the head, and the next window's leading rows
-        overwrite the stale K/V (contiguous writes land all W rows, so
-        padding rows park garbage at positions strictly past every
-        valid query — masked now, overwritten later). Accept length is
-        the same mask/argmax as the paged step; inactive lanes compute
-        but the active mask discards their cache like plain decode.
-        """
-
-        def lane(cache_s, tok_row, pos0_s, rng_s):
-            cache_s = jax.tree.map(
-                lambda leaf: (pos0_s.astype(leaf.dtype)
-                              if leaf.ndim == 0 else leaf), cache_s)
-            positions = pos0_s + jnp.arange(tok_row.shape[0])
-            logits, vars_out = self.model.apply(
-                {"params": params, "cache": cache_s}, tok_row[None, :],
-                positions=positions[None], train=False, decode=True,
-                mutable=["cache"])
-
-            def one(pos_s, row):
-                return sample_token(jax.random.fold_in(rng_s, pos_s),
-                                    row[None], self.sample_cfg)[0]
-
-            return vars_out["cache"], jax.vmap(one)(positions, logits[0])
-
-        new_cache, t = jax.vmap(lane)(cache, tok, pos0, rngs)
-        active = valid[:, 0]
-
-        def keep(new, old):
-            mask = active.reshape((-1,) + (1,) * (new.ndim - 1))
-            return jnp.where(mask, new, old)
-
-        new_cache = jax.tree.map(keep, new_cache, cache)
-        t = jnp.where(valid, t, jnp.int32(self.sample_cfg.pad_id))
-        return new_cache, t, self._accept_len(tok, t, valid)
-
     # -- host-side lifecycle -------------------------------------------------
     def submit(self, prompt, max_new_tokens: int | None = None,
                arrival_t: float | None = None, priority: int = 0,
@@ -871,7 +685,7 @@ class Engine:
         deadline overriding the configured default (the front door's
         deadline field). Raises :class:`~distributed_training_tpu.
         inference.sampler.CacheBudgetError` when it can never fit a
-        slot's page table (or the legacy contiguous budget). With a
+        slot's page table. With a
         journal, the admission record is durable before this returns —
         a request the journal never saw was never accepted.
         ``trace_id`` is the fleet-tracing correlation id the front door
@@ -907,10 +721,6 @@ class Engine:
         return (len(self.queue) == 0 and self.scheduler.num_active == 0
                 and not self.queue.has_shed_pending
                 and self._in_flight is None)
-
-    def _bucket(self, n: int) -> int:
-        b = self.cfg.prefill_bucket
-        return min(self.budget, -(-n // b) * b)
 
     def _req_pages(self, req: Request) -> int:
         """Worst-case page commitment: the request's whole lifetime
@@ -1004,10 +814,7 @@ class Engine:
     def check_balanced(self) -> None:
         """Leak audit at the drained steady state: every pool page free
         or — prefix cache on — held by exactly the trie with exactly one
-        reference, nothing committed. The paged twin of the legacy
-        path's no-op (no pool, nothing to leak)."""
-        if self.pool is None:
-            return
+        reference, nothing committed."""
         self.pool.check_balanced(
             cached=(self.prefix_cache.pages_held()
                     if self.prefix_cache is not None else None))
@@ -1046,7 +853,7 @@ class Engine:
             return CAUSE_PREFILL
         return CAUSE_DECODE
 
-    # -- tier-aware admission (shared by both step paths) --------------------
+    # -- tier-aware admission ------------------------------------------------
     def _queue_evict_finish(self, entry, reason: str) -> FinishedRequest:
         """Complete an entry evicted FROM THE QUEUE (tier-aware shed or
         deadline expiry): a fresh request carries nothing; a requeued
@@ -1113,8 +920,7 @@ class Engine:
                 # to _finish_iteration would reclaim the new tenant's
                 # pages. slot=None keeps the finish sweep from freeing
                 # twice.
-                if self.paged:
-                    self._free_slot_pages(seq.slot)
+                self._free_slot_pages(seq.slot)
                 finished.append(FinishedRequest.from_active(
                     seq, FINISH_CANCELLED, slot=None))
 
@@ -1137,8 +943,6 @@ class Engine:
             finished.append(self._queue_evict_finish(entry, FINISH_SHED))
 
         def can_seat(entry) -> bool:
-            if not self.paged:
-                return True
             req = (entry.request if isinstance(entry, ActiveSequence)
                    else entry)
             # Prefix-cache sizing probe (read-only): the candidate
@@ -1190,8 +994,6 @@ class Engine:
             return True
 
         def on_seat(seq: ActiveSequence) -> None:
-            if not self.paged:
-                return
             slot = seq.slot
             # Claim the resident prefix (refcount per page) and alias
             # it into the slot's block table; commit only the tail.
@@ -1276,8 +1078,7 @@ class Engine:
             # next prefill chunks consume it before billing 'prefill',
             # keeping ledger_tokens_recompute == the engine's counter.
             seq.recompute_owed += recompute
-            if self.paged:
-                self._free_slot_pages(seq.slot)
+            self._free_slot_pages(seq.slot)
             self.telemetry.on_preempted(recompute,
                                         seq.request.priority)
             if self.trace is not None:
@@ -1291,8 +1092,7 @@ class Engine:
 
         def preempt_helps(entry, victims) -> bool:
             # Futility bound: would evicting EVERY strictly-lower-tier
-            # active ever let this candidate seat? On the legacy path a
-            # freed slot is all a candidate can need; paged, the
+            # active ever let this candidate seat? The
             # preemptible pool must cover the candidate's worst-case
             # commitment minus its resident prefix, with the same
             # reserved-page headroom can_seat applies. Without this
@@ -1313,8 +1113,6 @@ class Engine:
             # would free nothing — the futility the bound exists to
             # catch. Never counted when the candidate's own hit chain
             # pins the page.
-            if not self.paged:
-                return True
             req = (entry.request if isinstance(entry, ActiveSequence)
                    else entry)
             need = self._req_pages(req)
@@ -1380,58 +1178,6 @@ class Engine:
         self._overloaded = len(self.queue) > 0
         return seated
 
-    def _prefill_request(self, seq) -> None:
-        """Legacy path: one bucketed batch-1 prefill + slot scatter.
-
-        A resumption re-prefills prompt + previously emitted tokens
-        minus the last (``seq.prefill_tokens``); its "first token"
-        sample at position ``n'-1`` recomputes the last emitted token
-        bitwise (same logits row, same ``fold_in(rng, pos)``), which is
-        exactly the incoming-token/write-head state an uninterrupted
-        run would hold — so it is NOT re-emitted, just landed in the
-        slot state by the same scatter.
-        """
-        req = seq.request
-        toks = seq.prefill_tokens
-        n = toks.size
-        padded = np.full((1, self._bucket(n)), self.sample_cfg.pad_id,
-                         np.int32)
-        padded[0, :n] = toks
-        req_rng = jax.random.fold_in(self._base_rng, req.uid)
-        new_cache, tok = self._prefill(
-            self.params, jnp.asarray(padded), jnp.int32(n), req_rng)
-        self._cache, self._tok, self._pos, self._rngs = self._admit(
-            self._cache, self._tok, self._pos, self._rngs,
-            jnp.int32(seq.slot), new_cache, tok, jnp.int32(n), req_rng)
-        seq.prefill_pos = n
-        # Ledger token attribution: positions this prefill REwrote
-        # (recompute debt from preemptions/crashes) vs first-time
-        # writes — the split that keeps ledger_tokens_recompute equal
-        # to the engine's recompute counters.
-        led = seq.request.ledger
-        if led is not None:
-            rec = min(n, seq.recompute_owed)
-            seq.recompute_owed -= rec
-            # A genuinely recomputed position's recovery charge stands;
-            # the recovery-attribution share just never exceeds the
-            # remaining debt (prefix-hit credit bookkeeping).
-            seq.recovery_owed = min(seq.recovery_owed,
-                                    seq.recompute_owed)
-            if rec:
-                led.add_tokens(CAUSE_RECOMPUTE, rec)
-            if n - rec:
-                led.add_tokens(CAUSE_PREFILL, n - rec)
-        if seq.tokens:
-            # Resumed mid-decode: no new token was emitted; bill the
-            # re-prefill dispatch to 'recompute' and resume decoding.
-            if led is not None:
-                led.stamp(CAUSE_RECOMPUTE, time.perf_counter())
-            return
-        # graftlint: disable=hot-path-transfer -- the one deliberate sync: TTFT is measured here
-        first = int(tok)
-        t = time.perf_counter()
-        self._note_first_token(seq, first, t)
-
     def _draft_window(self, decoding, unlanded=()):
         """Assemble the [max_batch, spec_width] verify-window inputs for
         one iteration (host-side numpy, like all slot routing).
@@ -1445,9 +1191,9 @@ class Engine:
         ``p+1..p+useful``, where ``useful = min(spec_k, remaining
         completion budget - 1, proposal length)`` — the budget clamp
         keeps every VALID write inside the request's worst-case page
-        commitment (paged) / admission budget (legacy), so speculation
+        commitment, so speculation
         never grows what admission promised. Padding rows are
-        validity-masked; on the paged path their positions additionally
+        validity-masked; their positions additionally
         clamp under the page-table width so the per-row overflow poison
         cannot fire on a masked lane. Returns ``(tok, pos, valid,
         useful_by_slot, drafted)``.
@@ -1478,10 +1224,7 @@ class Engine:
                 d_tok[seq.slot, 1:1 + useful] = props[:useful]
             if not lag:
                 d_tok[seq.slot, 0] = seq.tokens[-1]
-            win_pos = p + np.arange(w)
-            if self.paged:
-                win_pos = np.minimum(win_pos, self._l_all - 1)
-            d_pos[seq.slot] = win_pos
+            d_pos[seq.slot] = np.minimum(p + np.arange(w), self._l_all - 1)
             d_valid[seq.slot, :useful + 1] = True
             useful_by_slot[seq.slot] = useful
             drafted += useful
@@ -1761,16 +1504,13 @@ class Engine:
         decode, evict.
 
         Returns the requests that finished this iteration. Safe to call
-        when idle (records an excluded gap and returns []). On the paged
-        path a call delivers the tokens of one device step and, where it
+        when idle (records an excluded gap and returns []). A call
+        delivers the tokens of one device step and, where it
         may, has launched the next one first (:meth:`_iterate_paged`);
         the swap barrier waits for a call that enters with nothing in
         flight."""
         if self._in_flight is None:
             self._apply_pending_swap()
-        return self._step_paged() if self.paged else self._step_legacy()
-
-    def _step_paged(self) -> list[FinishedRequest]:
         it = self._iteration
         self._iteration += 1
         # The iteration and its phases, in order (docs/OBSERVABILITY.md
@@ -1989,8 +1729,7 @@ class Engine:
             # Head-of-line blocking: anything still queued after the
             # admission pass is blocked on a slot OR on pool pages until
             # the next boundary — bill the rest of this iteration as
-            # admission-blocked time (the legacy definition, generalized
-            # from "all slots busy" to "cannot seat").
+            # admission-blocked time.
             blocked_t0 = (time.perf_counter() if len(self.queue) > 0
                           else None)
             live = self.scheduler.num_active
@@ -2149,8 +1888,7 @@ class Engine:
                     first = int(np.asarray(step.c_sampled)[c - 1])
                     self._note_first_token(chunk_seq, first, t)
         # KV utilization, host-side only: reserved = pages
-        # held by occupied slots as this step was assembled (the paged
-        # win — compare the legacy path's active × full budget),
+        # held by occupied slots as this step was assembled,
         # written = live cache positions, both reconstructed without a
         # device read. The pool's allocation is counted as this step
         # needed it: pages the step ahead has drawn count with that one.
@@ -2177,112 +1915,14 @@ class Engine:
             self.trace.counter("kv_pages_allocated",
                                self.pool.num_allocated)
 
-    def _step_legacy(self) -> list[FinishedRequest]:
-        it = self._iteration
-        self._iteration += 1
-        eos = self.sample_cfg.eos_id
-        deadlines = (self.cfg.ttft_deadline_ms is not None
-                     or self.cfg.deadline_ms is not None)
-        finished: list[FinishedRequest] = []
-        # Deadline sweep BEFORE admission: a queued request already past
-        # its TTFT/total deadline must not consume a prefill — it
-        # completes with finish reason 'timeout' and zero tokens.
-        if deadlines:
-            self._expire_queue(finished, time.perf_counter())
-        self._cancel_pass(finished)
-
-        had_work = not self.idle
-        if had_work:
-            self.telemetry.begin_work()
-        for seq in self._admit_pass(finished):
-            self._prefill_request(seq)
-        # Prefill-time completions: a 1-token budget or an instant EOS
-        # never joins a decode iteration.
-        finished.extend(self.scheduler.evict_finished(eos))
-        # Head-of-line blocking: requests still queued after the
-        # admission pass cannot seat (slots, reserved headroom, or tier
-        # quota) and wait out the whole iteration (admission is
-        # boundary-only) — bill the rest of this iteration as
-        # admission-blocked time.
-        blocked_t0 = (time.perf_counter() if len(self.queue) > 0
-                      else None)
-
-        active_seqs = self.scheduler.active()
-        if active_seqs:
-            if self.spec_k:
-                # Verify-window variant: slot routing (write heads,
-                # tokens, drafts) is host-assembled like the paged path;
-                # the compiled lane forces each slot's cache_index to
-                # the host head, which IS the speculative rewind.
-                d_tok, d_pos, d_valid, useful_by_slot, drafted = \
-                    self._draft_window(active_seqs)
-                self._cache, nxt, acc = self._decode(
-                    self.params, self._cache, jnp.asarray(d_tok),
-                    jnp.asarray(d_pos[:, 0]), jnp.asarray(d_valid),
-                    self._rngs)
-                # graftlint: disable=hot-path-transfer -- THE per-iteration sync: tokens must land (docs/SERVING.md)
-                toks = np.asarray(nxt)
-                # graftlint: disable=hot-path-transfer -- per-slot accept lengths ride the same iteration sync
-                accepts = np.asarray(acc)
-                t = time.perf_counter()
-                emitted, accepted = self._apply_accepts(
-                    active_seqs, toks, accepts, useful_by_slot, t)
-                t_roll = time.perf_counter()
-                for seq in active_seqs:
-                    if seq.request.ledger is not None:
-                        seq.request.ledger.stamp(CAUSE_SPEC_ROLLBACK,
-                                                 t_roll)
-                self.telemetry.on_spec(
-                    drafted=drafted, accepted=accepted,
-                    rollback_s=t_roll - t)
-                self.telemetry.on_decode(lanes=len(active_seqs),
-                                         tokens=emitted)
-                self.telemetry.on_tokens(emitted, t)
-            else:
-                mask = self.scheduler.active_mask()
-                self._cache, nxt, self._pos = self._decode(
-                    self.params, self._cache, self._tok, self._pos,
-                    jnp.asarray(mask), self._rngs)
-                self._tok = nxt
-                # graftlint: disable=hot-path-transfer -- THE per-iteration sync: tokens must land (docs/SERVING.md)
-                toks = np.asarray(nxt)
-                t = time.perf_counter()
-                for seq in active_seqs:
-                    seq.note_token(toks[seq.slot], t)
-                    if seq.request.ledger is not None:
-                        seq.request.ledger.stamp(CAUSE_DECODE, t)
-                        seq.request.ledger.add_tokens(CAUSE_DECODE, 1)
-                self.telemetry.on_decode(lanes=len(active_seqs),
-                                         tokens=len(active_seqs))
-                self.telemetry.on_tokens(len(active_seqs), t)
-            # KV utilization, host-side only: a slot's occupied cache
-            # positions equal prompt + decode-written tokens — the
-            # device cache_index reconstructed without a device read;
-            # every active slot reserves the full per-slot budget.
-            written = sum(s.request.prompt.size + len(s.tokens) - 1
-                          for s in active_seqs)
-            self.telemetry.on_kv(
-                reserved=len(active_seqs) * self.budget, written=written,
-                active=len(active_seqs), slots=self.cfg.max_batch)
-            if blocked_t0 is not None:
-                self.telemetry.on_admission_blocked(t - blocked_t0)
-            if self.trace is not None:
-                self.trace.counter("active_slots", len(active_seqs))
-                self.trace.counter("kv_written_tokens", written)
-            finished.extend(self.scheduler.evict_finished(
-                eos, now=t if deadlines else None))
-
-        return self._finish_iteration(it, had_work, finished)
-
     def _finish_iteration(self, it: int, had_work: bool,
                           finished: list[FinishedRequest]
                           ) -> list[FinishedRequest]:
-        """Shared iteration tail: page reclamation, journal, telemetry,
+        """The iteration's tail: page reclamation, journal, telemetry,
         traces."""
-        if self.paged:
-            for fin in finished:
-                if fin.slot is not None:
-                    self._free_slot_pages(fin.slot)
+        for fin in finished:
+            if fin.slot is not None:
+                self._free_slot_pages(fin.slot)
         if self.journal is not None:
             # Durability sweep, enqueue-only (the journal's writer
             # thread owns the disk): each active slot's newly emitted
@@ -2389,9 +2029,8 @@ class Engine:
             # Gauges (instantaneous, still schedule-deterministic).
             "queue_depth": len(self.queue),
             "active_slots": self.scheduler.num_active,
-            "pool_occupancy": (
-                self.pool.num_allocated / self.pool.num_pages
-                if self.paged else 0.0),
+            "pool_occupancy":
+                self.pool.num_allocated / self.pool.num_pages,
             "prefix_cache_pages_held": (
                 self.prefix_cache.num_pages
                 if self.prefix_cache is not None else 0),
@@ -2768,10 +2407,8 @@ class Engine:
     def compiled_programs(self) -> dict[str, int | None]:
         """Name → compiled-shape count per jit program — the sanitizer
         hook (``observability/sanitizer.py``). The documented inventory
-        (docs/SERVING.md): paged = ``fused`` + ``decode`` (2 programs,
-        one shape each once warm); legacy = ``prefill`` + ``admit`` +
-        ``decode`` (3 programs; prefill holds one shape per prompt
-        bucket served). Speculation does not change these counts — the
+        (docs/SERVING.md): ``fused`` + ``decode`` (2 programs, one shape
+        each once warm). Speculation does not change these counts — the
         verify window replaces the decode lane at a wider fixed shape —
         but a GPT drafter contributes its own single-shape ``draft``
         program. Values are None when the running jax doesn't expose
@@ -2779,12 +2416,8 @@ class Engine:
         from distributed_training_tpu.observability.sanitizer import (
             jit_cache_size,
         )
-        if self.paged:
-            progs = {"fused": self._fused, "decode": self._decode}
-        else:
-            progs = {"prefill": self._prefill, "admit": self._admit,
-                     "decode": self._decode}
-        out = {name: jit_cache_size(fn) for name, fn in progs.items()}
+        out = {"fused": jit_cache_size(self._fused),
+               "decode": jit_cache_size(self._decode)}
         if self.drafter is not None:
             out.update(self.drafter.compiled_programs())
         return out

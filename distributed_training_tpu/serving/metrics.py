@@ -280,15 +280,12 @@ class ServeTelemetry:
         self._busy_t1 = time.perf_counter() if t is None else t
 
     def on_kv(self, *, reserved: int, written: int, active: int,
-              slots: int, pages_allocated: int | None = None,
-              pages_total: int | None = None,
-              pages_live: int | None = None,
-              pages_budget: int | None = None) -> None:
+              slots: int, pages_allocated: int, pages_total: int,
+              pages_live: int, pages_budget: int) -> None:
         """One decode iteration's KV-cache occupancy: ``reserved`` =
         KV positions actually HELD for occupied slots (allocated pages ×
-        page size under the paged allocator; active slots × full budget
-        on the legacy path), ``written`` = Σ live cache write heads
-        (prompt + generated positions actually holding K/V). The paged
+        page size), ``written`` = Σ live cache write heads
+        (prompt + generated positions actually holding K/V). The
         engine also reports pool occupancy (``pages_allocated`` of
         ``pages_total``), and what the iteration's attention had to
         read: ``pages_live`` = pages the live slots' positions cover, of
@@ -299,12 +296,10 @@ class ServeTelemetry:
         self.kv_written_tokens += int(written)
         self.slot_iters_active += int(active)
         self.slot_iters_total += int(slots)
-        if pages_allocated is not None and pages_total is not None:
-            self.page_iters_allocated += int(pages_allocated)
-            self.page_iters_total += int(pages_total)
-        if pages_live is not None and pages_budget is not None:
-            self.page_iters_live += int(pages_live)
-            self.page_iters_budget += int(pages_budget)
+        self.page_iters_allocated += int(pages_allocated)
+        self.page_iters_total += int(pages_total)
+        self.page_iters_live += int(pages_live)
+        self.page_iters_budget += int(pages_budget)
 
     def on_admitted(self, queue_wait_ms: float,
                     prefill_ms: float) -> None:
@@ -615,7 +610,7 @@ class ServeTelemetry:
             "slot_occupancy_mean": (
                 self.slot_iters_active / self.slot_iters_total
                 if self.slot_iters_total else 0.0),
-            # Paged-allocator pool view (0.0 on the legacy path): mean
+            # Paged-allocator pool view: mean
             # fraction of pool pages allocated per iteration, and the
             # same numerator in page-iterations for the bench gate's
             # workload-deterministic drift check.
@@ -623,7 +618,7 @@ class ServeTelemetry:
                 self.page_iters_allocated / self.page_iters_total
                 if self.page_iters_total else 0.0),
             "kv_pages_allocated_iters": int(self.page_iters_allocated),
-            # Paged attention's read (0.0 legacy): pages the live slots'
+            # Paged attention's read: pages the live slots'
             # positions covered ÷ slots × pages per slot, over the run —
             # the share of the whole-table read that was live.
             "kv_pages_live_iters": int(self.page_iters_live),

@@ -178,9 +178,7 @@ class ActiveSequence:
 
     @property
     def prefilling(self) -> bool:
-        """Seated but not yet decoding (paged engine's chunked prefill);
-        always False on the legacy path, whose batch-1 prefill emits the
-        first token before the sequence ever reaches the slot state."""
+        """Seated but not yet decoding (the engine's chunked prefill)."""
         return (self.prefill_pos < self.prefill_tokens.size
                 or not self.tokens)
 
@@ -264,9 +262,7 @@ class ActiveSequence:
             return timeout
         # TTFT deadline, mid-prefill: chunked prefill holds a slot for
         # ceil(prompt/chunk) iterations before the first token, so a
-        # request can now miss its TTFT SLA while SEATED (impossible on
-        # the legacy path, whose seat and first token share an
-        # iteration). Past the deadline with no first token it will
+        # request can miss its TTFT SLA while SEATED. Past the deadline with no first token it will
         # never make its SLA — evict so the chunk lane and its pool
         # pages go to a request that still can. A first token landing on
         # the deadline tick wins (first_token_t set → not a timeout),

@@ -28,6 +28,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 
 def add_argument() -> argparse.Namespace:
+    from distributed_training_tpu.config import kv_page_size_arg
+
     parser = argparse.ArgumentParser(
         description="TransformerLM continuous-batching serving")
     parser.add_argument("--prompts-file", type=str, default=None,
@@ -44,27 +46,23 @@ def add_argument() -> argparse.Namespace:
     parser.add_argument("--top-k", type=int, default=None)
     parser.add_argument("--top-p", type=float, default=None)
     parser.add_argument("--eos-id", type=int, default=None)
-    parser.add_argument("--kv-page-size", type=int, default=8,
+    parser.add_argument("--kv-page-size", type=kv_page_size_arg,
+                        default=8,
                         help="paged KV cache (docs/SERVING.md): KV "
                              "memory is a pool of this-many-token pages "
                              "with per-slot page tables; pages allocate "
                              "as written, so admission gates on actual "
-                             "footprint, not max-len. 0 = legacy "
-                             "contiguous per-slot reservation")
+                             "footprint, not max-len")
     parser.add_argument("--kv-pages", type=int, default=None,
                         help="KV pool size in pages; default max_batch x "
-                             "ceil(budget/page_size) = the legacy "
-                             "capacity. Smaller oversubscribes: bursts "
+                             "ceil(budget/page_size) = every slot's "
+                             "full budget. Smaller oversubscribes: bursts "
                              "queue on pages instead of slots")
     parser.add_argument("--prefill-chunk", type=int, default=64,
-                        help="chunked prefill (paged mode): prompt "
+                        help="chunked prefill: prompt "
                              "tokens prefilled per decode iteration, "
                              "riding the fused step so admission never "
                              "blocks decode")
-    parser.add_argument("--prefill-bucket", type=int, default=64,
-                        help="LEGACY prefill (--kv-page-size 0): prompt "
-                             "lengths pad to a multiple of this (bounds "
-                             "prefill compile count)")
     parser.add_argument("--prefix-cache",
                         action=argparse.BooleanOptionalAction,
                         default=False,
@@ -75,8 +73,7 @@ def add_argument() -> argparse.Namespace:
                              "page-aligned prefix aliases them, "
                              "prefilling only the tail — shared system "
                              "prompts prefill once. Bitwise-neutral; "
-                             "flushed at every hot-swap barrier. "
-                             "Requires paged mode (--kv-page-size > 0)")
+                             "flushed at every hot-swap barrier")
     parser.add_argument("--prefix-cache-pages", type=int, default=None,
                         help="cap on pool pages the prefix-cache trie "
                              "may hold (LRU leaves evict past it); "
@@ -125,9 +122,7 @@ def add_argument() -> argparse.Namespace:
                              "per-head scales, quantizing on scatter "
                              "and dequantizing in the gather inside "
                              "the same compiled programs (inventory "
-                             "stays at 2). Requires paged mode "
-                             "(--kv-page-size > 0). Default: model "
-                             "dtype")
+                             "stays at 2). Default: model dtype")
     # SLO tiers + multi-tenant fairness (docs/SERVING.md "Tiered
     # scheduling & preemption").
     parser.add_argument("--num-tiers", type=int, default=1,
@@ -356,10 +351,9 @@ def main() -> int:
         top_k=args.top_k,
         top_p=args.top_p,
         eos_id=args.eos_id,
-        kv_page_size=args.kv_page_size or None,
+        kv_page_size=args.kv_page_size,
         kv_pages=args.kv_pages,
         prefill_chunk=args.prefill_chunk,
-        prefill_bucket=args.prefill_bucket,
         prefix_cache=args.prefix_cache,
         prefix_cache_pages=args.prefix_cache_pages,
         spec_k=args.spec_k,

@@ -190,8 +190,7 @@ def served():
     params = model.init(jax.random.PRNGKey(0),
                         np.zeros((1, 8), np.int32))["params"]
     eng = Engine(model, params, ServeConfig(
-        max_batch=2, max_new_tokens=N_NEW, prefill_bucket=4,
-        flush_every=2))
+        max_batch=2, max_new_tokens=N_NEW, flush_every=2))
     exp = MetricsExporter(eng.flight_snapshot, port=0,
                           phase_provider=lambda: eng.phase).start()
     rng = np.random.RandomState(3)

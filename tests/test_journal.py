@@ -8,7 +8,7 @@ Load-bearing properties, in order of importance:
    re-deliver from the log exactly once, unfinished requests re-seat
    through the round-16 resume path, and every completed output is
    BITWISE identical to the uninterrupted single-slot oracle (greedy
-   and sampled, paged and legacy, speculation on and off). Tokens past
+   and sampled, speculation on and off). Tokens past
    the last durable flush are recomputed by the same
    ``fold_in(rng, position)`` induction, not lost.
 2. **Durable-format robustness**: length-prefixed crc-framed records;
@@ -366,19 +366,12 @@ class TestJournalUnit:
         assert e.deadline_rel_s == pytest.approx(30.0)
 
 
-# Every axis value (paged/legacy, spec 0/2) under both greedy and
-# sampled temperatures, without the full product. The legacy-cache
-# combos ride the slow mark (round-8 tier-1 budget note): the resume
-# path they share is already tier-1-pinned by test_preemption, and the
-# paged combos + the CI crash drill carry the per-push recovery claim.
+# Spec 0/2 across greedy and sampled, without the full product: the
+# resume path they share is tier-1-pinned by test_preemption, and these
+# + the CI crash drill carry the per-push recovery claim.
 CRASH_CASES = [
     ({"prefill_chunk": 4}, 0.0),
     ({"prefill_chunk": 4, "spec_k": 2}, 0.8),
-    pytest.param({"kv_page_size": None, "prefill_bucket": 8}, 0.0,
-                 marks=pytest.mark.slow),
-    pytest.param({"kv_page_size": None, "prefill_bucket": 8,
-                  "spec_k": 2, "max_len": 40}, 0.8,
-                 marks=pytest.mark.slow),
 ]
 
 
@@ -417,8 +410,7 @@ class TestCrashRecovery:
                for f in rep["redelivered"] + rep["completed_at_replay"]}
         for f in eng2.drain():
             out[f.uid] = f.tokens.tolist()
-        if eng2.paged:
-            eng2.pool.check_balanced()
+        eng2.pool.check_balanced()
         solo = _solo_outputs(model, params, [(p, 8) for p in prompts[:3]],
                              temperature=temp, **cfg_kw)
         assert sorted(out) == uids

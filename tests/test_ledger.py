@@ -6,7 +6,7 @@ Load-bearing properties, in order of importance:
    ``(cause, start, end)`` intervals partition its wall lifetime —
    ``sum(intervals) == finish_t − arrival_t`` within
    ``ledger.EPSILON_S`` — under EVERY composition the engine supports:
-   greedy/sampled × paged/legacy × speculation on/off × preemption ×
+   greedy/sampled × pages of 8/16 × speculation on/off × preemption ×
    hot-swap × crash recovery, and for queue-side completions (timeout,
    shed) that never reached a slot.
 2. **TTFT decomposition**: for an unpreempted, unrecovered request,
@@ -227,22 +227,17 @@ class TestLedgerUnit:
         assert st["ledger_conservation_violations"] == 0
 
 
-# Every axis value (greedy/sampled, paged/legacy, spec 0/2) appears at
-# least twice across the tier-1 cases without the full 8-way product.
+# Every axis value (greedy/sampled, spec 0/2) appears twice across the
+# tier-1 cases, and the page size every benchmark cell runs (16; the
+# default is 8) once.
 MATRIX_T1 = [
     ({"prefill_chunk": 4}, 0.0),
     ({"prefill_chunk": 4, "spec_k": 2}, 0.8),
-    ({"kv_page_size": None, "prefill_bucket": 8}, 0.8),
-    ({"kv_page_size": None, "prefill_bucket": 8, "spec_k": 2,
-      "max_len": 40}, 0.0),
+    ({"prefill_chunk": 4, "kv_page_size": 16}, 0.8),
+    ({"prefill_chunk": 4, "spec_k": 2}, 0.0),
 ]
 MATRIX_FULL = [
-    (dict(base, **({} if spec == 0 else {"spec_k": spec,
-                                         **({"max_len": 40}
-                                            if "kv_page_size" in base
-                                            else {})})), temp)
-    for base in ({"prefill_chunk": 4},
-                 {"kv_page_size": None, "prefill_bucket": 8})
+    ({"prefill_chunk": 4, **({"spec_k": spec} if spec else {})}, temp)
     for spec in (0, 2)
     for temp in (0.0, 0.8)
 ]

@@ -278,10 +278,6 @@ class TestCacheHitBitwise:
                     "prefix_cache_pages_held", "ledger_tokens_prefix_hit"):
             assert st[key] == 0
 
-    def test_legacy_path_refuses(self, lm):
-        with pytest.raises(ValueError, match="prefix_cache requires"):
-            make_engine(lm, prefix_cache=True, kv_page_size=None)
-
 
 class TestEvictionPressure:
     def test_pool_pressure_evicts_and_stays_balanced(self, lm):

@@ -195,9 +195,11 @@ class TestQuantizeParams:
 
 # -- config gating ----------------------------------------------------------
 class TestConfig:
-    def test_kv_dtype_requires_paged(self):
-        with pytest.raises(ValueError, match="paged"):
-            ServeConfig(kv_dtype="int8", kv_page_size=None)
+    @pytest.mark.parametrize("page_size", [None, 0, -1])
+    def test_kv_page_size_below_one_rejected(self, page_size):
+        """The option that selected the contiguous-slot engine."""
+        with pytest.raises(ValueError, match="removed in PR 29"):
+            ServeConfig(kv_page_size=page_size)
 
     def test_unknown_kv_dtype_rejected(self):
         with pytest.raises(ValueError, match="kv_dtype"):

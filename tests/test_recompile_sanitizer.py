@@ -2,13 +2,12 @@
 
 The runtime half of the static-shape discipline (the AST half is
 ``tools/lint``'s ``static-shape`` rule): the serving engine's documented
-inventory — paged = 2 compiled programs, legacy = 3, one shape per
-program except the bucketed legacy prefill (docs/SERVING.md
-"compiled-program inventory") — is pinned through
+inventory — 2 compiled programs, one shape per program
+(docs/SERVING.md "compiled-program inventory") — is pinned through
 ``Engine.compiled_programs()`` + ``check_engine_inventory``, and a warm
 steady state must not compile at all (``CompileWatch``). The growth
-case forces a retrace the way a real leak would appear (a prompt
-landing in an unwarmed bucket) and asserts the sanitizer trips.
+case forces a retrace the way a real leak would appear (a step input
+whose width varies) and asserts the sanitizer trips.
 """
 
 import jax
@@ -77,31 +76,50 @@ class TestCompileWatch:
         assert b > a >= 0
 
 
+def _gpt_engine(lm):
+    model, params = lm
+    eng = Engine(model, params, ServeConfig(
+        max_batch=2, max_new_tokens=4, temperature=0.0,
+        prefill_chunk=4))
+    _submit(eng, [3, 5, 7])
+    assert len(eng.run()) == 3
+    return eng
+
+
+def _prefix_hit_engine(lm):
+    """The same prompt twice: the second seat aliases the first's two
+    full pages and its chunk lane starts past them."""
+    model, params = lm
+    eng = Engine(model, params, ServeConfig(
+        max_batch=2, max_new_tokens=4, temperature=0.0,
+        prefill_chunk=4, kv_page_size=4, prefix_cache=True))
+    for _ in range(2):
+        _submit(eng, [9])
+        assert len(eng.run()) == 1
+    assert eng.stats()["prefix_cache_hit_tokens"] == 8
+    return eng
+
+
+def _deepseek_engine(lm):
+    """The second model family's toy (latent and index pools, experts'
+    step counters as one more output of both programs)."""
+    from test_deepseek_v32 import run_engine
+
+    return run_engine(5, (24, 31), max_new=4)[0]
+
+
 class TestEngineInventory:
-    def test_paged_engine_pins_two_programs_one_shape(self, lm):
-        model, params = lm
-        eng = Engine(model, params, ServeConfig(
-            max_batch=2, max_new_tokens=4, temperature=0.0,
-            prefill_chunk=4))
-        _submit(eng, [3, 5, 7])
-        assert len(eng.run()) == 3
+    @pytest.mark.parametrize("build", [
+        _gpt_engine, _prefix_hit_engine, _deepseek_engine],
+        ids=["gpt", "prefix_hit", "deepseek_v32"])
+    def test_engine_pins_two_programs_one_shape(self, lm, build):
+        eng = build(lm)
         progs = eng.compiled_programs()
         # Both programs ran (chunked prefill rode the fused step; the
         # post-prefill iterations were decode-only) and each holds
         # exactly one trace.
         assert progs == {"fused": 1, "decode": 1}
         assert check_engine_inventory(eng) == progs
-
-    def test_legacy_engine_pins_three_programs(self, lm):
-        model, params = lm
-        eng = Engine(model, params, ServeConfig(
-            max_batch=2, max_new_tokens=4, temperature=0.0,
-            kv_page_size=None, prefill_bucket=8))
-        _submit(eng, [3, 5, 7])  # one shared 8-token prefill bucket
-        assert len(eng.run()) == 3
-        progs = eng.compiled_programs()
-        assert progs == {"prefill": 1, "admit": 1, "decode": 1}
-        assert check_engine_inventory(eng, prefill_shapes=1) == progs
 
     def test_warm_paged_steady_state_never_compiles(self, lm):
         model, params = lm
@@ -120,22 +138,27 @@ class TestEngineInventory:
         model, params = lm
         eng = Engine(model, params, ServeConfig(
             max_batch=2, max_new_tokens=4, temperature=0.0,
-            kv_page_size=None, prefill_bucket=8))
+            prefill_chunk=4))
         _submit(eng, [3, 5])
-        eng.run()  # warm within the first bucket only
-        check_engine_inventory(eng, prefill_shapes=1)
+        eng.run()  # warm: both programs, one shape each
+        check_engine_inventory(eng)
+        # A decode lane two tokens wide where the engine's is one, every
+        # row invalid (it writes the null page): a step input whose
+        # width varies, as a leak would have it.
+        wide = jnp.zeros((2, 2), jnp.int32)
         with CompileWatch() as watch:
-            _submit(eng, [13])  # lands in the UNWARMED second bucket
-            eng.run()
+            eng._decode(eng.params, eng._cache, wide, wide,
+                        wide.astype(bool), jnp.asarray(eng._slot_rng),
+                        jnp.asarray(eng._tables), wide[:, 0], wide,
+                        eng._no_tokens[1])
         # The forced retrace is visible on both surfaces: the window
-        # compiled, and the prefill program now holds two shapes.
+        # compiled, and the decode program now holds two shapes.
         assert watch.compiles >= 1
         with pytest.raises(RecompileError, match="must not retrace"):
-            watch.check_no_growth("legacy window with a cross-bucket "
-                                  "prompt")
-        assert eng.compiled_programs()["prefill"] == 2
-        with pytest.raises(RecompileError, match="prefill"):
-            check_engine_inventory(eng, prefill_shapes=1)
+            watch.check_no_growth("window with a second lane width")
+        assert eng.compiled_programs()["decode"] == 2
+        with pytest.raises(RecompileError, match="decode"):
+            check_engine_inventory(eng)
 
     def test_fixture_hands_out_a_marked_watch(self, lm, compile_watch):
         # The conftest fixture arms a watch before the test body; a

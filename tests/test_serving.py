@@ -6,16 +6,14 @@ Load-bearing properties, in order of importance:
    paged KV pool + chunked prefill, the default — is token-identical to
    the sequential :class:`Generator` (temperature 0) run per prompt:
    slot packing, page-table gathers, chunked prefill, and mid-flight
-   refills must not change a single emitted token. The legacy
-   contiguous path (kv_page_size=None) is pinned equal too.
+   refills must not change a single emitted token.
 2. **Composition independence**: a request's tokens are bitwise
    independent of which other requests share the batch (engine at
    max_batch=N == engine at max_batch=1), greedy AND sampled — per-row
    arithmetic independence and fold_in(uid, position) RNG guarantee it.
    The solo engine runs a DIFFERENT prefill chunking (chunk 4, forcing
    multi-chunk prefills) against the batched engine's single-chunk
-   prefills, so the same equality pins chunking invisibility (the
-   legacy analogue pinned bucket-padding invisibility).
+   prefills, so the same equality pins chunking invisibility.
 3. **Scheduler mechanics**: FIFO admission (page-aware under an
    oversubscribed pool), slot refill at iteration boundaries,
    EOS/length eviction, typed page-accounted admission rejection.
@@ -83,7 +81,7 @@ def prompts():
 
 def _serve(model, params, prompts, **cfg_kw):
     """Run one engine over ``prompts``; returns (engine, {uid: result})."""
-    cfg = ServeConfig(**{"prefill_bucket": 8, **cfg_kw})
+    cfg = ServeConfig(**cfg_kw)
     eng = Engine(model, params, cfg)
     for p in prompts:
         eng.submit(p)
@@ -94,8 +92,8 @@ def _serve(model, params, prompts, **cfg_kw):
 
 @pytest.fixture(scope="module")
 def batched_greedy(lm, prompts):
-    """6 greedy requests through 2 slots (3× oversubscription, padded
-    prefill buckets) — the shared continuous-batching run."""
+    """6 greedy requests through 2 slots (3× oversubscription) — the
+    shared continuous-batching run."""
     model, params = lm
     return _serve(model, params, prompts, max_batch=2,
                   max_new_tokens=N_NEW, temperature=0.0, flush_every=2)
@@ -109,17 +107,6 @@ def solo_greedy(lm, prompts):
     model, params = lm
     return _serve(model, params, prompts, max_batch=1,
                   max_new_tokens=N_NEW, temperature=0.0, prefill_chunk=4)
-
-
-@pytest.fixture(scope="module")
-def legacy_greedy(lm, prompts):
-    """The pre-paging engine (contiguous max_len slots, bucketed batch-1
-    prefill) on the same workload — the before side of the before/after
-    evidence pair, and the layout-equivalence oracle."""
-    model, params = lm
-    return _serve(model, params, prompts, max_batch=2,
-                  max_new_tokens=N_NEW, temperature=0.0,
-                  kv_page_size=None)
 
 
 class TestOracleEquivalence:
@@ -146,17 +133,6 @@ class TestOracleEquivalence:
         for uid in batched:
             np.testing.assert_array_equal(batched[uid].tokens,
                                           solo[uid].tokens)
-
-    def test_legacy_contiguous_engine_bitwise_equal(self, legacy_greedy,
-                                                    batched_greedy):
-        """The legacy contiguous-slot path (kv_page_size=None: bucketed
-        batch-1 prefill + vmapped decode) emits bitwise-identical tokens
-        to the paged+chunked default — one oracle, two cache layouts."""
-        _, legacy = legacy_greedy
-        _, paged = batched_greedy
-        for uid in paged:
-            np.testing.assert_array_equal(paged[uid].tokens,
-                                          legacy[uid].tokens)
 
     def test_oversubscribed_pool_completes_and_matches(self, lm, prompts,
                                                        batched_greedy):
@@ -221,8 +197,7 @@ class TestSchedulerMechanics:
         head["bias"] = head["bias"].at[eos].add(1e4)
         biased["lm_head"] = head
         eng = Engine(model, biased, ServeConfig(
-            max_batch=1, max_new_tokens=N_NEW, eos_id=eos,
-            prefill_bucket=8))
+            max_batch=1, max_new_tokens=N_NEW, eos_id=eos))
         eng.submit(np.array([1, 2], np.int32))
         eng.submit(np.array([3, 4, 5], np.int32))
         done = eng.run()
@@ -238,7 +213,7 @@ class TestSchedulerMechanics:
         prefill emits the token) and matches the full run's first token."""
         model, params = lm
         eng = Engine(model, params, ServeConfig(
-            max_batch=2, max_new_tokens=1, prefill_bucket=8))
+            max_batch=2, max_new_tokens=1))
         eng.submit(prompts[0])
         done = eng.run()
         assert len(done) == 1 and done[0].tokens.size == 1
@@ -286,18 +261,13 @@ class TestAdmissionControl:
         needed vs what the table/pool can ever serve one sequence."""
         model, params = lm
         eng = Engine(model, params, ServeConfig(
-            max_batch=1, max_new_tokens=2, max_len=16, prefill_bucket=8))
+            max_batch=1, max_new_tokens=2, max_len=16))
         with pytest.raises(CacheBudgetError,
                            match=r"needs 3 KV page\(s\) of 8"):
             eng.submit(np.arange(15, dtype=np.int32))  # 15 + 2 > 16
         eng.submit(np.arange(8, dtype=np.int32))       # 8 + 2 fits
         assert len(eng.run()) == 1
         assert eng.queue.rejected == 1
-        # Legacy path keeps the token-based message.
-        leg = Engine(model, params, ServeConfig(
-            max_batch=1, max_new_tokens=2, max_len=16, kv_page_size=None))
-        with pytest.raises(CacheBudgetError, match="exceeds the KV cache"):
-            leg.submit(np.arange(15, dtype=np.int32))
 
     def test_empty_prompt_rejected(self, lm):
         model, params = lm
@@ -313,7 +283,7 @@ class TestGracefulDegradation:
     def test_drain_completes_inflight_and_rejects_new(self, lm, prompts):
         model, params = lm
         eng = Engine(model, params, ServeConfig(
-            max_batch=1, max_new_tokens=3, prefill_bucket=8))
+            max_batch=1, max_new_tokens=3))
         for p in prompts[:3]:
             eng.submit(p)
         done = eng.drain()
@@ -332,8 +302,7 @@ class TestGracefulDegradation:
     def test_bounded_queue_sheds_typed(self, lm, prompts):
         model, params = lm
         eng = Engine(model, params, ServeConfig(
-            max_batch=1, max_new_tokens=2, max_queue_depth=1,
-            prefill_bucket=8))
+            max_batch=1, max_new_tokens=2, max_queue_depth=1))
         eng.submit(prompts[0])  # queued (no iteration has run)
         with pytest.raises(QueueFullError, match="max_depth"):
             eng.submit(prompts[1])
@@ -345,8 +314,7 @@ class TestGracefulDegradation:
                                                 tmp_path):
         model, params = lm
         eng = Engine(model, params, ServeConfig(
-            max_batch=1, max_new_tokens=3, prefill_bucket=8,
-            ttft_deadline_ms=50.0))
+            max_batch=1, max_new_tokens=3, ttft_deadline_ms=50.0))
         # Arrival backdated past the TTFT deadline: the engine must
         # evict it from the queue with reason 'timeout' and zero tokens
         # instead of spending a prefill on a request that already
@@ -407,9 +375,8 @@ class TestGracefulDegradation:
         assert sched.num_active == 0
 
     def test_ttft_deadline_evicts_mid_prefill(self, lm, prompts):
-        """Chunked prefill opens a seated-but-no-first-token window the
-        legacy path never had (seat and first token shared an
-        iteration): a request past its TTFT deadline mid-prefill must
+        """Chunked prefill opens a seated-but-no-first-token window: a
+        request past its TTFT deadline mid-prefill must
         leave with reason 'timeout' — not monopolize the chunk lane for
         its remaining chunks and then pollute the TTFT percentiles with
         a deadline-violating sample."""
@@ -518,9 +485,8 @@ class TestUtilizationAccounting:
     def test_kv_reserved_vs_written_pinned_mixed_lengths(
             self, batched_greedy):
         """Acceptance: with the paged allocator the reservation tracks
-        the write head to page granularity — the analytic pin AND the
-        headline: the ratio drops from the legacy ×4+ over-reservation
-        to < 1.5 on the same mixed-length workload. Both counters stay
+        the write head to page granularity — the analytic pin, and the
+        ratio stays < 1.5 on the mixed-length workload. Both counters stay
         workload-deterministic (per-request sums over each request's own
         prefill-chunk and decode iterations)."""
         eng, by_uid = batched_greedy
@@ -537,20 +503,6 @@ class TestUtilizationAccounting:
         assert stats["kv_pages_allocated_iters"] \
             == exp_reserved // eng.page_size
         assert 0.0 < stats["page_pool_occupancy_mean"] <= 1.0
-
-    def test_legacy_kv_over_reservation_still_measured(self, legacy_greedy):
-        """The legacy path still reports the ×4+ over-reservation the
-        paged allocator reclaims — the before/after evidence pair."""
-        eng, _ = legacy_greedy
-        iters = N_NEW - 1  # first token comes from prefill
-        exp_written = sum(iters * l + iters * (iters + 1) // 2
-                          for l in PROMPT_LENS)
-        exp_reserved = len(PROMPT_LENS) * iters * eng.budget
-        stats = eng.stats()
-        assert stats["kv_written_tokens"] == exp_written
-        assert stats["kv_reserved_tokens"] == exp_reserved
-        assert stats["kv_reserved_vs_written"] > 4.0
-        assert stats["page_pool_occupancy_mean"] == 0.0
 
     def test_admission_breakdown_and_occupancy(self, batched_greedy):
         eng, by_uid = batched_greedy
@@ -598,8 +550,7 @@ class TestServeBenchCli:
             "serve_bench.py", "--requests", "6", "--rate", "500",
             "--max-batch", "2", "--num-layers", "1", "--num-heads", "2",
             "--hidden-dim", "32", "--model-max-len", "64",
-            "--prompt-len", "6", "--max-new-tokens", "4",
-            "--prefill-bucket", "16"])
+            "--prompt-len", "6", "--max-new-tokens", "4"])
         assert bench.main() == 0
         line = capsys.readouterr().out.strip().splitlines()[-1]
         stats = json.loads(line)
@@ -613,6 +564,21 @@ class TestServeBenchCli:
         assert stats["throughput_tok_s"] > 0
         assert stats["requests_finished"] == 6
         assert stats["ttft_hist_p99_ms"] >= stats["ttft_hist_p50_ms"] > 0
+
+
+@pytest.mark.parametrize("cli", [
+    "gpt/jax_tpu/serve.py", "tools/serve_bench.py", "tools/serve_net.py"])
+def test_cli_parser_refuses_page_size_zero(cli, monkeypatch, capsys):
+    """``--kv-page-size 0`` selected the contiguous-slot engine: now a
+    parser error, before a model or an engine is built."""
+    from conftest import load_cli_module
+
+    mod = load_cli_module(cli)
+    monkeypatch.setattr("sys.argv", [cli, "--kv-page-size", "0"])
+    with pytest.raises(SystemExit) as exit_info:
+        mod.main()
+    assert exit_info.value.code == 2
+    assert "removed in PR 29" in capsys.readouterr().err
 
 
 @pytest.mark.slow
@@ -643,7 +609,7 @@ class TestServeCliSigterm:
                  "--num-layers", "1", "--num-heads", "2",
                  "--hidden-dim", "32", "--model-max-len", "128",
                  "--max-new-tokens", "64", "--max-batch", "2",
-                 "--prefill-bucket", "16", "--json",
+                 "--json",
                  "--flight-dump", str(dump)],
                 stdout=subprocess.PIPE, stderr=errfh, text=True, env=env)
             # SIGTERM only once the guard is installed ("engine ready"):
@@ -686,7 +652,7 @@ class TestServeCli:
             "--prompts-file", str(pfile),
             "--num-layers", "1", "--num-heads", "2", "--hidden-dim", "32",
             "--model-max-len", "64", "--max-new-tokens", "4",
-            "--max-batch", "2", "--prefill-bucket", "16", "--json"])
+            "--max-batch", "2", "--json"])
         assert serve_cli.main() == 0
         out = capsys.readouterr().out
         lines = [ln for ln in out.splitlines() if ln.strip()]

@@ -3,8 +3,8 @@
 Load-bearing properties, in order of importance:
 
 1. **Oracle equivalence** (the acceptance criterion): greedy output
-   under speculation — both drafters, ``spec_k`` ∈ {2, 4}, paged AND
-   legacy cache layouts, 2×+ pool oversubscription — is bitwise
+   under speculation — both drafters, ``spec_k`` ∈ {2, 4},
+   2×+ pool oversubscription — is bitwise
    token-identical to the sequential :class:`Generator`. Drafts decide
    how many tokens one dispatch lands, never what any token is.
 2. **Sampled distribution-identity**: fixed-seed sampled output under
@@ -73,7 +73,7 @@ def oracle(lm, prompts):
 
 
 def _serve(model, params, prompts, drafter=None, **cfg_kw):
-    cfg = ServeConfig(**{"prefill_bucket": 8, **cfg_kw})
+    cfg = ServeConfig(**cfg_kw)
     eng = Engine(model, params, cfg, drafter=drafter)
     for p in prompts:
         eng.submit(p)
@@ -205,26 +205,6 @@ class TestOracleEquivalence:
         assert progs.get("draft") == 1
         assert eng.stats()["drafted_tokens"] > 0
 
-    def test_greedy_legacy_contiguous_matches_generator(self, lm,
-                                                        prompts, oracle):
-        """The legacy contiguous path verifies through forced
-        cache_index rewinds instead of page tables — same tokens."""
-        model, params = lm
-        _, by_uid = _serve(model, params, prompts, max_batch=2,
-                           max_new_tokens=N_NEW, temperature=0.0,
-                           spec_k=2, kv_page_size=None, max_len=32)
-        for uid in by_uid:
-            np.testing.assert_array_equal(by_uid[uid].tokens,
-                                          oracle[uid])
-
-    def test_legacy_spec_needs_cache_slack(self, lm):
-        """budget + spec_k must fit the positional table on the legacy
-        path (the contiguous window writes all its rows)."""
-        model, params = lm
-        with pytest.raises(ValueError, match="budget \\+ spec_k"):
-            Engine(model, params, ServeConfig(
-                max_batch=1, spec_k=2, kv_page_size=None))
-
     def test_sampled_spec_bitwise_equal_to_nonspec(self, lm, prompts):
         """Fixed-seed sampled outputs: speculation on == speculation
         off, bitwise — the logit-trace/RNG stream is position-pinned,
@@ -276,8 +256,7 @@ class TestAcceptScheduling:
         head["bias"] = head["bias"].at[eos].add(1e4)
         biased["lm_head"] = head
         eng = Engine(model, biased, ServeConfig(
-            max_batch=1, max_new_tokens=N_NEW, eos_id=eos, spec_k=3,
-            prefill_bucket=8))
+            max_batch=1, max_new_tokens=N_NEW, eos_id=eos, spec_k=3))
         eng.submit(np.array([1, 2], np.int32))
         eng.submit(np.array([3, 4, 5], np.int32))
         done = eng.run()
@@ -327,7 +306,7 @@ class TestDraftEconomics:
         model, params = lm
         eng = Engine(model, params, ServeConfig(
             max_batch=2, max_new_tokens=N_NEW, temperature=0.0,
-            spec_k=2, prefill_bucket=8))
+            spec_k=2))
 
         def window():
             for p in prompts:
@@ -363,8 +342,7 @@ class TestHotSwapMidSpeculation:
         model, params = lm
         eng = Engine(model, params, ServeConfig(
             max_batch=1, max_new_tokens=N_NEW, temperature=0.0,
-            spec_k=2, spec_drafter="gpt", spec_draft_window=8,
-            prefill_bucket=8))
+            spec_k=2, spec_drafter="gpt", spec_draft_window=8))
         assert eng.drafter.mirror_target
         assert eng.drafter.params is eng.params
         params2 = model.init(jax.random.PRNGKey(3),
@@ -419,15 +397,3 @@ class TestSpecSweep:
             np.testing.assert_array_equal(by_uid[uid].tokens,
                                           oracle[uid])
         eng.pool.check_balanced()
-
-    @pytest.mark.parametrize("spec_k", [1, 4])
-    def test_legacy_sweep_matches_generator(self, lm, prompts, oracle,
-                                            spec_k):
-        model, params = lm
-        _, by_uid = _serve(model, params, prompts, max_batch=2,
-                           max_new_tokens=N_NEW, temperature=0.0,
-                           spec_k=spec_k, kv_page_size=None,
-                           max_len=32)
-        for uid in by_uid:
-            np.testing.assert_array_equal(by_uid[uid].tokens,
-                                          oracle[uid])

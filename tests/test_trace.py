@@ -154,8 +154,7 @@ def traced_engine():
                         np.zeros((1, 8), np.int32))["params"]
     tr = TraceSession(process_name="serve-test")
     eng = Engine(model, params,
-                 ServeConfig(max_batch=2, max_new_tokens=4,
-                             prefill_bucket=16), trace=tr)
+                 ServeConfig(max_batch=2, max_new_tokens=4), trace=tr)
     rng = np.random.RandomState(0)
     for _ in range(4):
         eng.submit(rng.randint(0, 64, size=5).astype(np.int32))

@@ -149,7 +149,7 @@ def render(summary: dict) -> str:
                 f"reserved {srv['kv_reserved_tokens']:.0f} token-iters  "
                 f"(over-reservation x{srv['kv_reserved_vs_written']:.2f})"
                 f"  |  slot occupancy {srv['slot_occupancy_mean']:.1%}")
-        # Paged-KV pool view (0 on the legacy contiguous path).
+        # Paged-KV pool view.
         if srv.get("page_pool_occupancy_mean"):
             add(f"    kv pages: pool occupancy "
                 f"{srv['page_pool_occupancy_mean']:.1%}  "

@@ -38,6 +38,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def add_argument() -> argparse.Namespace:
+    from distributed_training_tpu.config import kv_page_size_arg
+
     p = argparse.ArgumentParser(
         description="Poisson-load benchmark for the serving engine")
     p.add_argument("--requests", type=int, default=32,
@@ -88,16 +90,14 @@ def add_argument() -> argparse.Namespace:
     p.add_argument("--max-new-tokens", type=int, default=32)
     p.add_argument("--temperature", type=float, default=0.0)
     p.add_argument("--eos-id", type=int, default=None)
-    p.add_argument("--kv-page-size", type=int, default=8,
-                   help="paged KV cache: pool page size in tokens; "
-                        "0 = legacy contiguous per-slot reservation "
-                        "(and legacy bucketed prefill)")
+    p.add_argument("--kv-page-size", type=kv_page_size_arg, default=8,
+                   help="paged KV cache: pool page size in tokens")
     p.add_argument("--kv-pages", type=int, default=None,
                    help="KV pool size in pages; default max_batch x "
                         "ceil(budget/page) (no oversubscription)")
     p.add_argument("--prefill-chunk", type=int, default=64,
                    help="chunked prefill: prompt tokens prefilled per "
-                        "decode iteration (paged mode)")
+                        "decode iteration")
     p.add_argument("--prefix-cache", action=argparse.BooleanOptionalAction,
                    default=False,
                    help="radix-tree prefix cache over the paged pool "
@@ -107,14 +107,11 @@ def add_argument() -> argparse.Namespace:
                         "aliases them and prefills only the tail — "
                         "bitwise-neutral, pure TTFT/prefill-compute "
                         "win on shared-boilerplate traffic (pair with "
-                        "--scenario shared_prefix). Requires paged "
-                        "mode (--kv-page-size > 0)")
+                        "--scenario shared_prefix)")
     p.add_argument("--prefix-cache-pages", type=int, default=None,
                    help="cap on pool pages the prefix-cache trie may "
                         "hold (LRU leaves evict past it); default "
                         "unbounded within the pool")
-    p.add_argument("--prefill-bucket", type=int, default=16,
-                   help="LEGACY prefill bucketing (--kv-page-size 0)")
     p.add_argument("--spec-k", type=int, default=0,
                    help="speculative decoding: drafts proposed per slot "
                         "per iteration, verified in one fixed-width "
@@ -148,8 +145,7 @@ def add_argument() -> argparse.Namespace:
                         "pages as int8 with per-row per-head scales "
                         "(quantize-on-scatter / dequantize-in-gather "
                         "inside the same compiled programs — the "
-                        "inventory stays at 2). Requires paged mode "
-                        "(--kv-page-size > 0). Default: model dtype")
+                        "inventory stays at 2). Default: model dtype")
     # Tiny random-weight model (no checkpoint: this benches the ENGINE —
     # scheduling, prefill/decode latency — not model quality).
     p.add_argument("--vocab-size", type=int, default=256)
@@ -170,8 +166,8 @@ def add_argument() -> argparse.Namespace:
                         "swap_blocked_s) alongside latency. 0 = off")
     p.add_argument("--check-compiles", action="store_true", default=False,
                    help="compiled-program sanitizer: after warm-up, pin "
-                        "the engine's program inventory (paged: 2, "
-                        "legacy: 3; docs/SERVING.md) and fail — exit 1, "
+                        "the engine's program inventory (2 programs; "
+                        "docs/SERVING.md) and fail — exit 1, "
                         "one-line error — if anything recompiles inside "
                         "the measured window (silent retrace growth). "
                         "Requires warm-up (ignored with --no-warmup)")
@@ -327,10 +323,9 @@ def main() -> int:
         max_batch=args.max_batch, max_len=args.max_len,
         max_new_tokens=args.max_new_tokens,
         temperature=args.temperature, eos_id=args.eos_id,
-        kv_page_size=args.kv_page_size or None,
+        kv_page_size=args.kv_page_size,
         kv_pages=args.kv_pages,
         prefill_chunk=args.prefill_chunk,
-        prefill_bucket=args.prefill_bucket,
         prefix_cache=args.prefix_cache,
         prefix_cache_pages=args.prefix_cache_pages,
         spec_k=args.spec_k, spec_drafter=args.spec_drafter,
@@ -397,10 +392,9 @@ def main() -> int:
     elif not args.no_warmup:
         # Compile on the measured engine itself (compiles are
         # per-jit-closure, so a throwaway engine would not warm this
-        # one), then reset the telemetry window. Paged mode has exactly
+        # one), then reset the telemetry window. The engine has exactly
         # two shapes — the fused chunk+decode step and the decode-only
-        # step — so two short requests cover them; legacy mode walks
-        # every prefill bucket.
+        # step — so two short requests cover them.
         # Speculation needs at least one drafted decode iteration in
         # the warm-up (remaining budget > 1) so a GPT drafter's
         # 'draft' program compiles outside the measured window; the
@@ -412,21 +406,11 @@ def main() -> int:
         # how many slots are active).
         warm_new = 4 if args.spec_k else 2
         warm_fins = []
-        if engine.paged:
-            for _ in range(2):
-                engine.submit(rng.randint(0, args.vocab_size,
-                                          size=2).astype(np.int32),
-                              max_new_tokens=warm_new)
-                warm_fins.extend(engine.run())
-        else:
-            for lb in range(args.prefill_bucket, 2 * args.prompt_len - 1 +
-                            args.prefill_bucket, args.prefill_bucket):
-                # keep warm-ups admissible
-                lb = min(lb, engine.budget - warm_new)
-                engine.submit(rng.randint(0, args.vocab_size,
-                                          size=lb).astype(np.int32),
-                              max_new_tokens=warm_new)
-                warm_fins.extend(engine.run())
+        for _ in range(2):
+            engine.submit(rng.randint(0, args.vocab_size,
+                                      size=2).astype(np.int32),
+                          max_new_tokens=warm_new)
+            warm_fins.extend(engine.run())
         if engine.journal is not None:
             # Warm-up results are consumed here and now: ack them so a
             # later recovery neither redelivers them nor carries them
@@ -586,13 +570,12 @@ def main() -> int:
         f"delivered {delivered} + {shed_at_submit} shed-at-submit, "
         f"expected {expected} ({n} requests, scenario resumed at "
         f"{submitted_start}, {recovered_n} recovered)")
-    if engine.paged:
-        # Leak audit: every page back on the free list (or held by
-        # exactly the prefix-cache trie at one reference each), no
-        # stranded commitment — speculation's accept-rewind and the
-        # prefix cache's aliasing/eviction churn included (the CI
-        # speculation and prefix-cache legs run on this assertion).
-        engine.check_balanced()
+    # Leak audit: every page back on the free list (or held by
+    # exactly the prefix-cache trie at one reference each), no
+    # stranded commitment — speculation's accept-rewind and the
+    # prefix cache's aliasing/eviction churn included (the CI
+    # speculation and prefix-cache legs run on this assertion).
+    engine.check_balanced()
 
     if compile_watch is not None:
         from distributed_training_tpu.observability.sanitizer import (

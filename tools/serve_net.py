@@ -48,6 +48,8 @@ def add_engine_args(p: argparse.ArgumentParser) -> None:
     """The serve_bench-compatible subset of engine knobs a replica
     needs (tiny random-weight model: this drills the NETWORK plane —
     routing, streaming, deploys — not model quality)."""
+    from distributed_training_tpu.config import kv_page_size_arg
+
     p.add_argument("--vocab-size", type=int, default=256)
     p.add_argument("--num-layers", type=int, default=2)
     p.add_argument("--num-heads", type=int, default=2)
@@ -57,7 +59,7 @@ def add_engine_args(p: argparse.ArgumentParser) -> None:
     p.add_argument("--max-len", type=int, default=192)
     p.add_argument("--max-new-tokens", type=int, default=16)
     p.add_argument("--temperature", type=float, default=0.0)
-    p.add_argument("--kv-page-size", type=int, default=8)
+    p.add_argument("--kv-page-size", type=kv_page_size_arg, default=8)
     p.add_argument("--kv-pages", type=int, default=256)
     p.add_argument("--no-prefix-cache", action="store_true",
                    default=False)
@@ -89,7 +91,7 @@ def build_engine(args: argparse.Namespace, trace=None):
         max_batch=args.max_batch, max_len=args.max_len,
         max_new_tokens=args.max_new_tokens,
         temperature=args.temperature,
-        kv_page_size=args.kv_page_size or None, kv_pages=args.kv_pages,
+        kv_page_size=args.kv_page_size, kv_pages=args.kv_pages,
         prefix_cache=not args.no_prefix_cache,
         journal_dir=args.journal_dir, seed=args.seed)
     return Engine(model, params, cfg, trace=trace)
