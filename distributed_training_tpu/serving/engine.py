@@ -93,6 +93,7 @@ zero-tolerance (docs/OBSERVABILITY.md "Latency ledger").
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 import time
 from typing import Any
@@ -165,6 +166,84 @@ from distributed_training_tpu.serving.timeseries import (
 # ``nxt[slot, 0]``; or, for a slot whose final chunk was that step, its
 # ``c_sampled[row]`` as ``_SRC_CHUNK + row``.
 _SRC_HOST, _SRC_NXT, _SRC_CHUNK = 0, 1, 2
+
+
+@dataclasses.dataclass(frozen=True)
+class _StepLayout:
+    """Where each thing the host hands a device step lies in the ONE
+    int32 vector a launch transfers (one transfer costs the host the
+    same whatever its size, so six or eleven of them were most of a
+    launch: PERF.md §5).
+
+    A block ``[slots, 3·width + 1 + key_words + pages]`` — per decode
+    lane its tokens, positions, valid rows as 0/1, source (``_SRC_*``),
+    RNG key words bit-cast from uint32, and page-table row — and, for
+    the fused program only, a tail of ``3·chunk + 1``: the chunk's
+    tokens, positions, valid rows and its SLOT, whose table row and key
+    the device takes from the block. :meth:`pack` is host numpy,
+    :meth:`unpack` runs under ``jit``; no field changes value between
+    the two."""
+
+    slots: int
+    width: int
+    key_words: int
+    pages: int
+    chunk: int
+
+    @property
+    def lane(self) -> int:
+        return 3 * self.width + 1 + self.key_words + self.pages
+
+    @functools.cached_property
+    def _cols(self) -> tuple[slice, ...]:
+        """A lane's six fields as column slices, in the block's order."""
+        widths = (self.width,) * 3 + (1, self.key_words, self.pages)
+        ends = np.cumsum(widths).tolist()
+        return tuple(map(slice, [0] + ends[:-1], ends))
+
+    def size(self, fused: bool) -> int:
+        return self.slots * self.lane + (3 * self.chunk + 1) * fused
+
+    def pack(self, d_tok, d_pos, d_valid, d_src, rngs, tables,
+             chunk=(), chunk_slot: int = 0) -> np.ndarray:
+        """A fresh buffer each call: the step it is handed to may read
+        it where it lies (a CPU backend aliases host memory), and the
+        host goes on writing its tables and keys."""
+        n = self.slots * self.lane
+        fused = len(chunk) > 0
+        buf = np.empty((self.size(fused),), np.int32)
+        lanes = buf[:n].reshape(self.slots, self.lane)
+        for cols, field in zip(self._cols, (
+                d_tok, d_pos, d_valid, d_src[:, None],
+                rngs.view(np.int32), tables)):
+            lanes[:, cols] = field
+        if fused:
+            buf[n:-1].reshape(3, self.chunk)[:] = chunk
+            buf[-1] = chunk_slot
+        return buf
+
+    def unpack(self, packed):
+        """``(tok, pos, valid, rngs, tables, src), chunk`` as
+        ``Engine._decode_step`` and ``_chunk_step`` take them: ``chunk``
+        is ``(c_tok, c_pos, c_valid, c_table, c_rng)`` for a buffer with
+        the fused tail and None without. Static slices, but for the
+        chunk's slot."""
+        n = self.slots * self.lane
+        if packed.shape not in ((self.size(False),), (self.size(True),)):
+            raise ValueError(
+                f"a packed step of shape {packed.shape} fits neither "
+                f"program of {self}")
+        lanes = packed[:n].reshape(self.slots, self.lane)
+        tok, pos, valid, src, keys, tables = (
+            lanes[:, cols] for cols in self._cols)
+        rngs = jax.lax.bitcast_convert_type(keys, jnp.uint32)
+        lane = (tok, pos, valid != 0, rngs, tables, src[:, 0])
+        if packed.shape[0] == n:
+            return lane, None
+        c_tok, c_pos, c_valid = packed[n:-1].reshape(3, self.chunk)
+        slot = packed[-1]
+        return lane, (c_tok, c_pos, c_valid != 0, tables[slot][None],
+                      rngs[slot])
 
 
 @dataclasses.dataclass
@@ -475,7 +554,8 @@ class Engine:
         # token is the previous step's `nxt` where the host has not
         # fetched it yet (_incoming). Slot routing (page tables,
         # write heads, RNGs, and the tokens the host has seen) is
-        # host-side numpy, shipped as tiny step inputs — so page
+        # host-side numpy, shipped as one packed step input
+        # (_StepLayout) — so page
         # allocation and slot membership never touch compiled code,
         # and how much of each slot's table is live is data the
         # decode lane's attention kernel reads, not a shape.
@@ -496,6 +576,10 @@ class Engine:
         # decide what enters the trie.
         self._slot_shared = [0] * s
         self._slot_seq: list[ActiveSequence | None] = [None] * s
+        self._layout = _StepLayout(
+            slots=s, width=self.spec_width,
+            key_words=self._slot_rng.shape[1], pages=self.pages_per_slot,
+            chunk=self.prefill_chunk)
         with trace_lib.span("setup.program_build") as build_span:
             # Which attention formulation each lane's shapes select:
             # the model says (it decides by the same call when the
@@ -643,34 +727,33 @@ class Engine:
         sampled = jax.vmap(row)(pos, logits[0])
         return vars_out["cache"], sampled, self._counted(vars_out)
 
-    def _fused_impl(self, params, cache, d_tok, d_pos, d_valid, d_rngs,
-                    tables, c_tok, c_pos, c_valid, c_table, c_rng,
-                    d_src, prev_nxt, prev_sampled):
+    def _fused_impl(self, params, cache, packed, prev_nxt, prev_sampled):
         """The fused iteration: one prefill chunk piggybacks onto the
         decode batch's verify window inside one compiled program
         (Sarathi-Serve), so an admission costs decode ZERO extra
         dispatches and never blocks it. The two sub-applies touch
         disjoint pages (the chunk's slot is not decoding), so their
-        order is arithmetic-free. The last three arguments are the
-        decode lane's (:meth:`_decode_step`)."""
+        order is arithmetic-free. ``packed`` is the host's whole hand-over
+        (:class:`_StepLayout`); the last two arguments are the step
+        before's own outputs (:meth:`_decode_step`)."""
         with jax.named_scope("serve.fused"):
+            lane, chunk = self._layout.unpack(packed)
             cache, c_sampled, c_counted = self._chunk_step(
-                params, cache, c_tok, c_pos, c_valid, c_table, c_rng)
+                params, cache, *chunk)
             cache, nxt, accept, counted = self._decode_step(
-                params, cache, d_tok, d_pos, d_valid, d_rngs, tables,
-                d_src, prev_nxt, prev_sampled)
+                params, cache, *lane, prev_nxt, prev_sampled)
         out = (cache, nxt, accept, c_sampled)
         # a model with step counters: the chunk's rows beside the decode's
         return out if counted is None else out + (counted + c_counted,)
 
-    def _decode_only_impl(self, params, cache, d_tok, d_pos, d_valid,
-                          d_rngs, tables, d_src, prev_nxt, prev_sampled):
+    def _decode_only_impl(self, params, cache, packed, prev_nxt,
+                          prev_sampled):
         """Iterations with no prefill pending skip the chunk lane's
         compute entirely (the second compiled program)."""
         with jax.named_scope("serve.decode"):
+            lane, _ = self._layout.unpack(packed)
             *out, counted = self._decode_step(
-                params, cache, d_tok, d_pos, d_valid, d_rngs, tables,
-                d_src, prev_nxt, prev_sampled)
+                params, cache, *lane, prev_nxt, prev_sampled)
         return tuple(out) if counted is None else (*out, counted)
 
     # -- host-side lifecycle -------------------------------------------------
@@ -1652,32 +1735,39 @@ class Engine:
                 kv_rows_selected=sum(map(self.model.attended_rows,
                                          rows_live))))
 
-    def _launch(self, step: _DeviceStep, prev: _DeviceStep | None) -> None:
-        """Upload ``step``'s inputs and launch its program behind
-        ``prev``'s on the device; nothing is fetched. ``prev``'s outputs
-        go in as they came out: the device picks a slot's incoming token
-        from them where the host could not (``d_src``)."""
+    def _launch(self, step: _DeviceStep, prev: _DeviceStep | None) -> int:
+        """Hand ``step``'s inputs over as one packed buffer
+        (:class:`_StepLayout`) and launch its program behind ``prev``'s
+        on the device; nothing is fetched. ``prev``'s outputs go in as
+        they came out: the device picks a slot's incoming token from
+        them where the host could not (``d_src``). Returns the
+        host-to-device transfers made."""
         prev_nxt, prev_sampled = self._no_tokens
         if prev is not None:
             prev_nxt = prev.nxt
             if prev.c_sampled is not None:
                 prev_sampled = prev.c_sampled
         step.t0 = time.perf_counter()
-        lane = (jnp.asarray(step.d_tok), jnp.asarray(step.d_pos),
-                jnp.asarray(step.d_valid), jnp.asarray(self._slot_rng),
-                jnp.asarray(self._tables))
-        came_before = (jnp.asarray(step.d_src), prev_nxt, prev_sampled)
-        if step.chunk_seq is not None:
-            slot = step.chunk_seq.slot
-            (self._cache, step.nxt, step.acc, step.c_sampled,
-             *counted) = self._fused(
-                self.params, self._cache, *lane,
-                *map(jnp.asarray, step.chunk),
-                jnp.asarray(self._tables[slot][None]),
-                jnp.asarray(self._slot_rng[slot]), *came_before)
-        else:
-            self._cache, step.nxt, step.acc, *counted = self._decode(
-                self.params, self._cache, *lane, *came_before)
+        fused = step.chunk_seq is not None
+        packed = self._layout.pack(
+            step.d_tok, step.d_pos, step.d_valid, step.d_src,
+            self._slot_rng, self._tables, step.chunk,
+            step.chunk_seq.slot if fused else 0)
+        # The numpy buffer goes to the jitted call as it is: the call's
+        # own placement of an argument is the cheapest of the ways to hand
+        # it over (PERF.md §5: 1.54 ms a launch against 1.62 through
+        # jax.device_put and 1.71 through jnp.asarray) — and this one
+        # transfer is the launch's contract, so it is allowed by name
+        # where a caller forbids implicit ones (jax.transfer_guard).
+        with jax.transfer_guard_host_to_device("allow"):
+            if fused:
+                (self._cache, step.nxt, step.acc, step.c_sampled,
+                 *counted) = self._fused(self.params, self._cache, packed,
+                                         prev_nxt, prev_sampled)
+            else:
+                self._cache, step.nxt, step.acc, *counted = self._decode(
+                    self.params, self._cache, packed, prev_nxt,
+                    prev_sampled)
         if counted:
             step.counted = counted[0]
         # What the host will fetch starts for the host the moment the
@@ -1685,6 +1775,7 @@ class Engine:
         for out in (step.nxt, step.acc, step.c_sampled, step.counted):
             if out is not None:
                 out.copy_to_host_async()
+        return 1
 
     def _iterate_paged(self, it: int, it_span) -> list[FinishedRequest]:
         """One call's work, one device step ahead of the host where it
@@ -1767,11 +1858,10 @@ class Engine:
             with span("serve.device_step",
                       program=step.program) as dev_span:
                 if launches:
-                    with span("serve.dispatch",
-                              uploads=sum(11 if q.chunk_seq is not None
-                                          else 6 for q in launches)):
+                    with span("serve.dispatch", uploads=0) as disp_span:
                         for q in launches:
-                            self._launch(q, prev)
+                            disp_span.attrs["uploads"] += \
+                                self._launch(q, prev)
                             prev = q
                 with span("serve.token_wait"):
                     # graftlint: disable=hot-path-transfer -- the iteration's one wait for the device: this step's tokens must land (docs/SERVING.md); its successor is already queued behind it

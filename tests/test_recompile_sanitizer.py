@@ -10,6 +10,8 @@ case forces a retrace the way a real leak would appear (a step input
 whose width varies) and asserts the sanitizer trips.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -143,14 +145,17 @@ class TestEngineInventory:
         eng.run()  # warm: both programs, one shape each
         check_engine_inventory(eng)
         # A decode lane two tokens wide where the engine's is one, every
-        # row invalid (it writes the null page): a step input whose
-        # width varies, as a leak would have it.
-        wide = jnp.zeros((2, 2), jnp.int32)
+        # row invalid (it writes the null page), handed over as the
+        # launch hands it: a packed buffer of another width, as a leak
+        # would have it.
+        wide = np.zeros((2, 2), np.int32)
+        eng._layout = dataclasses.replace(eng._layout, width=2)
         with CompileWatch() as watch:
-            eng._decode(eng.params, eng._cache, wide, wide,
-                        wide.astype(bool), jnp.asarray(eng._slot_rng),
-                        jnp.asarray(eng._tables), wide[:, 0], wide,
-                        eng._no_tokens[1])
+            eng._decode(eng.params, eng._cache,
+                        jnp.asarray(eng._layout.pack(
+                            wide, wide, wide.astype(bool), wide[:, 0],
+                            eng._slot_rng, eng._tables)),
+                        jnp.asarray(wide), eng._no_tokens[1])
         # The forced retrace is visible on both surfaces: the window
         # compiled, and the decode program now holds two shapes.
         assert watch.compiles >= 1

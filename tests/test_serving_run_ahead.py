@@ -420,5 +420,5 @@ def test_two_programs_of_one_shape_with_the_outputs_they_had(lm, prompts,
     eng._decode, eng._fused = decode, fused
     assert eng.compiled_programs() == {"fused": 1, "decode": 1}
     w = spec_k + 1
-    assert seen["decode"] == {(10, (2, w), (2,))}
-    assert seen["fused"] == {(15, (2, w), (2,), (4,))}
+    assert seen["decode"] == {(5, (2, w), (2,))}
+    assert seen["fused"] == {(5, (2, w), (2,), (4,))}
