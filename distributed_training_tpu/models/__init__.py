@@ -47,10 +47,16 @@ def _deepseek_v32(**kw):
     return make_deepseek_v32(**kw)
 
 
+def _sarvam_mla(**kw):
+    from distributed_training_tpu.models.deepseek_v32 import make_sarvam_mla
+    return make_sarvam_mla(**kw)
+
+
 _REGISTRY["vit_b16"] = _vit
 _REGISTRY["moe_mlp"] = _moe
 _REGISTRY["transformer_lm"] = _lm
 _REGISTRY["deepseek_v32"] = _deepseek_v32
+_REGISTRY["sarvam_mla"] = _sarvam_mla
 
 
 def available_models() -> list[str]:

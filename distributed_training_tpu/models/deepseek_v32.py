@@ -27,6 +27,12 @@ and what each piece forces on the serving path:
 - **Experts.** :class:`~distributed_training_tpu.models.moe.HeldExpertsMlp`:
   routed over all experts, computed for the held ones.
 
+A model of the family may lack the query latent (``q_rank=None``: one
+matrix ``wq [d, heads, nope + rope]``) and the indexer (``index_topk=None``:
+no ``index_*`` leaf, one pool a layer, every query attends every earlier
+key; ``sarvam_mla`` is such a model). The lanes of that dense case are at
+the end of this text.
+
 Two lanes, chosen from the call's width alone (:meth:`DeepseekV32LM.
 paged_lane`): ``sparse-gather`` for a narrow window (the decode lane's one
 row a slot: index scores over the slot's table, ``lax.top_k``, the chosen
@@ -39,6 +45,15 @@ context costs, not the budget; where the heads are whole lane tiles wide, a
 block's attention is one call of the kernel ``ops/masked_attention.py``,
 the lane ``masked-blocks-kernel``). Both select the same set: the top
 ``index_topk`` by score, ties to the lower position.
+
+Without the indexer the same two widths are dense: a narrow window reads
+every live row of its slot in the absorbed form — where they lie, through
+the shared-row kernel of ``ops/paged_attention.py`` (all heads of a slot
+against the one latent row a key is, the row's first ``kv_rank`` lanes the
+value: ``dense-latent-kernel``), or, where that kernel does not fit (toy
+widths, windows whose rows x heads pass 128), over a gather of the slot's
+page budget (``dense-latent-gather``); the chunk takes ``masked-blocks``
+with the causal mask alone and no index pass.
 """
 
 from __future__ import annotations
@@ -52,7 +67,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from distributed_training_tpu.models.moe import GatedMlp, HeldExpertsMlp
-from distributed_training_tpu.ops import masked_attention
+from distributed_training_tpu.ops import masked_attention, paged_attention
 from distributed_training_tpu.parallel.ring_attention import PagedKV
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -61,12 +76,28 @@ NARROW_WINDOW = 8
 LANES = 128      # a pool row is a whole number of these wide
 
 
-def paged_lane(t_in: int) -> str:
-    """The attention formulation a paged call ``t_in`` rows wide takes."""
-    return "sparse-gather" if t_in <= NARROW_WINDOW else "masked-blocks"
+def paged_lane(t_in: int, sparse: bool) -> str:
+    """The attention formulation a paged call ``t_in`` rows wide takes, of
+    a model with the indexer (``sparse``) or without."""
+    if t_in > NARROW_WINDOW:
+        return "masked-blocks"
+    return "sparse-gather" if sparse else "dense-latent-gather"
 
 
 KERNEL_LANE = "masked-blocks-kernel"   # masked-blocks, a block in the kernel
+DENSE_KERNEL_LANE = "dense-latent-kernel"   # live rows read where they lie
+
+
+def dense_kernel_fits(t_in: int, num_heads: int, kv_rank: int, rope_dim: int,
+                      page_size: int | None, dtype) -> bool:
+    """Whether a dense window ``t_in`` rows wide reads its slot's live rows
+    through the shared-row kernel of ``ops/paged_attention.py``: the pool's
+    row is ``[c_kv | k_rope]`` padded to whole lane tiles, its first
+    ``kv_rank`` lanes the value."""
+    width = kv_rank + rope_dim
+    return page_size is not None and paged_attention.kernel_fits(
+        t_in, num_heads, width + -width % LANES, int(page_size), dtype,
+        value_lanes=kv_rank)
 
 
 def yarn_frequencies(dim: int, base: float, factor: float, original: int,
@@ -256,17 +287,19 @@ class RMSNorm(nn.Module):
 
 
 class SparseLatentAttention(nn.Module):
-    """MLA with the indexer's selection; see the module docstring."""
+    """MLA, with the indexer's selection where ``index_topk`` is set and
+    dense over every earlier key where it is None; with a query latent
+    where ``q_rank`` is set; see the module docstring."""
 
     num_heads: int
-    q_rank: int
+    q_rank: int | None
     kv_rank: int
     nope_dim: int
     rope_dim: int
     v_dim: int
-    index_heads: int
-    index_dim: int
-    index_topk: int
+    index_heads: int | None
+    index_dim: int | None
+    index_topk: int | None
     rope: tuple          # (base, factor, original, beta_fast, beta_slow, mscale)
     rope_scaled: bool
     norm_eps: float = 1e-6
@@ -289,17 +322,27 @@ class SparseLatentAttention(nn.Module):
         h, dt = self.num_heads, self.dtype
         init = nn.initializers.normal(0.02)
         qk_dim = self.nope_dim + self.rope_dim
-        wq_a = self.param("wq_a", init, (d, self.q_rank)).astype(dt)
-        wq_b = self.param("wq_b", init, (self.q_rank, h, qk_dim)).astype(dt)
+        sparse = self.index_topk is not None
+        if sparse and self.q_rank is None:
+            raise ValueError("the indexer's query is made from the query "
+                             "latent: index_topk needs q_rank")
+        if self.q_rank is None:
+            wq = self.param("wq", init, (d, h, qk_dim)).astype(dt)
+        else:
+            wq_a = self.param("wq_a", init, (d, self.q_rank)).astype(dt)
+            wq_b = self.param("wq_b", init,
+                              (self.q_rank, h, qk_dim)).astype(dt)
         wkv_a = self.param("wkv_a", init,
                            (d, self.kv_rank + self.rope_dim)).astype(dt)
         wkv_b = self.param("wkv_b", init, (self.kv_rank, h, self.nope_dim
                                            + self.v_dim)).astype(dt)
         wo = self.param("wo", init, (h, self.v_dim, d)).astype(dt)
-        wi_q = self.param("index_wq", init, (self.q_rank, self.index_heads,
-                                             self.index_dim)).astype(dt)
-        wi_k = self.param("index_wk", init, (d, self.index_dim)).astype(dt)
-        wi_w = self.param("index_weights", init, (d, self.index_heads))
+        if sparse:
+            wi_q = self.param("index_wq", init, (
+                self.q_rank, self.index_heads, self.index_dim)).astype(dt)
+            wi_k = self.param("index_wk", init,
+                              (d, self.index_dim)).astype(dt)
+            wi_w = self.param("index_weights", init, (d, self.index_heads))
         _, factor, _, _, _, mscale = self.rope
         scale = (yarn_softmax_scale(qk_dim, factor, mscale)
                  if self.rope_scaled else qk_dim ** -0.5)
@@ -308,8 +351,12 @@ class SparseLatentAttention(nn.Module):
             angles = positions.astype(jnp.float32)[..., None] \
                 * jnp.asarray(self._frequencies())
             cos, sin = jnp.cos(angles), jnp.sin(angles)      # [B, T, rope/2]
-            c_q = RMSNorm(self.norm_eps, dt, name="q_norm")(jnp.dot(x, wq_a))
-            q = jnp.einsum("btr,rhd->bthd", c_q, wq_b)
+            if self.q_rank is None:
+                q = jnp.einsum("btd,dhe->bthe", x, wq)
+            else:
+                c_q = RMSNorm(self.norm_eps, dt, name="q_norm")(
+                    jnp.dot(x, wq_a))
+                q = jnp.einsum("btr,rhd->bthd", c_q, wq_b)
             q_nope = q[..., :self.nope_dim]
             q_rope = rotate_interleaved(
                 q[..., self.nope_dim:], cos[:, :, None],
@@ -323,40 +370,44 @@ class SparseLatentAttention(nn.Module):
             width = self.kv_rank + self.rope_dim
             latent = jnp.concatenate(
                 [c_kv, k_rope, jnp.zeros((b, t, -width % LANES), dt)], axis=-1)
-            # the indexer's query, key and head weights
-            q_i = jnp.einsum("btr,rhd->bthd", c_q, wi_q)
-            q_i = jnp.concatenate([
-                rotate_half_split(q_i[..., :self.rope_dim], cos[:, :, None],
-                                  sin[:, :, None]).astype(dt),
-                q_i[..., self.rope_dim:]], axis=-1)
-            k_i = nn.LayerNorm(epsilon=self.norm_eps, dtype=dt,
-                               name="index_k_norm")(jnp.dot(x, wi_k))
-            k_i = jnp.concatenate([
-                rotate_half_split(k_i[..., :self.rope_dim], cos,
-                                  sin).astype(dt),
-                k_i[..., self.rope_dim:]], axis=-1)
-            w_i = jnp.dot(x.astype(jnp.float32), wi_w.astype(jnp.float32),
-                          precision=HIGHEST) \
-                * (self.index_heads ** -0.5 * self.index_dim ** -0.5)
+            index = None       # (q_i, w_i, k_i): the indexer's, if any
+            if sparse:
+                q_i = jnp.einsum("btr,rhd->bthd", c_q, wi_q)
+                q_i = jnp.concatenate([
+                    rotate_half_split(q_i[..., :self.rope_dim],
+                                      cos[:, :, None],
+                                      sin[:, :, None]).astype(dt),
+                    q_i[..., self.rope_dim:]], axis=-1)
+                k_i = nn.LayerNorm(epsilon=self.norm_eps, dtype=dt,
+                                   name="index_k_norm")(jnp.dot(x, wi_k))
+                k_i = jnp.concatenate([
+                    rotate_half_split(k_i[..., :self.rope_dim], cos,
+                                      sin).astype(dt),
+                    k_i[..., self.rope_dim:]], axis=-1)
+                w_i = jnp.dot(x.astype(jnp.float32),
+                              wi_w.astype(jnp.float32), precision=HIGHEST) \
+                    * (self.index_heads ** -0.5 * self.index_dim ** -0.5)
+                index = (q_i, w_i, k_i)
 
         if pages is None:
             valid = jnp.ones((b, t), bool)
+            keys = (latent,) if index is None else (latent, index[2])
             out = self._masked_blocks(
-                q_nope, q_rope, q_i, w_i, positions, valid, wkv_b, scale,
-                *self._local_keys(latent, k_i))
+                q_nope, q_rope, index, positions, valid, wkv_b, scale,
+                *self._local_keys(*keys))
         else:
-            out = self._paged(q_nope, q_rope, q_i, w_i, latent, k_i, wkv_b,
-                              scale, pages)
+            out = self._paged(q_nope, q_rope, index, latent, wkv_b, scale,
+                              pages)
         return jnp.einsum("bthv,hvd->btd", out.astype(dt), wo)
 
     # -- where the keys come from --------------------------------------------
-    def _local_keys(self, latent, k_i):
-        """The call's own rows as the keys (no cache): the plain forward."""
-        t = latent.shape[1]
+    def _local_keys(self, *rows):
+        """The call's own rows as the keys (no cache): the plain forward.
+        ``rows`` are the latent rows and, with the indexer, its keys."""
+        t = rows[0].shape[1]
         kb = min(int(self.key_block), t)
         pad = -t % kb
-        padded = [jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
-                  for a in (latent, k_i)]
+        padded = [jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in rows]
 
         def fetch(j):
             return tuple(jax.lax.dynamic_slice_in_dim(a, j * kb, kb, axis=1)
@@ -364,52 +415,63 @@ class SparseLatentAttention(nn.Module):
 
         return fetch, kb, (t + pad) // kb
 
-    def _paged(self, q_nope, q_rope, q_i, w_i, latent, k_i, wkv_b, scale,
+    def _paged(self, q_nope, q_rope, index, latent, wkv_b, scale,
                pages: PagedKV):
-        """Write this call's rows into the two pools in place, then attend
-        through the page table in the lane the call's width selects."""
+        """Write this call's rows into the layer's pools in place (the
+        latent rows, and the indexer's keys where there is one), then
+        attend through the page table in the lane the call's width
+        selects."""
         b, t = latent.shape[:2]
         if self.kv_page_size is None or self.kv_pages is None:
             raise ValueError("pages= passed but the model was not cloned "
                              "with kv_page_size / kv_pages")
         ps = int(self.kv_page_size)
         pool_rows = int(self.kv_pages) * ps
-        lat_pool = self.variable("cache", "latent_pages", jnp.zeros,
-                                 (pool_rows, latent.shape[-1]), latent.dtype)
-        idx_pool = self.variable("cache", "index_pages", jnp.zeros,
-                                 (pool_rows, k_i.shape[-1]), k_i.dtype)
+        written = [("latent_pages", latent)]
+        if index is not None:
+            written.append(("index_pages", index[2]))
+        pools = [self.variable("cache", name, jnp.zeros,
+                               (pool_rows, rows.shape[-1]), rows.dtype)
+                 for name, rows in written]
         table, positions, valid = pages
         phys = jnp.take_along_axis(table, positions // ps, axis=1) * ps \
             + positions % ps
         write_idx = jnp.where(valid, phys, 0).reshape(-1)   # null page: row 0
-        lat_all = lat_pool.value.at[write_idx].set(
-            latent.reshape(b * t, -1))
-        idx_all = idx_pool.value.at[write_idx].set(k_i.reshape(b * t, -1))
+        pools_all = [pool.value.at[write_idx].set(rows.reshape(b * t, -1))
+                     for pool, (_, rows) in zip(pools, written)]
         if not self.is_initializing():
-            lat_pool.value, idx_pool.value = lat_all, idx_all
+            for pool, rows_all in zip(pools, pools_all):
+                pool.value = rows_all
 
-        if paged_lane(t) == "sparse-gather":
-            out = self._sparse_gather(q_nope, q_rope, q_i, w_i, positions,
-                                      table, lat_all, idx_all, wkv_b, scale)
-        else:
+        lane = paged_lane(t, index is not None)
+        if lane == "masked-blocks":
             ppb = max(int(self.key_block) // ps, 1)      # pages a key block
             n_blocks = -(-table.shape[1] // ppb)
             padded = jnp.pad(table, ((0, 0),
                                      (0, n_blocks * ppb - table.shape[1])))
-            lat_paged, idx_paged = (by_page(a, ps) for a in (lat_all, idx_all))
+            paged = [by_page(a, ps) for a in pools_all]
 
             def fetch(j):
                 tbl = jax.lax.dynamic_slice_in_dim(padded, j * ppb, ppb, 1)
-                return tuple(a[tbl].reshape(b, ppb * ps, -1)
-                             for a in (lat_paged, idx_paged))
+                return tuple(a[tbl].reshape(b, ppb * ps, -1) for a in paged)
 
-            out = self._masked_blocks(q_nope, q_rope, q_i, w_i, positions,
+            out = self._masked_blocks(q_nope, q_rope, index, positions,
                                       valid, wkv_b, scale, fetch, ppb * ps,
                                       n_blocks)
+        elif lane == "sparse-gather":
+            out = self._sparse_gather(q_nope, q_rope, *index[:2], positions,
+                                      table, *pools_all, wkv_b, scale)
+        elif dense_kernel_fits(t, self.num_heads, self.kv_rank,
+                               self.rope_dim, ps, self.dtype):
+            out = self._dense_kernel(q_nope, q_rope, pages, pools_all[0],
+                                     wkv_b, scale)
+        else:
+            out = self._dense_gather(q_nope, q_rope, positions, table,
+                                     pools_all[0], wkv_b, scale)
         overflow = positions >= table.shape[1] * ps
         return jnp.where(overflow[:, :, None, None], jnp.nan, out)
 
-    # -- the two lanes -------------------------------------------------------
+    # -- the narrow window's lanes -------------------------------------------
     def _sparse_gather(self, q_nope, q_rope, q_i, w_i, positions, table,
                        lat_all, idx_all, wkv_b, scale):
         b = table.shape[0]
@@ -429,7 +491,45 @@ class SparseLatentAttention(nn.Module):
             return attend_absorbed(q_nope, q_rope, lat_all[chosen_rows],
                                    keep, wkv_b, scale)
 
-    def _masked_blocks(self, q_nope, q_rope, q_i, w_i, positions, valid,
+    def _dense_gather(self, q_nope, q_rope, positions, table, lat_all,
+                      wkv_b, scale):
+        """Every query over every earlier row of its slot, the slot's whole
+        page budget gathered: the absorbed form in XLA."""
+        b, t = positions.shape
+        ps = int(self.kv_page_size)
+        l_all = table.shape[1] * ps
+        with jax.named_scope("mla.attend"):
+            rows = by_page(lat_all, ps)[table].reshape(b, 1, l_all, -1)
+            keep = jnp.arange(l_all) <= positions[..., None]
+            return attend_absorbed(
+                q_nope, q_rope,
+                jnp.broadcast_to(rows, (b, t, *rows.shape[2:])), keep,
+                wkv_b, scale)
+
+    def _dense_kernel(self, q_nope, q_rope, pages: PagedKV, lat_all, wkv_b,
+                      scale):
+        """The absorbed form with the live pages read where they lie: the
+        key expansion folded into the query (as wide as a pool row), the
+        kernel's weighted sum of latent rows expanded to the heads'
+        values."""
+        b, t, h = q_nope.shape[:3]
+        table, positions, valid = pages
+        with jax.named_scope("mla.attend"):
+            q_abs = jnp.einsum("bthd,chd->bthc", q_nope,
+                               wkv_b[..., :self.nope_dim])
+            pad = lat_all.shape[-1] - self.kv_rank - self.rope_dim
+            q = jnp.concatenate(
+                [q_abs, q_rope, jnp.zeros((b, t, h, pad), q_abs.dtype)],
+                axis=-1)
+            o = paged_attention.paged_latent_attention(
+                q, lat_all, table, positions, valid,
+                value_lanes=self.kv_rank, page_size=int(self.kv_page_size),
+                scale=float(scale))
+            return jnp.einsum("bthc,chv->bthv", o,
+                              wkv_b[..., self.nope_dim:])
+
+    # -- the chunk's lane ----------------------------------------------------
+    def _masked_blocks(self, q_nope, q_rope, index, positions, valid,
                        wkv_b, scale, fetch, kb: int, n_blocks: int):
         b, t, h = q_nope.shape[:3]
         # key blocks that some existing row's position reaches
@@ -437,30 +537,45 @@ class SparseLatentAttention(nn.Module):
             jnp.max(jnp.where(valid, positions, 0)) // kb + 1, n_blocks)
         kpos = jnp.arange(kb)
 
-        def index_block(j, scores):
-            s = index_scores(q_i, w_i, fetch(j)[1])
-            s = jnp.where(j * kb + kpos <= positions[..., None], s + 0.0,
-                          -jnp.inf)
-            return jax.lax.dynamic_update_slice_in_dim(scores, s, j * kb, 2)
+        # keep_block(j): which keys of block j each query attends, [B, T,
+        # kb]; with ``row`` that one sequence's [T, kb]
+        if index is None:
+            def keep_block(j, row=None):   # causal: every earlier key
+                at = positions if row is None else positions[row]
+                return j * kb + kpos <= at[..., None]
+        else:
+            q_i, w_i, _ = index
 
-        with jax.named_scope("dsa.index"):
-            scores = jax.lax.fori_loop(
-                0, n_live, index_block,
-                jnp.full((b, t, n_blocks * kb), -jnp.inf, jnp.float32))
-        with jax.named_scope("dsa.select"):
-            mask = exact_topk_mask(scores, self.index_topk, (n_live, kb)) \
-                & (scores > -jnp.inf)
+            def index_block(j, scores):
+                s = index_scores(q_i, w_i, fetch(j)[1])
+                s = jnp.where(j * kb + kpos <= positions[..., None], s + 0.0,
+                              -jnp.inf)
+                return jax.lax.dynamic_update_slice_in_dim(scores, s,
+                                                           j * kb, 2)
+
+            with jax.named_scope("dsa.index"):
+                scores = jax.lax.fori_loop(
+                    0, n_live, index_block,
+                    jnp.full((b, t, n_blocks * kb), -jnp.inf, jnp.float32))
+            with jax.named_scope("dsa.select"):
+                mask = exact_topk_mask(scores, self.index_topk,
+                                       (n_live, kb)) & (scores > -jnp.inf)
+
+            def keep_block(j, row=None):
+                if row is None:
+                    return jax.lax.dynamic_slice_in_dim(mask, j * kb, kb, 2)
+                return jax.lax.dynamic_slice_in_dim(mask[row], j * kb, kb, 1)
 
         if self.chunk_kernel(b, t, kb):
             with jax.named_scope("mla.attend"):
                 return self._attend_blocks_kernel(
-                    q_nope, q_rope, mask, wkv_b, scale, fetch, kb, n_live)
+                    q_nope, q_rope, keep_block, wkv_b, scale, fetch, kb,
+                    n_live)
 
         def attend_block(j, carry):
             o, m, l = carry
             s, v = per_head_block(q_nope, q_rope, fetch(j)[0], wkv_b, scale)
-            keep = jax.lax.dynamic_slice_in_dim(mask, j * kb, kb, 2)
-            s = jnp.where(keep[:, None], s, -jnp.inf)        # [B, H, T, S]
+            s = jnp.where(keep_block(j)[:, None], s, -jnp.inf)  # [B, H, T, S]
             m_new = jnp.maximum(m, s.max(-1))
             m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
             p = jnp.exp(s - m_safe[..., None])
@@ -485,8 +600,8 @@ class SparseLatentAttention(nn.Module):
         return masked_attention.kernel_fits(
             b, t, kb, self.nope_dim, self.rope_dim, self.v_dim, self.dtype)
 
-    def _attend_blocks_kernel(self, q_nope, q_rope, mask, wkv_b, scale,
-                              fetch, kb: int, n_live):
+    def _attend_blocks_kernel(self, q_nope, q_rope, keep_block, wkv_b,
+                              scale, fetch, kb: int, n_live):
         """The per-head form, a key block a kernel call: the same products
         and the same online softmax as ``attend_block``, the scores in
         VMEM. One sequence (``kernel_fits``)."""
@@ -500,7 +615,7 @@ class SparseLatentAttention(nn.Module):
         def attend_block(j, state):
             latent = fetch(j)[0][0]                        # [kb, width]
             c_kv = latent[:, :rank]
-            keep = jax.lax.dynamic_slice_in_dim(mask[0], j * kb, kb, 1)
+            keep = keep_block(j, row=0)
             return tuple(masked_attention.masked_attention_block(
                 q_nope, q_rope, jnp.dot(c_kv, w_k),
                 latent[:, rank:rank + self.rope_dim], jnp.dot(c_kv, w_v),
@@ -540,12 +655,15 @@ class DeepseekV32Block(nn.Module):
 
 class DeepseekV32LM(nn.Module):
     """The model as the serving engine drives it: ``apply(tokens, positions,
-    decode=True, pages=PagedKV)`` with a mutable ``cache`` collection (two
-    pools a layer), ``clone(cache_len, kv_page_size, kv_pages, kv_dtype)``,
+    decode=True, pages=PagedKV)`` with a mutable ``cache`` collection (a
+    latent pool a layer, and an index pool beside it where the model has
+    the indexer), ``clone(cache_len, kv_page_size, kv_pages, kv_dtype)``,
     ``max_len`` (the position limit), and what the engine asks a model
     about itself: :meth:`paged_lane`, :meth:`attended_rows`,
     ``step_counters``. ``decode=False`` is the plain forward over the call's
-    own rows (no cache), through the masked-blocks lane."""
+    own rows (no cache), through the masked-blocks lane. ``q_rank`` and the
+    three ``index_*`` sizes are None in a model without the query latent or
+    without the indexer."""
 
     vocab_size: int
     num_layers: int
@@ -554,14 +672,14 @@ class DeepseekV32LM(nn.Module):
     dense_dim: int
     expert_dim: int
     num_heads: int
-    q_rank: int
+    q_rank: int | None
     kv_rank: int
     nope_dim: int
     rope_dim: int
     v_dim: int
-    index_heads: int
-    index_dim: int
-    index_topk: int
+    index_heads: int | None
+    index_dim: int | None
+    index_topk: int | None
     num_experts: int             # the router's width
     held: tuple                  # (first, count) of the experts held here
     experts_per_token: int
@@ -581,21 +699,29 @@ class DeepseekV32LM(nn.Module):
     kv_pages: int | None = None
     kv_dtype: str | None = None
 
-    step_counters = ("expert_rows", "expert_rows_max")   # HeldExpertsMlp sows
+    # HeldExpertsMlp sows them
+    step_counters = ("expert_rows", "expert_rows_max", "experts_hit")
 
     def paged_lane(self, t_in: int, page_size: int | None = None,
                    kv_dtype: str | None = None) -> str:
         """The attention formulation a paged call ``t_in`` rows wide takes
         (the width alone decides; pools are in the compute dtype)."""
-        del page_size, kv_dtype
-        lane = paged_lane(t_in)
-        kernel = lane == "masked-blocks" and masked_attention.kernel_fits(
-            1, t_in, self.key_block, self.nope_dim, self.rope_dim,
-            self.v_dim, self.dtype)
-        return KERNEL_LANE if kernel else lane
+        del kv_dtype
+        lane = paged_lane(t_in, self.index_topk is not None)
+        if lane == "masked-blocks" and masked_attention.kernel_fits(
+                1, t_in, self.key_block, self.nope_dim, self.rope_dim,
+                self.v_dim, self.dtype):
+            return KERNEL_LANE
+        if lane == "dense-latent-gather" and dense_kernel_fits(
+                t_in, self.num_heads, self.kv_rank, self.rope_dim, page_size,
+                self.dtype):
+            return DENSE_KERNEL_LANE
+        return lane
 
     def attended_rows(self, live: int) -> int:
         """Of ``live`` cached rows, how many one query attends."""
+        if self.index_topk is None:
+            return int(live)
         return min(int(live), self.index_topk)
 
     @nn.compact
@@ -648,3 +774,14 @@ def make_deepseek_v32(*, num_classes: int, dtype: Any = jnp.float32,
     """Registry factory; ``num_classes`` is the vocabulary held here."""
     del axis_name
     return DeepseekV32LM(vocab_size=num_classes, dtype=dtype, **kwargs)
+
+
+def make_sarvam_mla(*, num_classes: int, dtype: Any = jnp.float32,
+                    axis_name: str | None = None, **kwargs) -> DeepseekV32LM:
+    """Registry factory of the ``sarvam_mla`` family: the same model with
+    no query latent, no indexer (dense latent attention over every earlier
+    key) and a router of one group."""
+    del axis_name
+    return DeepseekV32LM(
+        vocab_size=num_classes, dtype=dtype, q_rank=None, index_heads=None,
+        index_dim=None, index_topk=None, n_group=1, topk_group=1, **kwargs)

@@ -334,8 +334,9 @@ class HeldExpertsMlp(nn.Module):
     ``valid`` [T] masks tokens that do not exist (padding rows, empty
     decode slots): they are routed nowhere and counted nowhere. Sows, in
     the ``counters`` collection when it is mutable, ``expert_rows`` (pairs
-    that landed on held experts) and ``expert_rows_max`` (on the busiest
-    of them), int32 scalars."""
+    that landed on held experts), ``expert_rows_max`` (on the busiest of
+    them) and ``experts_hit`` (held experts that got at least one pair:
+    the experts whose weights the step reads), int32 scalars."""
 
     num_experts: int
     held: tuple
@@ -387,6 +388,10 @@ class HeldExpertsMlp(nn.Module):
                      init_fn=lambda: jnp.zeros((), jnp.int32),
                      reduce_fn=jnp.add)
             self.sow("counters", "expert_rows_max", rows.max(),
+                     init_fn=lambda: jnp.zeros((), jnp.int32),
+                     reduce_fn=jnp.add)
+            self.sow("counters", "experts_hit",
+                     (rows > 0).sum(dtype=jnp.int32),
                      init_fn=lambda: jnp.zeros((), jnp.int32),
                      reduce_fn=jnp.add)
 

@@ -17,7 +17,9 @@ TPU in Pallas interpret mode.
    gather): tokens against the sequential ``Generator``, greedy and
    sampled, speculation on and off; the ``kv_pages_live`` counter.
 5. **For the chip**: the kernel compiles at GPT-2-large's widths for a
-   described v5e (no chip needed; skipped where it cannot be described).
+   described v5e (no chip needed; skipped where it cannot be described),
+   and its shared-row form at sarvam-105b's (the form itself against the
+   gather lane: tests/test_sarvam_mla.py).
 """
 
 import time
@@ -388,6 +390,40 @@ def test_the_kernel_compiles_for_a_v5e_at_gpt2_large_widths(one_chip, t_in):
     assert "tpu_custom_call" in text
     # Nothing of the pool's size is made: the kernel reads it in place.
     assert compiled.memory_analysis().temp_size_in_bytes < rows * width
+
+
+@pytest.mark.parametrize("t_in", [1, 2])
+def test_the_shared_row_kernel_compiles_for_a_v5e_at_sarvam_widths(one_chip,
+                                                                   t_in):
+    """``paged_latent_attention`` at the reasoning cell's shapes: 64 slots
+    of 384 pages, 64 heads against a 640-wide latent row whose first 512
+    lanes are the value."""
+    from distributed_training_tpu.ops.paged_attention import (
+        paged_latent_attention,
+    )
+
+    heads, width, value, ps, slots, per_slot, rows = (
+        64, 640, 512, 16, 64, 384, 393232)
+    assert kernel_fits(t_in, heads, width, ps, jnp.bfloat16,
+                       value_lanes=value)
+
+    def shape(s, d):
+        return jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+
+    def call(q, pool, table, positions, valid):
+        return paged_latent_attention(
+            q, pool, table, positions, valid, value_lanes=value,
+            page_size=ps, scale=0.1353, interpret=False)
+
+    compiled = jax.jit(call).lower(
+        shape((slots, t_in, heads, width), jnp.bfloat16),
+        shape((rows, width), jnp.bfloat16),
+        shape((slots, per_slot), jnp.int32),
+        shape((slots, t_in), jnp.int32),
+        shape((slots, t_in), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # Nothing of the pool's size is made: the kernel reads it in place.
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * width // 8
 
 
 def test_the_masked_attention_kernel_compiles_for_a_v5e_at_deepseek_widths(
