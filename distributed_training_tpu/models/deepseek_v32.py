@@ -35,9 +35,11 @@ the end of this text.
 
 Two lanes, chosen from the call's width alone (:meth:`DeepseekV32LM.
 paged_lane`): ``sparse-gather`` for a narrow window (the decode lane's one
-row a slot: index scores over the slot's table, ``lax.top_k``, the chosen
-latent rows gathered, absorbed attention) and ``masked-blocks`` for a wide
-one (the prefill chunk: index scores and per-head attention over key blocks
+row a slot: index scores over the slot's table, the selection as a mask
+(:func:`exact_topk_mask`: a threshold found by counting, no sort), the
+mask's positions in ascending order (:func:`mask_positions`), those latent
+rows gathered, absorbed attention) and ``masked-blocks`` for a wide one
+(the prefill chunk: index scores and per-head attention over key blocks
 of ``key_block`` rows, only as many blocks as the chunk's positions reach,
 the selection as a mask, softmax accumulated online — so nothing of size
 heads x chunk x context is ever held, and every pass costs what the live
@@ -148,8 +150,9 @@ LIVE_RUNS = (4, 8, 12)
 
 def exact_topk_mask(scores, k: int, live=None):
     """Boolean mask of the ``k`` highest entries of each row (last axis)
-    of float32 ``scores``. Ties go to the lower index (-inf entries too,
-    where fewer than ``k`` are finite): the set ``lax.top_k`` returns,
+    of float32 ``scores``; a -inf entry is never among them, so a row
+    with fewer than ``k`` finite entries keeps just those. Ties go to the
+    lower index: the finite part of the set ``lax.top_k`` returns,
     without a sort.
 
     The k-th highest value is found by bisection on the scores' bits (32
@@ -161,9 +164,7 @@ def exact_topk_mask(scores, k: int, live=None):
     ``live = (n_live, width)`` says that only the first ``n_live`` (traced)
     runs of ``width`` entries can hold a finite score: the passes then
     read a prefix of the rows that covers those runs, one of a few static
-    lengths (:data:`LIVE_RUNS`), and the mask is the same wherever the
-    scores are finite (what lies behind the prefix is -inf, and false
-    here)."""
+    lengths (:data:`LIVE_RUNS`), and the mask is the same."""
     if live is None:
         return _topk_mask(scores, k)
     n_live, width = live
@@ -195,9 +196,11 @@ def _topk_mask(scores, k: int):
         return jnp.where(enough, cand, t)
 
     kth = jax.lax.fori_loop(0, 32, bisect, jnp.zeros(rows, jnp.uint32))
-    kth = kth[..., None]
+    # fewer than k finite entries: all of them, and -inf ties with nothing
+    neg_inf = jnp.uint32(0x007FFFFF)                  # the key of -inf
+    kth = jnp.maximum(kth, neg_inf)[..., None]
     above = key > kth
-    tied = key == kth
+    tied = (key == kth) & (kth > neg_inf)
     left = k - above.sum(-1, dtype=jnp.int32)             # ties to take
     index = jnp.arange(n, dtype=jnp.int32)
     index_bits = n.bit_length()           # candidates reach n itself
@@ -217,6 +220,53 @@ def _topk_mask(scores, k: int):
     # every row may take all its ties (the usual case: the k-th value once)
     all_fit = (tied.sum(-1, dtype=jnp.int32) <= left).all()
     return jax.lax.cond(all_fit, lambda: above | tied, in_index_order)
+
+
+# Entries a run of :func:`mask_positions`: one lane tile.
+RUN = 128
+
+
+def mask_positions(mask, k: int):
+    """Where the set entries of each row (last axis) of boolean ``mask``
+    lie, in ascending order: ``chosen [..., k]`` int32 and ``keep [...,
+    k]``, false behind the row's count (``chosen`` is 0 there). Of a row
+    with more than ``k`` set entries, the first ``k``.
+
+    No sort, scatter or gather, which cost the TPU more than the
+    arithmetic: the row is cut into runs of :data:`RUN`; entry ``j`` of
+    the output lies in the run whose span of the running totals holds
+    ``j`` (a comparison against every run's), and its place in that run
+    is how many of the run's counts are at most ``j`` less the run's
+    start. The counts and the totals are products with a triangle of
+    ones, a run's counts come to ``j`` through a one-hot product: both
+    exact, a count of at most :data:`RUN` being a bfloat16 and the sums
+    float32."""
+    lead, n = mask.shape[:-1], mask.shape[-1]
+    runs = jnp.pad(mask, [(0, 0)] * len(lead) + [(0, -n % RUN)]).reshape(
+        *lead, -1, RUN).astype(jnp.bfloat16)
+
+    def running(x):            # inclusive sums along the last axis
+        size = x.shape[-1]
+        upto = jnp.arange(size)[:, None] <= jnp.arange(size)
+        return jnp.einsum("...c,cd->...d", x, upto.astype(jnp.bfloat16),
+                          preferred_element_type=jnp.float32)
+
+    within = running(runs)                 # [..., runs, RUN]: set so far
+    totals = within[..., -1]
+    ends = running(totals.astype(jnp.bfloat16)).astype(jnp.int32)
+    starts = ends - totals.astype(jnp.int32)
+    j = jnp.arange(k, dtype=jnp.int32)[:, None]
+    # [..., k, runs]: the run that holds output j (none behind the count)
+    holds = (starts[..., None, :] <= j) & (j < ends[..., None, :])
+    run = (holds * jnp.arange(runs.shape[-2], dtype=jnp.int32)).sum(-1)
+    offset = j[:, 0] - (holds * starts[..., None, :]).sum(-1)
+    counts = jnp.einsum("...kr,...rc->...kc", holds.astype(jnp.bfloat16),
+                        within.astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32)
+    place = (counts <= offset[..., None].astype(jnp.float32)).sum(
+        -1, dtype=jnp.int32)
+    keep = j[:, 0] < ends[..., -1:]
+    return jnp.where(keep, run * RUN + place, 0), keep
 
 
 def by_page(pool, page_size: int):
@@ -483,8 +533,8 @@ class SparseLatentAttention(nn.Module):
             s = jnp.where(jnp.arange(l_all) <= positions[..., None],
                           s + 0.0, -jnp.inf)
         with jax.named_scope("dsa.select"):
-            top, chosen = jax.lax.top_k(s, min(self.index_topk, l_all))
-            keep = top > -jnp.inf
+            k = min(self.index_topk, l_all)
+            chosen, keep = mask_positions(exact_topk_mask(s, k), k)
             chosen_rows = jnp.take_along_axis(
                 table[:, None, :], chosen // ps, axis=2) * ps + chosen % ps
         with jax.named_scope("mla.attend"):
@@ -559,7 +609,7 @@ class SparseLatentAttention(nn.Module):
                     jnp.full((b, t, n_blocks * kb), -jnp.inf, jnp.float32))
             with jax.named_scope("dsa.select"):
                 mask = exact_topk_mask(scores, self.index_topk,
-                                       (n_live, kb)) & (scores > -jnp.inf)
+                                       (n_live, kb))
 
             def keep_block(j, row=None):
                 if row is None:

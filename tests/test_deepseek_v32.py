@@ -8,6 +8,7 @@ and the toy cell through the harness: tests/benchmark/test_bm_deepseek_v32.py.""
 import json
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -163,34 +164,70 @@ def brute_topk_mask(scores, k):
     return out
 
 
-@pytest.mark.parametrize("case", ["random", "ties", "negative_zero",
-                                  "short_rows", "k_covers_all", "wide"])
-def test_the_selected_set_against_a_brute_force_top_k(case):
+SELECTION_CASES = ["random", "ties", "negative_zero", "short_rows",
+                   "k_covers_all", "wide"]
+
+
+def selection_case(case, rows=6, n=None):
+    """Index scores ``[rows, n]`` of one case, and its ``k``."""
     rng = np.random.default_rng(7)
-    n, k = (4096, 2048) if case == "wide" else (40, 8)
-    s = rng.normal(0, 1, (6, n)).astype(np.float32)
-    if case == "ties":
+    wide_n, k = (4096, 2048) if case == "wide" else (40, 8)
+    n = n or wide_n
+    s = rng.normal(0, 1, (rows, n)).astype(np.float32)
+    if case in ("ties", "idle_slot"):
         s = np.round(s * 2) / 2               # few values, many equal
     if case == "negative_zero":
         s = np.where(rng.random(s.shape) < 0.5, 0.0, -0.0).astype(np.float32)
         s[:, :3] = 1.0
     if case == "short_rows":                  # causal rows with < k keys
-        s = np.where(np.arange(n)[None] <= np.array([0, 2, 6, 7, 8, 20])[
-            :, None], s, -np.inf).astype(np.float32)
+        last = np.resize(np.array([0, 2, 6, 7, 8, 20]), rows)
+        s = np.where(np.arange(n)[None] <= last[:, None], s,
+                     -np.inf).astype(np.float32)
+    if case == "idle_slot":     # one key, beside rows crowded with ties
+        s[0, 1:] = -np.inf
     if case == "k_covers_all":
         k = n
+    return s, k
+
+
+@pytest.mark.parametrize("case", SELECTION_CASES + ["idle_slot"])
+def test_the_selected_set_against_a_brute_force_top_k(case):
+    s, k = selection_case(case)
     want = brute_topk_mask(s, k)
+    # the selection never takes a -inf entry: a row with fewer than k keys
+    # (the idle slot has one) keeps them all, whatever its neighbours need
     got = np.asarray(dsv32.exact_topk_mask(jnp.asarray(s), k))
-    np.testing.assert_array_equal(got, want)
-    # lax.top_k (the decode lane's, the reference's) picks the same set,
-    # once a negative zero is a zero (both add 0.0 first)
+    np.testing.assert_array_equal(got, want & (s > -np.inf))
+    # lax.top_k (the reference's) picks the same set, once a negative zero
+    # is a zero (both add 0.0 first)
     top = np.zeros_like(want)
     np.put_along_axis(
         top, np.asarray(jax.lax.top_k(jnp.asarray(s) + 0.0, k)[1]), True,
         axis=1)
     np.testing.assert_array_equal(top, want)
     keep = np.asarray(ref.select_keys(jnp.asarray(s), k))
-    np.testing.assert_array_equal(keep, want & (s > -np.inf))
+    np.testing.assert_array_equal(keep, got)
+
+
+@pytest.mark.parametrize("t", [1, 4])
+@pytest.mark.parametrize("case", SELECTION_CASES)
+def test_the_selected_positions_against_a_brute_force_list(case, t):
+    """The decode lane's compaction: the set as positions in ascending
+    order, padded behind each row's count."""
+    s, k = selection_case(case, rows=3 * t,
+                          n=16896 if case == "wide" else None)
+    n = s.shape[-1]
+    mask = dsv32.exact_topk_mask(jnp.asarray(s.reshape(3, t, n)), k)
+    chosen, keep = (np.asarray(a).reshape(3 * t, k) for a in
+                    jax.jit(dsv32.mask_positions, static_argnums=1)(mask, k))
+    finite = (s > -np.inf).sum(-1)
+    brute = brute_topk_mask(s, k)
+    for row in range(3 * t):
+        want = [i for i in range(n) if brute[row, i] and s[row, i] > -np.inf]
+        assert keep[row].sum() == min(k, finite[row]) == len(want)
+        assert keep[row, :len(want)].all()
+        assert chosen[row][keep[row]].tolist() == want
+    assert ((chosen >= 0) & (chosen < n)).all()
 
 
 @pytest.fixture(scope="module")
@@ -300,6 +337,86 @@ def test_chunked_prefill_then_paged_decode_gives_the_references_logits(
     np.testing.assert_allclose(got, want, atol=3e-6)
     plain = jax.jit(model.apply)({"params": params}, jnp.asarray(seq[None]))
     np.testing.assert_allclose(plain[0], want, atol=3e-6)
+
+
+def sorted_sparse_gather(self, q_nope, q_rope, q_i, w_i, positions, table,
+                         lat_all, idx_all, wkv_b, scale):
+    """The decode lane as it was before PR 33: the keys picked by a sort of
+    the slot's whole page budget, attended in score order."""
+    b = table.shape[0]
+    ps = int(self.kv_page_size)
+    l_all = table.shape[1] * ps
+    keys = dsv32.by_page(idx_all, ps)[table].reshape(b, l_all, -1)
+    s = dsv32.index_scores(q_i, w_i, keys)
+    s = jnp.where(jnp.arange(l_all) <= positions[..., None], s + 0.0,
+                  -jnp.inf)
+    top, chosen = jax.lax.top_k(s, min(self.index_topk, l_all))
+    chosen_rows = jnp.take_along_axis(
+        table[:, None, :], chosen // ps, axis=2) * ps + chosen % ps
+    return dsv32.attend_absorbed(q_nope, q_rope, lat_all[chosen_rows],
+                                 top > -jnp.inf, wkv_b, scale)
+
+
+@pytest.fixture(scope="module")
+def lane_and_sorted_lane():
+    """Positions 4 .. 47 of one sequence decoded a token at a time through
+    the sparse-gather lane, and through the sorted formulation."""
+    with jax.default_matmul_precision("highest"):
+        _, params = toy_params(3)
+        seq = np.random.default_rng(3).integers(0, 64, LENGTH).astype(
+            np.int32)
+        lane = paged_logits(toy_model(), params, seq, 4, 4, 4)
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(dsv32.SparseLatentAttention, "_sparse_gather",
+                          sorted_sparse_gather)
+            by_sort = paged_logits(toy_model(), params, seq, 4, 4, 4)
+    return lane, by_sort
+
+
+@pytest.mark.parametrize("contexts,first,last", [
+    ("below_top_k", 4, 6), ("at_top_k", 7, 7), ("above_top_k", 8, 47)])
+def test_the_lane_against_the_sort_it_replaced(lane_and_sorted_lane,
+                                               contexts, first, last):
+    """``index_topk`` = 8: a query at position 7 has exactly 8 keys."""
+    lane, by_sort = lane_and_sorted_lane
+    assert np.abs(by_sort[first:last + 1]).max() > 0.1
+    np.testing.assert_allclose(lane[first:last + 1],
+                               by_sort[first:last + 1], atol=1e-6)
+
+
+# the operand types of every ordering operation of a lowered program
+ORDERINGS = re.compile(r'stablehlo\.sort"\(.*?\}\) : \(([^)]*)\)'
+                       r"|chlo\.top_k\([^)]*\) : (\S+)", re.S)
+
+
+def test_the_decode_program_sorts_nothing_as_wide_as_the_page_budget():
+    """The toy's paged decode step as lowered: what is still put in order
+    (the routers' ``top_k`` over the experts, an expert layer's pairs by
+    expert) is narrower than the index scores ``[2, 1, 12 x 4]``."""
+    _, params = toy_params(3)
+    paged = toy_model().clone(kv_page_size=4, kv_pages=25)
+    cache = init_decode_cache(paged, params, batch_size=2)
+
+    def ordered():
+        def step(cache, toks, pos, table):      # traced anew at each call
+            routing = PagedKV(table=table, positions=pos,
+                              valid=jnp.ones_like(pos, bool))
+            return paged.apply({"params": params, "cache": cache}, toks,
+                               positions=pos, decode=True, mutable=["cache"],
+                               pages=routing)
+
+        text = jax.jit(step).lower(
+            cache, jnp.zeros((2, 1), jnp.int32), jnp.zeros((2, 1), jnp.int32),
+            jnp.zeros((2, 12), jnp.int32)).as_text()
+        return [a or b for a, b in ORDERINGS.findall(text)]
+
+    scores = "tensor<2x1x48xf32>"
+    now = ordered()
+    assert now and not any(scores in types for types in now)
+    with pytest.MonkeyPatch.context() as patch:     # the check can tell
+        patch.setattr(dsv32.SparseLatentAttention, "_sparse_gather",
+                      sorted_sparse_gather)
+        assert any(scores in types for types in ordered())
 
 
 def run_engine(seed, lengths, *, max_new=8, **clone):
