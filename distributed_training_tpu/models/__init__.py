@@ -52,11 +52,17 @@ def _sarvam_mla(**kw):
     return make_sarvam_mla(**kw)
 
 
+def _keye_vl2(**kw):
+    from distributed_training_tpu.models.keye_vl2 import make_keye_vl2
+    return make_keye_vl2(**kw)
+
+
 _REGISTRY["vit_b16"] = _vit
 _REGISTRY["moe_mlp"] = _moe
 _REGISTRY["transformer_lm"] = _lm
 _REGISTRY["deepseek_v32"] = _deepseek_v32
 _REGISTRY["sarvam_mla"] = _sarvam_mla
+_REGISTRY["keye_vl2"] = _keye_vl2
 
 
 def available_models() -> list[str]:

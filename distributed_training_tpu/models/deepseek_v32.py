@@ -275,6 +275,63 @@ def by_page(pool, page_size: int):
     return pool.reshape(-1, page_size, pool.shape[-1])
 
 
+def local_key_blocks(rows, key_block: int):
+    """A call's own rows as its keys (no cache), cut into blocks: ``rows``
+    are arrays ``[B, T, width]`` (cache rows and, with an indexer, its
+    keys). Returns ``(fetch, kb, n_blocks)``: ``fetch(j)`` is block ``j``
+    of each, ``[B, kb, width]``."""
+    t = rows[0].shape[1]
+    kb = min(int(key_block), t)
+    pad = -t % kb
+    padded = [jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in rows]
+
+    def fetch(j):
+        return tuple(jax.lax.dynamic_slice_in_dim(a, j * kb, kb, axis=1)
+                     for a in padded)
+
+    return fetch, kb, (t + pad) // kb
+
+
+def write_paged_rows(module: nn.Module, written, pages: PagedKV,
+                     page_size: int, pool_pages: int):
+    """Write a call's rows into ``module``'s pools in place through the
+    page table: ``written`` is ``((leaf name, rows [B, T, width]), ...)``,
+    one ``cache`` leaf ``[pool_pages x page_size, width]`` each; rows that
+    do not exist go to the null page. Returns the pools as written."""
+    b, t = written[0][1].shape[:2]
+    pools = [module.variable("cache", name, jnp.zeros,
+                             (pool_pages * page_size, rows.shape[-1]),
+                             rows.dtype)
+             for name, rows in written]
+    table, positions, valid = pages
+    phys = jnp.take_along_axis(table, positions // page_size, axis=1) \
+        * page_size + positions % page_size
+    write_idx = jnp.where(valid, phys, 0).reshape(-1)   # null page: row 0
+    pools_all = [pool.value.at[write_idx].set(rows.reshape(b * t, -1))
+                 for pool, (_, rows) in zip(pools, written)]
+    if not module.is_initializing():
+        for pool, rows_all in zip(pools, pools_all):
+            pool.value = rows_all
+    return pools_all
+
+
+def paged_key_blocks(pools_all, table, page_size: int, key_block: int):
+    """A slot's cached rows as key blocks, gathered by page: ``(fetch, kb,
+    n_blocks)`` as :func:`local_key_blocks` gives them, block ``j`` holding
+    the rows of table entries ``j x pages a block ..``."""
+    b = table.shape[0]
+    ppb = max(int(key_block) // page_size, 1)      # pages a key block
+    n_blocks = -(-table.shape[1] // ppb)
+    padded = jnp.pad(table, ((0, 0), (0, n_blocks * ppb - table.shape[1])))
+    paged = [by_page(a, page_size) for a in pools_all]
+
+    def fetch(j):
+        tbl = jax.lax.dynamic_slice_in_dim(padded, j * ppb, ppb, 1)
+        return tuple(a[tbl].reshape(b, ppb * page_size, -1) for a in paged)
+
+    return fetch, ppb * page_size, n_blocks
+
+
 def index_scores(q_i, w_i, k_i):
     """``I[b, t, s] = sum_h w_i[b, t, h] * relu(q_i[b, t, h] . k_i[b, s])``
     in float32: q_i [B, T, Hi, D], w_i [B, T, Hi], k_i [B, S, D]."""
@@ -374,8 +431,10 @@ class SparseLatentAttention(nn.Module):
         qk_dim = self.nope_dim + self.rope_dim
         sparse = self.index_topk is not None
         if sparse and self.q_rank is None:
-            raise ValueError("the indexer's query is made from the query "
-                             "latent: index_topk needs q_rank")
+            raise ValueError(
+                "this module's indexer makes its query from the query "
+                "latent, so index_topk needs q_rank; an indexer without a "
+                "query latent is models/keye_vl2.py's")
         if self.q_rank is None:
             wq = self.param("wq", init, (d, h, qk_dim)).astype(dt)
         else:
@@ -444,70 +503,36 @@ class SparseLatentAttention(nn.Module):
             keys = (latent,) if index is None else (latent, index[2])
             out = self._masked_blocks(
                 q_nope, q_rope, index, positions, valid, wkv_b, scale,
-                *self._local_keys(*keys))
+                *local_key_blocks(keys, self.key_block))
         else:
             out = self._paged(q_nope, q_rope, index, latent, wkv_b, scale,
                               pages)
         return jnp.einsum("bthv,hvd->btd", out.astype(dt), wo)
 
     # -- where the keys come from --------------------------------------------
-    def _local_keys(self, *rows):
-        """The call's own rows as the keys (no cache): the plain forward.
-        ``rows`` are the latent rows and, with the indexer, its keys."""
-        t = rows[0].shape[1]
-        kb = min(int(self.key_block), t)
-        pad = -t % kb
-        padded = [jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in rows]
-
-        def fetch(j):
-            return tuple(jax.lax.dynamic_slice_in_dim(a, j * kb, kb, axis=1)
-                         for a in padded)
-
-        return fetch, kb, (t + pad) // kb
-
     def _paged(self, q_nope, q_rope, index, latent, wkv_b, scale,
                pages: PagedKV):
         """Write this call's rows into the layer's pools in place (the
         latent rows, and the indexer's keys where there is one), then
         attend through the page table in the lane the call's width
         selects."""
-        b, t = latent.shape[:2]
+        t = latent.shape[1]
         if self.kv_page_size is None or self.kv_pages is None:
             raise ValueError("pages= passed but the model was not cloned "
                              "with kv_page_size / kv_pages")
         ps = int(self.kv_page_size)
-        pool_rows = int(self.kv_pages) * ps
         written = [("latent_pages", latent)]
         if index is not None:
             written.append(("index_pages", index[2]))
-        pools = [self.variable("cache", name, jnp.zeros,
-                               (pool_rows, rows.shape[-1]), rows.dtype)
-                 for name, rows in written]
+        pools_all = write_paged_rows(self, written, pages, ps,
+                                     int(self.kv_pages))
         table, positions, valid = pages
-        phys = jnp.take_along_axis(table, positions // ps, axis=1) * ps \
-            + positions % ps
-        write_idx = jnp.where(valid, phys, 0).reshape(-1)   # null page: row 0
-        pools_all = [pool.value.at[write_idx].set(rows.reshape(b * t, -1))
-                     for pool, (_, rows) in zip(pools, written)]
-        if not self.is_initializing():
-            for pool, rows_all in zip(pools, pools_all):
-                pool.value = rows_all
 
         lane = paged_lane(t, index is not None)
         if lane == "masked-blocks":
-            ppb = max(int(self.key_block) // ps, 1)      # pages a key block
-            n_blocks = -(-table.shape[1] // ppb)
-            padded = jnp.pad(table, ((0, 0),
-                                     (0, n_blocks * ppb - table.shape[1])))
-            paged = [by_page(a, ps) for a in pools_all]
-
-            def fetch(j):
-                tbl = jax.lax.dynamic_slice_in_dim(padded, j * ppb, ppb, 1)
-                return tuple(a[tbl].reshape(b, ppb * ps, -1) for a in paged)
-
-            out = self._masked_blocks(q_nope, q_rope, index, positions,
-                                      valid, wkv_b, scale, fetch, ppb * ps,
-                                      n_blocks)
+            out = self._masked_blocks(
+                q_nope, q_rope, index, positions, valid, wkv_b, scale,
+                *paged_key_blocks(pools_all, table, ps, self.key_block))
         elif lane == "sparse-gather":
             out = self._sparse_gather(q_nope, q_rope, *index[:2], positions,
                                       table, *pools_all, wkv_b, scale)
@@ -773,6 +798,14 @@ class DeepseekV32LM(nn.Module):
         if self.index_topk is None:
             return int(live)
         return min(int(live), self.index_topk)
+
+    def index_rows_scored(self, live: int, budget: int) -> int:
+        """Of a decoding slot that holds ``live`` rows of a page budget of
+        ``budget``, the rows whose index key its lane reads and scores:
+        ``sparse-gather`` goes through the slot's whole table; none without
+        the indexer."""
+        del live
+        return 0 if self.index_topk is None else int(budget)
 
     @nn.compact
     def __call__(self, tokens, positions=None, train: bool = False,

@@ -321,6 +321,11 @@ class TransformerLM(nn.Module):
         """Of ``live`` cached rows a query attends all: dense attention."""
         return live
 
+    def index_rows_scored(self, live: int, budget: int) -> int:
+        """No indexer: no index key is scored."""
+        del live, budget
+        return 0
+
     @nn.compact
     def __call__(self, tokens, positions=None, train: bool = False,
                  decode: bool = False, return_hidden: bool = False,

@@ -31,7 +31,6 @@ Noisy gate policies (DeepSpeed names):
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Sequence
 
 import flax.linen as nn
@@ -289,6 +288,20 @@ def grouped_sigmoid_route(logits, bias, *, n_group: int, topk_group: int,
     return experts.astype(jnp.int32), w
 
 
+def softmax_topk_route(logits, *, top_k: int):
+    """Dropless top-k routing by softmax probabilities, renormalised (the
+    Qwen3-MoE family's router with ``norm_topk_prob``), in float32.
+
+    ``p = softmax(logits)`` over all experts; the ``top_k`` highest are
+    taken (ties to the lower index, as ``lax.top_k`` breaks them) and their
+    weights are ``p_i`` over the sum of the chosen: no bias, no groups, no
+    scale. Returns ``(experts int32 [T, top_k], weights float32 [T,
+    top_k])``."""
+    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    w, experts = jax.lax.top_k(p, top_k)
+    return experts.astype(jnp.int32), w / w.sum(-1, keepdims=True)
+
+
 def _silu_ffn(x, w1, w3, w2):
     """``(SiLU(x w1) * (x w3)) w2`` in ``x``'s type."""
     h = jax.nn.silu(jnp.dot(x, w1)) * jnp.dot(x, w3)
@@ -317,19 +330,25 @@ class HeldExpertsMlp(nn.Module):
 
     ``held = (first, count)``: of the ``num_experts`` routed experts this
     chip holds ``first .. first + count - 1`` (expert parallelism's share
-    of the layer). Every token is routed over ALL ``num_experts``
-    (:func:`grouped_sigmoid_route`); the layer computes the held experts'
-    part of ``sum_i w_i E_i(x)`` for the tokens routed to them, adds the
-    shared expert (replicated: every chip computes it alike), and returns
-    that partial sum. What the absent experts would add is left out and
-    nothing stands in for their chips or the exchange with them.
+    of the layer). Every token is routed over ALL ``num_experts``, by the
+    router the model's family has (``scoring``: ``"sigmoid"`` is
+    :func:`grouped_sigmoid_route` with its selection bias, groups and
+    scale; ``"softmax"`` is :func:`softmax_topk_route`, which has none of
+    the three and no ``router_bias`` leaf); the layer computes the held
+    experts' part of ``sum_i w_i E_i(x)`` for the tokens routed to them,
+    adds the shared experts where the model has some (replicated: every
+    chip computes them alike), and returns that partial sum. What the
+    absent experts would add is left out and nothing stands in for their
+    chips or the exchange with them.
 
     Dropless at static shapes: the (token, expert) pairs that landed on a
-    held expert are sorted by expert, and each held expert walks its run
-    of pairs ``block_rows`` at a time (rows gathered, multiplied,
-    scattered back under their routing weights): no block for an expert
-    that got none (its weights are not read), one for the usual few rows,
-    as many as it takes otherwise. No capacity, no drop.
+    held expert are sorted by expert, and one loop walks the experts' runs
+    of pairs ``block_rows`` at a time (rows gathered, multiplied by the
+    block's expert, scattered back under their routing weights): no block
+    for an expert that got none (its weights are not read), one for the
+    usual few rows, as many as it takes otherwise. One loop body whatever
+    the number of experts held, so a program's size and compile time do not
+    grow with it. No capacity, no drop.
 
     ``valid`` [T] masks tokens that do not exist (padding rows, empty
     decode slots): they are routed nowhere and counted nowhere. Sows, in
@@ -342,10 +361,11 @@ class HeldExpertsMlp(nn.Module):
     held: tuple
     hidden_dim: int
     top_k: int
-    n_group: int
-    topk_group: int
-    routed_scale: float
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scale: float = 1.0
     shared_experts: int = 1
+    scoring: str = "sigmoid"
     block_rows: int = 128
     dtype: Any = jnp.float32
 
@@ -360,8 +380,11 @@ class HeldExpertsMlp(nn.Module):
             raise ValueError(f"held {self.held} outside the "
                              f"{self.num_experts} routed experts")
         init = nn.initializers.normal(0.02)
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring {self.scoring!r}: sigmoid or softmax")
         w_g = self.param("router", init, (d, self.num_experts))
-        b_g = self.param("router_bias", init, (self.num_experts,))
+        if self.scoring == "sigmoid":
+            b_g = self.param("router_bias", init, (self.num_experts,))
         w1 = self.param("w1", init, (count, d, self.hidden_dim))
         w3 = self.param("w3", init, (count, d, self.hidden_dim))
         w2 = self.param("w2", init, (count, self.hidden_dim, d))
@@ -369,10 +392,14 @@ class HeldExpertsMlp(nn.Module):
         with jax.named_scope("moe.route"):
             logits = jnp.dot(x.astype(jnp.float32), w_g.astype(jnp.float32),
                              precision=jax.lax.Precision.HIGHEST)
-            experts, weights = grouped_sigmoid_route(
-                logits, b_g, n_group=self.n_group,
-                topk_group=self.topk_group, top_k=self.top_k,
-                scale=self.routed_scale)
+            if self.scoring == "sigmoid":
+                experts, weights = grouped_sigmoid_route(
+                    logits, b_g, n_group=self.n_group,
+                    topk_group=self.topk_group, top_k=self.top_k,
+                    scale=self.routed_scale)
+            else:
+                experts, weights = softmax_topk_route(logits,
+                                                      top_k=self.top_k)
             # Pairs by held expert; pairs routed elsewhere, or of tokens
             # that do not exist, go to the sentinel group ``count``.
             local = experts - first
@@ -401,7 +428,16 @@ class HeldExpertsMlp(nn.Module):
             pair_weight = jnp.pad(weights.reshape(-1)[order], (0, r))
             w1, w3, w2 = (w.astype(self.dtype) for w in (w1, w3, w2))
 
-            def block(e, i, acc):
+            # One walk over the blocks of every held expert in turn: block
+            # ``b`` is the expert's whose span of the running block totals
+            # holds ``b`` (an expert without rows has no block, and its
+            # weights are not read).
+            blocks = (rows + r - 1) // r
+            ends = jnp.cumsum(blocks)
+
+            def block(b, acc):
+                e = (ends <= b).sum(dtype=jnp.int32)
+                i = b - (ends[e] - blocks[e])
                 at = starts[e] + i * r
                 tok = jax.lax.dynamic_slice_in_dim(pair_token, at, r)
                 wgt = jax.lax.dynamic_slice_in_dim(pair_weight, at, r)
@@ -409,10 +445,8 @@ class HeldExpertsMlp(nn.Module):
                 y = _silu_ffn(x[tok], w1[e], w3[e], w2[e])
                 return acc.at[tok].add(y.astype(jnp.float32) * wgt[:, None])
 
-            acc = jnp.zeros((t, d), jnp.float32)
-            for e in range(count):
-                acc = jax.lax.fori_loop(0, (rows[e] + r - 1) // r,
-                                        functools.partial(block, e), acc)
+            acc = jax.lax.fori_loop(0, ends[-1], block,
+                                    jnp.zeros((t, d), jnp.float32))
             out = acc.astype(self.dtype)
             if self.shared_experts:
                 out = out + GatedMlp(

@@ -21,6 +21,14 @@ keys and values of all heads as the plain products ``c_kv @ W`` ``[S,
 heads x dim]`` (a head is a lane-aligned column block), the rotated key
 ``[S, rope]`` shared by the heads, the output ``[T, heads x v]``.
 
+The **grouped form** serves grouped-query attention from the same body: the
+keys and values hold fewer heads than the queries (``[S, kv_heads x dim]``
+as a K/V pool's pages hold them), query head ``h`` reads the column block
+``h // group``, and there is no shared rotated key (``q_rope`` and
+``k_rope`` are None: the whole key is the head's own). A group's query heads
+follow one another in the grid, so a key head's block is fetched once for
+all of them and is never expanded to the query heads in HBM.
+
 Off the TPU the kernel runs in Pallas interpret mode
 (``utils/compat.py::pallas_interpret``).
 """
@@ -46,8 +54,9 @@ def kernel_fits(batch: int, t: int, key_block: int, nope: int, rope: int,
                 v_dim: int, dtype) -> bool:
     """Whether a call's shapes are ones the kernel serves: one sequence, a
     chunk of whole query blocks, and heads whose key and value columns are
-    whole lane tiles. Decided from shapes and dtype alone — the same answer
-    on every backend."""
+    whole lane tiles; ``rope`` is the width of the rotated key the heads
+    share, 0 where there is none (the grouped form). Decided from shapes
+    and dtype alone — the same answer on every backend."""
     if jnp.dtype(dtype).itemsize not in (2, 4):
         return False
     return (batch == 1 and t % min(t, BLOCK_Q) == 0 and t % 32 == 0
@@ -61,8 +70,9 @@ def _kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, keep_ref,
     # operands in their own dtype, float32 accumulation (flash_attention.py)
     s = jax.lax.dot_general(qn_ref[0], kn_ref[...], contract_last,
                             preferred_element_type=jnp.float32)
-    s = s + jax.lax.dot_general(qr_ref[0], kr_ref[...], contract_last,
-                                preferred_element_type=jnp.float32)
+    if qr_ref is not None:
+        s = s + jax.lax.dot_general(qr_ref[0], kr_ref[...], contract_last,
+                                    preferred_element_type=jnp.float32)
     keep = keep_ref[...] != 0
     s = jnp.where(keep, s * scale, NEG_INF)
     m_prev, l_prev = stats_in[0][:, :1], stats_in[0][:, 1:2]
@@ -105,30 +115,54 @@ def masked_attention_block(q_nope, q_rope, k_nope, k_rope, v, keep, state,
     share; ``keep`` [T, S] (int8, non-zero = attend) the selection. Scores
     ``(q_nope . k_nope + q_rope . k_rope) * scale`` and the softmax
     statistics are float32; the probabilities meet the values in the
-    values' dtype, as in the flash kernels."""
+    values' dtype, as in the flash kernels.
+
+    The grouped form: ``k_nope`` [S, KVH x nope] and ``v`` [S, KVH x v]
+    hold ``KVH = H / group`` heads, query head ``h`` reads head ``h //
+    group`` of both, and ``q_rope`` / ``k_rope`` are None (no key part is
+    shared by the heads); the output's head size is ``v``'s."""
     h, t, nope = q_nope.shape
-    rope = q_rope.shape[-1]
-    s = k_rope.shape[0]
-    v_dim = v.shape[1] // h
+    s = k_nope.shape[0]
+    group = h * nope // k_nope.shape[1]
+    v_dim = v.shape[1] * group // h
     bq = min(t, BLOCK_Q)
-    o, stats = state
+
+    def by_head(width):          # a head's rows of the queries
+        return pl.BlockSpec((1, bq, width), lambda hh, i: (hh, i, 0))
+
+    def key_head(width):         # the column block query head hh reads
+        if group == 1:
+            return pl.BlockSpec((s, width), lambda hh, i: (0, hh))
+        return pl.BlockSpec((s, width), lambda hh, i: (0, hh // group))
+
     row = pl.BlockSpec((1, bq, LSE_LANES), lambda hh, i: (hh, i, 0))
     out = pl.BlockSpec((bq, v_dim), lambda hh, i: (i, hh))
+    shared = q_rope is not None      # a rotated key part all heads share
+    operands = [(q_nope, by_head(nope))]
+    if shared:
+        operands.append((q_rope, by_head(q_rope.shape[-1])))
+    operands.append((k_nope, key_head(nope)))
+    if shared:
+        operands.append((k_rope, pl.BlockSpec((s, k_rope.shape[-1]),
+                                              lambda hh, i: (0, 0))))
+    operands += [(v, key_head(v_dim)),
+                 (keep, pl.BlockSpec((bq, s), lambda hh, i: (i, 0))),
+                 (state[0], out), (state[1], row)]
+    n = len(operands)
     return pl.pallas_call(
-        functools.partial(_kernel, scale=scale),
+        functools.partial(_kernel if shared else _grouped_kernel,
+                          scale=scale),
         grid=(h, t // bq),
-        in_specs=[
-            pl.BlockSpec((1, bq, nope), lambda hh, i: (hh, i, 0)),
-            pl.BlockSpec((1, bq, rope), lambda hh, i: (hh, i, 0)),
-            pl.BlockSpec((s, nope), lambda hh, i: (0, hh)),
-            pl.BlockSpec((s, rope), lambda hh, i: (0, 0)),
-            pl.BlockSpec((s, v_dim), lambda hh, i: (0, hh)),
-            pl.BlockSpec((bq, s), lambda hh, i: (i, 0)),
-            out, row,
-        ],
+        in_specs=[spec for _, spec in operands],
         out_specs=[out, row],
         out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in state],
-        input_output_aliases={6: 0, 7: 1},
+        input_output_aliases={n - 2: 0, n - 1: 1},
         interpret=pallas_interpret(interpret),
         name=NAME,
-    )(q_nope, q_rope, k_nope, k_rope, v, keep, o, stats)
+    )(*(a for a, _ in operands))
+
+
+def _grouped_kernel(q_ref, k_ref, v_ref, keep_ref, o_in, stats_in, o_out,
+                    stats_out, *, scale):
+    _kernel(q_ref, None, k_ref, None, v_ref, keep_ref, o_in, stats_in,
+            o_out, stats_out, scale=scale)

@@ -294,11 +294,14 @@ class Engine:
     about itself, ``paged_lane(t_in, page_size, kv_dtype)`` (the name of
     the attention formulation a paged call that wide takes),
     ``attended_rows(live)`` (how many of a slot's live rows one query
-    reads) and ``step_counters`` (names of int32 scalars it sows into a
+    reads), ``index_rows_scored(live, budget)`` (how many index keys a
+    decoding slot's lane scores to select them; 0 without an indexer) and
+    ``step_counters`` (names of int32 scalars it sows into a
     ``counters`` collection each step: docs/SERVING.md "What a model
     tells the engine"). :class:`~distributed_training_tpu.models.gpt.
-    TransformerLM` and :class:`~distributed_training_tpu.models.
-    deepseek_v32.DeepseekV32LM` are the two.
+    TransformerLM`, :class:`~distributed_training_tpu.models.
+    deepseek_v32.DeepseekV32LM` and :class:`~distributed_training_tpu.
+    models.keye_vl2.KeyeVL2LM` are the three.
 
     >>> eng = Engine(model, params, ServeConfig(max_batch=8))
     >>> eng.submit(prompt_tokens)
@@ -1710,9 +1713,10 @@ class Engine:
         # slots' positions cover (each decoding slot through its
         # window's last valid row, the chunk's slot through the chunk's
         # last row), beside the fixed budget the gather formulation
-        # reads whatever is live; and what the decoding slots' queries
+        # reads whatever is live; what the decoding slots' queries
         # read of their live rows (a model with a learned selection
-        # reads fewer than all).
+        # reads fewer than all); and the rows whose index key such a
+        # model's lane scores to make that selection (one layer's).
         pages_live = sum(pages_for(r, self.page_size) for r in rows_live)
         if chunk_seq is not None:
             pages_live += pages_for(start + c, self.page_size)
@@ -1733,7 +1737,10 @@ class Engine:
                 kv_pages_budget=s * self.pages_per_slot,
                 kv_rows_live=sum(rows_live),
                 kv_rows_selected=sum(map(self.model.attended_rows,
-                                         rows_live))))
+                                         rows_live)),
+                index_rows_scored=sum(
+                    self.model.index_rows_scored(r, self._l_all)
+                    for r in rows_live)))
 
     def _launch(self, step: _DeviceStep, prev: _DeviceStep | None) -> int:
         """Hand ``step``'s inputs over as one packed buffer

@@ -1,5 +1,8 @@
 """MoE layer tests: gating invariants, expert parallelism, DS flag parity."""
 
+import json
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -139,3 +142,65 @@ def test_moe_model_registry_and_forward():
     variables = model.init(jax.random.PRNGKey(0), x, train=False)
     logits = model.apply(variables, x, train=False)
     assert logits.shape == (2, 10)
+
+
+# -- the softmax top-k router of HeldExpertsMlp ------------------------------
+
+def brute_softmax_route(logits, k):
+    """Row by row in numpy float64: softmax over all experts, the ``k``
+    highest (ties to the lower index), renormalised over the chosen."""
+    experts, weights = [], []
+    for row in logits.astype(np.float64):
+        p = np.exp(row - row.max())
+        p /= p.sum()
+        chosen = sorted(range(row.size), key=lambda i: (-p[i], i))[:k]
+        experts.append(chosen)
+        weights.append(p[chosen] / p[chosen].sum())
+    return np.array(experts), np.array(weights)
+
+
+@pytest.mark.parametrize("seed,e,k,ties", [
+    (0, 128, 8, False), (1, 128, 8, True), (2, 16, 2, True), (3, 8, 8, False)])
+def test_the_softmax_router_against_a_brute_force_routing(seed, e, k, ties):
+    """Against a float64 routing and against the benchmark's plain
+    reference. With ``ties`` the logits are rounded to halves, so that a
+    row's k-th probability is shared: the lower index wins. The weights are
+    float32 softmaxes: 1e-6 of values under 1."""
+    from benchmark.reference import keye_vl2 as ref
+    from distributed_training_tpu.models.moe import softmax_topk_route
+
+    rng = np.random.default_rng(seed)
+    logits = rng.normal(0, 1.7, (24, e)).astype(np.float32)
+    if ties:
+        logits = np.round(logits * 2) / 2
+    experts, w = softmax_topk_route(jnp.asarray(logits), top_k=k)
+    want_e, want_w = brute_softmax_route(logits, k)
+    np.testing.assert_array_equal(np.asarray(experts), want_e)
+    np.testing.assert_allclose(np.asarray(w), want_w, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(w).sum(-1), 1.0, atol=1e-6)
+    # the reference routes from x and the router's matrix: the identity
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmark", "data", "toy-keye.json")) as fh:
+        cfg = {**json.load(fh), "num_experts_per_tok": k}
+    with jax.default_matmul_precision("highest"):
+        ref_e, ref_w = ref.route(jnp.asarray(logits),
+                                 {"router": jnp.eye(e)}, cfg)
+    np.testing.assert_array_equal(np.asarray(ref_e), want_e)
+    np.testing.assert_allclose(np.asarray(ref_w), want_w, atol=1e-6)
+
+
+def test_the_held_experts_layer_takes_its_router_from_the_model():
+    """``scoring`` is a field of the model's structure: the softmax router
+    has no selection bias leaf, and a name that is neither is refused."""
+    from distributed_training_tpu.models.moe import HeldExpertsMlp
+
+    x = jnp.ones((3, 16))
+    kw = dict(num_experts=8, held=(0, 8), hidden_dim=8, top_k=2)
+    soft = HeldExpertsMlp(**kw, scoring="softmax", shared_experts=0)
+    assert set(soft.init(jax.random.key(0), x)["params"]) == {
+        "router", "w1", "w2", "w3"}
+    sig = HeldExpertsMlp(**kw, n_group=2, topk_group=1, routed_scale=2.5)
+    assert set(sig.init(jax.random.key(0), x)["params"]) == {
+        "router", "router_bias", "w1", "w2", "w3", "shared"}
+    with pytest.raises(ValueError, match="scoring"):
+        HeldExpertsMlp(**kw, scoring="tanh").init(jax.random.key(0), x)

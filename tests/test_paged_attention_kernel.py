@@ -452,3 +452,33 @@ def test_the_masked_attention_kernel_compiles_for_a_v5e_at_deepseek_widths(
     assert "tpu_custom_call" in compiled.as_text()
     # the state is advanced in place and no score reaches memory
     assert compiled.memory_analysis().temp_size_in_bytes < t * s * 4
+
+
+def test_the_grouped_masked_attention_kernel_compiles_for_a_v5e_at_keye_widths(
+        one_chip):
+    """The grouped form at ``keyevl2-serve-longctx``'s shapes: a 1024-row
+    chunk of 32 query heads against a 1024-key block of 4 key/value heads
+    of 128, as the pool's pages hold them."""
+    from distributed_training_tpu.ops import masked_attention as ma
+
+    heads, kv_heads, t, s, dim = 32, 4, 1024, 1024, 128
+    assert ma.kernel_fits(1, t, s, dim, 0, dim, jnp.bfloat16)
+
+    def shape(dims, d):
+        return jax.ShapeDtypeStruct(dims, d, sharding=one_chip)
+
+    def call(q, k, v, keep, state):
+        return ma.masked_attention_block(q, None, k, None, v, keep, state,
+                                         scale=0.1, interpret=False)
+
+    state = jax.eval_shape(lambda: ma.init_state(t, heads, dim))
+    compiled = jax.jit(call, donate_argnums=4).lower(
+        shape((heads, t, dim), jnp.bfloat16),
+        shape((s, kv_heads * dim), jnp.bfloat16),
+        shape((s, kv_heads * dim), jnp.bfloat16),
+        shape((t, s), jnp.int8),
+        tuple(shape(a.shape, a.dtype) for a in state)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the state is advanced in place, no score reaches memory and no key
+    # head is expanded to its query heads
+    assert compiled.memory_analysis().temp_size_in_bytes < t * s * 4
