@@ -70,7 +70,10 @@ import numpy as np
 
 from distributed_training_tpu.models.moe import GatedMlp, HeldExpertsMlp
 from distributed_training_tpu.ops import masked_attention, paged_attention
-from distributed_training_tpu.parallel.ring_attention import PagedKV
+from distributed_training_tpu.parallel.ring_attention import (
+    PagedKV,
+    paged_write,
+)
 
 HIGHEST = jax.lax.Precision.HIGHEST
 # A decode window at most this wide takes the sparse-gather lane.
@@ -296,19 +299,17 @@ def write_paged_rows(module: nn.Module, written, pages: PagedKV,
                      page_size: int, pool_pages: int):
     """Write a call's rows into ``module``'s pools in place through the
     page table: ``written`` is ``((leaf name, rows [B, T, width]), ...)``,
-    one ``cache`` leaf ``[pool_pages x page_size, width]`` each; rows that
-    do not exist go to the null page. Returns the pools as written."""
-    b, t = written[0][1].shape[:2]
+    one ``cache`` leaf ``[pool_pages x page_size, width]`` each, declared
+    here and written by the shared writer (``parallel/ring_attention.py::
+    paged_write``: a page at a time for a chunk, a row at a time for the
+    narrow lanes); rows that do not exist go to the null page. Returns the
+    pools as written."""
     pools = [module.variable("cache", name, jnp.zeros,
                              (pool_pages * page_size, rows.shape[-1]),
                              rows.dtype)
              for name, rows in written]
-    table, positions, valid = pages
-    phys = jnp.take_along_axis(table, positions // page_size, axis=1) \
-        * page_size + positions % page_size
-    write_idx = jnp.where(valid, phys, 0).reshape(-1)   # null page: row 0
-    pools_all = [pool.value.at[write_idx].set(rows.reshape(b * t, -1))
-                 for pool, (_, rows) in zip(pools, written)]
+    pools_all = paged_write([pool.value for pool in pools],
+                            [rows for _, rows in written], pages, page_size)
     if not module.is_initializing():
         for pool, rows_all in zip(pools, pools_all):
             pool.value = rows_all

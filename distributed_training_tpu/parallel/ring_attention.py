@@ -310,6 +310,95 @@ def paged_formulation(t_in: int, num_heads: int, head_dim: int,
     return "gather"
 
 
+def paged_write_form(t_in: int, page_size: int, dtypes) -> str:
+    """How a paged call ``t_in`` rows a slot wide writes its rows into
+    pools of ``dtypes`` (:func:`paged_write`): ``"page"`` where the run
+    spans at least a page and a page is whole tiles of every pool's
+    dtype (16 rows of bfloat16, 8 of float32, 32 of int8 — the pool
+    viewed ``[pages, page_size, width]`` is then a bitcast), else
+    ``"row"``. Shapes and dtypes only — no knob, no backend."""
+    whole_tiles = all(page_size % (32 // jnp.dtype(d).itemsize) == 0
+                      for d in dtypes)
+    return "page" if t_in >= page_size and whole_tiles else "row"
+
+
+def paged_write(pools, rows, pages: PagedKV, page_size: int):
+    """Write a call's new rows into paged pools through the page table;
+    the one writer of every paged cache. ``pools`` are ``[pool_rows,
+    width]`` arrays, ``rows`` the call's ``[B, T_in, ...]`` for each (the
+    trailing dimensions make ``width``: ``[H, hd]`` per-head rows too);
+    returns the pools as written (in place where the caller donated
+    them). Invalid rows go to the null page (page 0), where duplicate
+    writes are harmless garbage.
+
+    :func:`paged_write_form` picks the form from the call's shapes:
+
+    - **row** — each row scattered alone (the decode lane's row a slot,
+      a verify window, pages smaller than a tile of some pool's dtype —
+      the int8 pool at pages under 32 rows). On the device a row rewrites its whole tile row, so a
+      wide call pays a serialized update per row, padding included.
+    - **page** — a row of the call is a RUN: its valid rows are a
+      prefix holding the consecutive positions ``positions[b, 0] ..``
+      (the chunk lane's and the verify window's contract). The run's
+      pages are written whole: a page the run covers only in part (the
+      first, the last) is first merged with the rows it holds, so no
+      row outside the run changes; a page holding no row of the run
+      goes to the null page — a table entry past the slot's
+      allocation may be stale. Bit-equal to the row form on every page
+      but the null page."""
+    table, positions, valid = pages
+    b, t = positions.shape
+    ps = page_size
+    if paged_write_form(t, ps, [p.dtype for p in pools]) == "row":
+        phys = jnp.take_along_axis(table, positions // ps, axis=1) * ps \
+            + positions % ps
+        write_idx = jnp.where(valid, phys, 0).reshape(-1)
+        # per-head rows [B, T, H, hd] become tokens first, all of them,
+        # as the GPT layer did before this writer was shared: the decode
+        # programs' lowered text stays byte-equal (PR 36)
+        rows = [r.reshape(b * t, *r.shape[2:]) if r.ndim > 3 else r
+                for r in rows]
+        return [pool.at[write_idx].set(r.reshape(b * t, -1))
+                for pool, r in zip(pools, rows)]
+    count = valid.sum(axis=1, dtype=jnp.int32)          # [B]
+    off = positions[:, 0] % ps                          # first row's place
+    span = (t + 2 * ps - 2) // ps                       # most pages a run spans
+    j = jnp.arange(span)
+    holds = (j * ps < (off + count)[:, None]) & (count > 0)[:, None]
+    logical = jnp.minimum(positions[:, :1] // ps + j, table.shape[1] - 1)
+    phys = jnp.where(holds, jnp.take_along_axis(table, logical, axis=1), 0)
+    last = jnp.maximum(off + count - 1, 0) // ps        # the run's last page
+    edges = jnp.stack(
+        [phys[:, 0], jnp.take_along_axis(phys, last[:, None], axis=1)[:, 0]],
+        axis=1)                                          # [B, 2]
+    place = jnp.arange(span * ps)
+    fresh = ((place >= off[:, None]) & (place < (off + count)[:, None])
+             ).reshape(b, span, ps, 1)
+
+    def write(pool, r):
+        width = pool.shape[-1]
+        paged = pool.reshape(-1, ps, width)
+        # what the run's edge pages hold: page 0 of the run merges with
+        # the first, every later page with the last (only those two can
+        # be partial; the others are whole or go to the null page)
+        held = paged[edges]                              # [B, 2, ps, w]
+        old = jnp.where((j == 0)[None, :, None, None], held[:, :1],
+                        held[:, 1:])
+        # the rows where their pages hold them: padded by a page in
+        # front and sliced from ps - off (a chip run timed this at 9 µs
+        # a 256-row chunk into GPT-2 large's pool, 12-13 µs as an update
+        # of a zero buffer, 39 µs row by row: PERF.md §6, PR 36)
+        padded = jnp.pad(r.reshape(b, t, width),
+                         ((0, 0), (ps, span * ps - t), (0, 0)))
+        new = jax.vmap(lambda x, o: lax.dynamic_slice_in_dim(
+            x, ps - o, span * ps))(padded, off).reshape(b, span, ps, width)
+        merged = jnp.where(fresh, new, old)
+        return paged.at[phys.reshape(-1)].set(
+            merged.reshape(b * span, ps, width)).reshape(pool.shape)
+
+    return [write(pool, r) for pool, r in zip(pools, rows)]
+
+
 def paged_gather_attention(q, k_all, v_all, table, positions, *,
                            page_size: int, scales=None):
     """The gather formulation of paged attention, and the plain oracle of
@@ -444,11 +533,13 @@ class RingSelfAttention(nn.Module):
         [rows, 20, 64] array out with ROWS minor-most, so every use of
         it as rows began and ended with a relayout of the whole pool;
         [rows, H·hd] is row-major on the device as it is here, and a page
-        is one contiguous tile.) Each incoming token scatters its K/V at
+        is one contiguous tile.) Each incoming token's K/V land at
         ``table[b, pos // ps] * ps + pos % ps`` (null page when ``valid``
-        is False) — in place: the pool that enters is the pool that
-        leaves. Then every query row attends keys ``0..pos`` of its OWN
-        row's page table, the rows this same call wrote included, in one
+        is False) through :func:`paged_write` — a page at a time for the
+        chunk, a row at a time for the narrow lanes — in place: the pool
+        that enters is the pool that leaves. Then every query row attends
+        keys ``0..pos`` of its OWN row's page table, the rows this same
+        call wrote included, in one
         of two formulations, chosen from the call's shapes and dtypes
         alone (:func:`paged_formulation`):
 
@@ -509,14 +600,7 @@ class RingSelfAttention(nn.Module):
             cvs = self.variable("cache", "value_scales", jnp.zeros, sshape,
                                 jnp.float32)
         table, positions, valid = pages
-        # Physical write rows; invalid tokens land in the null page
-        # (row < ps), where duplicate scatters are harmless garbage.
-        logical = positions // ps
-        phys = jnp.take_along_axis(table, logical, axis=1) * ps \
-            + positions % ps
-        write_idx = jnp.where(valid, phys, 0).reshape(-1)
-        k_rows = k.reshape(b * t_in, -1, head_dim)
-        v_rows = v.reshape(b * t_in, -1, head_dim)
+        pools, rows = [ck, cv], [k, v]          # [B, T_in, H, hd] each
         if quant:
             # Quantize-on-scatter: symmetric per-row per-head int8,
             # scale = amax/127 over head_dim, round-to-nearest
@@ -531,16 +615,17 @@ class RingSelfAttention(nn.Module):
                               -127, 127).astype(jnp.int8)
                 return qr, scl
 
-            k_rows, k_scl = _quantize_rows(k_rows)
-            v_rows, v_scl = _quantize_rows(v_rows)
-            ks_all = cks.value.at[write_idx].set(k_scl)
-            vs_all = cvs.value.at[write_idx].set(v_scl)
-            if not self.is_initializing():
-                cks.value, cvs.value = ks_all, vs_all
-        k_all = ck.value.at[write_idx].set(k_rows.reshape(-1, width))
-        v_all = cv.value.at[write_idx].set(v_rows.reshape(-1, width))
+            k_rows, k_scl = _quantize_rows(k)
+            v_rows, v_scl = _quantize_rows(v)
+            pools += [cks, cvs]
+            rows = [k_rows, v_rows, k_scl, v_scl]
+        written = paged_write([p.value for p in pools], rows, pages, ps)
         if not self.is_initializing():
-            ck.value, cv.value = k_all, v_all
+            for pool, new in zip(pools, written):
+                pool.value = new
+        k_all, v_all = written[:2]
+        if quant:
+            ks_all, vs_all = written[2:]
 
         if paged_formulation(t_in, self.num_heads, head_dim, ps, k.dtype,
                              self.kv_dtype) == "kernel":

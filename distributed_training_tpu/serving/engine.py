@@ -112,7 +112,10 @@ from distributed_training_tpu.inference.sampler import (
 )
 from distributed_training_tpu.models.gpt import init_decode_cache
 from distributed_training_tpu.observability import trace as trace_lib
-from distributed_training_tpu.parallel.ring_attention import PagedKV
+from distributed_training_tpu.parallel.ring_attention import (
+    PagedKV,
+    paged_write_form,
+)
 from distributed_training_tpu.resilience.errors import SwapError
 from distributed_training_tpu.serving.alerts import (
     AlertEngine,
@@ -592,9 +595,16 @@ class Engine:
                                             cfg.kv_dtype)
                 for lane, t_in in (("decode", self.spec_k + 1),
                                    ("chunk", self.prefill_chunk))}
+            # How the chunk's rows land in the pools: a page or a row at a
+            # time, by the writer's own test of the shapes and dtypes.
+            self.chunk_write = paged_write_form(
+                self.prefill_chunk, ps,
+                {leaf.dtype for leaf in jax.tree_util.tree_leaves(
+                    self._cache)})
             build_span.attrs.update(
                 decode_lane=self.lane_formulation["decode"],
-                chunk_lane=self.lane_formulation["chunk"])
+                chunk_lane=self.lane_formulation["chunk"],
+                chunk_write=self.chunk_write)
             self._fused = jax.jit(
                 self._fused_impl,
                 donate_argnums=(1,) if donate else ())
@@ -1736,6 +1746,9 @@ class Engine:
                 kv_pages_live=pages_live,
                 kv_pages_budget=s * self.pages_per_slot,
                 kv_rows_live=sum(rows_live),
+                kv_pages_written=(
+                    pages_for(start % self.page_size + c, self.page_size)
+                    if c and self.chunk_write == "page" else 0),
                 kv_rows_selected=sum(map(self.model.attended_rows,
                                          rows_live)),
                 index_rows_scored=sum(

@@ -324,7 +324,8 @@ def paged_logits(model, params, seq, page: int, chunk: int, prompt: int):
 @pytest.mark.parametrize("key_block,expert_rows,page,chunk,prompt", [
     (8, 4, 4, 16, 29),        # several key blocks; experts overflow
     (1024, 128, 4, 16, 32),   # the cell's own block sizes; whole chunks
-    (16, 2, 8, 12, 39)])      # pages of 8; one decoded token
+    (16, 2, 8, 12, 39),       # pages of 8; one decoded token
+    (8, 4, 8, 20, 45)])       # a page at a time, chunks from mid-page
 def test_chunked_prefill_then_paged_decode_gives_the_references_logits(
         key_block, expert_rows, page, chunk, prompt):
     flat, params = toy_params(11 + key_block)

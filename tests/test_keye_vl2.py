@@ -124,7 +124,8 @@ def test_three_unequal_streams_give_the_references_logits(forwards):
 
 @pytest.mark.parametrize("key_block,expert_rows,page,chunk,prompt", [
     (8, 4, 4, 16, 29),        # several key blocks; experts overflow
-    (1024, 128, 8, 12, 39)])  # the cell's own block sizes; pages of 8
+    (1024, 128, 8, 12, 39),   # the cell's own block sizes; pages of 8
+    (8, 4, 8, 20, 45)])       # a page at a time, chunks from mid-page
 def test_chunked_prefill_then_paged_decode_gives_the_references_logits(
         key_block, expert_rows, page, chunk, prompt):
     """Contexts run to 48 keys where a query may attend 8: the selection

@@ -482,3 +482,53 @@ def test_the_grouped_masked_attention_kernel_compiles_for_a_v5e_at_keye_widths(
     # the state is advanced in place, no score reaches memory and no key
     # head is expanded to its query heads
     assert compiled.memory_analysis().temp_size_in_bytes < t * s * 4
+
+
+def _scatters(text):
+    """Each scatter of a compiled program's text: (result shape, how many
+    indices it writes), the index operand's shape read where it is made."""
+    import re
+
+    made = dict(re.findall(r"(%[\w.-]+) = \w+\[([\d,]*)\]", text))
+    out = []
+    for shape, idx in re.findall(
+            r"= bf16\[([\d,]+)\]\S* scatter\(%[\w.-]+, (%[\w.-]+)", text):
+        out.append((shape, int(made[idx].split(",")[0])))
+    return out
+
+
+@pytest.mark.parametrize("lane, slots, t_in", [("chunk", 1, 256),
+                                               ("decode", 32, 1)])
+def test_the_pool_write_compiles_for_a_v5e_at_gpt2_large_shapes(one_chip,
+                                                                lane, slots,
+                                                                t_in):
+    """``paged_write`` into one of the batch cell's pools: the chunk of 256
+    rows lands as 17 page updates of ``[pages, 16, 1280]`` (the pool
+    viewed by page: a bitcast), not as 256 row updates; the decode lane
+    keeps its scatter of 32 rows. Both in place: no copy of the pool."""
+    from distributed_training_tpu.parallel.ring_attention import paged_write
+
+    rows, width, ps, per_slot = 24592, 1280, 16, 64
+
+    def shape(s, d):
+        return jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+
+    def write(pool, new, table, positions, valid):
+        return paged_write([pool], [new], PagedKV(table, positions, valid),
+                           ps)[0]
+
+    compiled = jax.jit(write, donate_argnums=0).lower(
+        shape((rows, width), jnp.bfloat16),
+        shape((slots, t_in, width), jnp.bfloat16),
+        shape((slots, per_slot), jnp.int32),
+        shape((slots, t_in), jnp.int32),
+        shape((slots, t_in), jnp.bool_)).compile()
+    text = compiled.as_text()
+    want = {"chunk": [(f"{rows // ps},{ps},{width}", 17)],
+            "decode": [(f"{rows},{width}", slots)]}[lane]
+    assert _scatters(text) == want
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and "bf16[" in line]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == rows * width * 2      # in place
+    assert mem.temp_size_in_bytes < rows * width * 2 // 64
