@@ -1,0 +1,3 @@
+"""Reader of the per-layer metric ``host_gap_share.tokens`` (see PERF.md, Layers)."""
+
+from benchmark.stepreaders import host_gap_share as read  # noqa: F401

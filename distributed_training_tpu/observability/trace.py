@@ -28,6 +28,10 @@ kind of cross-component trace). This module is that timeline:
   process-wide bounded ring (:func:`host_spans`; always on, like the
   flight recorder's) and is forwarded to a :class:`TraceSession` when
   one is attached.
+- The process's own pauses are records of the same ring, always on:
+  ``proc.gc`` (a generation-2 collection, or any of ``GC_PAUSE_S`` or
+  more) and ``proc.compile`` (a backend compile). Each takes the key of
+  the span open on its thread, so a pause inside an iteration names it.
 
 Overhead contract: "off" means no ``TraceSession`` and no profiler
 session — integration points hold ``trace: TraceSession | None`` and
@@ -48,6 +52,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import itertools
 import json
 import os
@@ -55,6 +60,7 @@ import threading
 import time
 from typing import Any, Callable, Iterable, Iterator
 
+from jax import monitoring
 from jax.profiler import TraceAnnotation
 
 # One JSON object per file (not the bare-array variant): carries the
@@ -224,16 +230,29 @@ class TraceSession:
 
 
 # -- the program's spans ------------------------------------------------------
-# Ring capacity: a 40 s window of the fastest cell (~6 engine iterations
-# or ~8 training steps a second, <= 10 spans each) plus its set-up and
-# pre-roll fits four times over; at ~0.4 KB a record the ring tops out
-# near 13 MB.
+# Ring capacity: at ~0.4 KB a record the ring tops out near 13 MB. A
+# serving engine at ~180 iterations a second (~9 spans each) turns it over
+# in ~20 s; a trainer's steps fit many times over.
 RING_SPANS = 32768
 
 _ring: collections.deque = collections.deque(maxlen=RING_SPANS)
 _ring_lock = threading.Lock()
+# Records made where no lock may be taken (the collector's callback: a
+# collection can start on a thread that holds _ring_lock): appended here
+# without a lock and moved into the ring by the next close or read.
+_pending: collections.deque = collections.deque()
 _ids = itertools.count(1)
 _local = threading.local()
+
+
+def _flush_pending() -> list:
+    """Move the pending records into the ring; call with ``_ring_lock``
+    held. Returns them, for :meth:`Span._send` outside the lock."""
+    moved = []
+    while _pending:
+        moved.append(_pending.popleft())
+    _ring.extend(moved)
+    return moved
 
 
 def _stack() -> list:
@@ -289,7 +308,14 @@ class Span(contextlib.ContextDecorator):
 
     def _close(self) -> None:
         with _ring_lock:
+            paused = _flush_pending() if _pending else ()
             _ring.append(self)
+        for sp in paused:
+            sp._send()
+        self._send()
+
+    def _send(self) -> None:
+        """Forward to the attached session, if any, once."""
         if self.session is not None:
             args = (self.attrs if self.key is None
                     else {**self.attrs, "key": self.key})
@@ -359,12 +385,53 @@ def host_spans(since: float | None = None,
     """The ring's closed spans, oldest first; with bounds, those that lie
     wholly inside ``[since, until]`` (``perf_counter`` seconds)."""
     with _ring_lock:
+        paused = _flush_pending() if _pending else ()
         spans = list(_ring)
+    for sp in paused:
+        sp._send()
     if since is not None:
         spans = [s for s in spans if s.t0 >= since]
     if until is not None:
         spans = [s for s in spans if s.t1 <= until]
     return spans
+
+
+# -- the process's pauses -----------------------------------------------------
+# A collection of this long or longer is a record whatever its generation;
+# every generation-2 collection is one.
+GC_PAUSE_S = 1e-3
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_gc_t0 = 0.0
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook. It runs inside the collection, on whatever
+    thread allocated, so it takes no lock: the record goes to ``_pending``.
+    Collections do not overlap, so one start time is enough."""
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = time.perf_counter()
+        return
+    t1 = time.perf_counter()
+    gen = info["generation"]
+    if gen == 2 or t1 - _gc_t0 >= GC_PAUSE_S:
+        sp = Span("proc.gc", None, None, None,
+                  {"generation": gen, "collected": info["collected"]})
+        sp._adopt(_stack())
+        sp.t0, sp.t1 = _gc_t0, t1
+        _pending.append(sp)
+
+
+def _on_duration(event: str, seconds: float, **kw: Any) -> None:
+    """``jax.monitoring`` duration listener: a backend compile, which JAX
+    reports as it ends."""
+    if event == _COMPILE_EVENT:
+        t1 = time.perf_counter()
+        record("proc.compile", t1 - seconds, t1, fun_name=kw.get("fun_name"))
+
+
+gc.callbacks.append(_on_gc)
+monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def session_for_run(cfg, *, default_dir: str, component: str = "train"

@@ -275,9 +275,14 @@ class _DeviceStep:
     pages_drawn: int
     # this step's counters on the span of the iteration that delivers it
     attrs: dict
+    # perf_counter stamps, on that span too: `_launch` entered and
+    # returned; a bracket around the moment the outputs became ready
+    launch_t0: float = 0.0
+    launch_t1: float = 0.0
+    landed_lo: float = 0.0
+    landed_hi: float | None = None
     # what the launch returned: the arrays the next launch reads on the
     # device are the very ones the host fetches
-    t0: float = 0.0
     nxt: Any = None
     acc: Any = None
     c_sampled: Any = None
@@ -285,6 +290,26 @@ class _DeviceStep:
     # page releases of sequences that left while this step was in flight:
     # handed to the pool when it has landed
     held: list = dataclasses.field(default_factory=list)
+
+    def probe(self) -> None:
+        """Look whether the outputs have landed, and narrow the bracket:
+        ``landed_lo`` is the last look that found them not ready,
+        ``landed_hi`` the first that found them ready."""
+        if self.landed_hi is None:
+            t = time.perf_counter()
+            if self.nxt.is_ready():
+                self.landed_hi = time.perf_counter()
+            else:
+                self.landed_lo = t
+
+    def land(self) -> None:
+        """Wait for the outputs where the host is first. The wait returns
+        on the landing itself, so both ends are stamped from its return."""
+        self.probe()
+        if self.landed_hi is None:
+            # graftlint: disable=hot-path-transfer -- the wait the fetch below makes anyway, made first so that its return stamps the landing and not the copy
+            self.nxt.block_until_ready()
+            self.landed_lo = self.landed_hi = time.perf_counter()
 
 
 class Engine:
@@ -1755,19 +1780,18 @@ class Engine:
                     self.model.index_rows_scored(r, self._l_all)
                     for r in rows_live)))
 
-    def _launch(self, step: _DeviceStep, prev: _DeviceStep | None) -> int:
+    def _launch(self, step: _DeviceStep, prev: _DeviceStep | None) -> None:
         """Hand ``step``'s inputs over as one packed buffer
         (:class:`_StepLayout`) and launch its program behind ``prev``'s
         on the device; nothing is fetched. ``prev``'s outputs go in as
         they came out: the device picks a slot's incoming token from
-        them where the host could not (``d_src``). Returns the
-        host-to-device transfers made."""
+        them where the host could not (``d_src``)."""
         prev_nxt, prev_sampled = self._no_tokens
         if prev is not None:
             prev_nxt = prev.nxt
             if prev.c_sampled is not None:
                 prev_sampled = prev.c_sampled
-        step.t0 = time.perf_counter()
+        step.launch_t0 = time.perf_counter()
         fused = step.chunk_seq is not None
         packed = self._layout.pack(
             step.d_tok, step.d_pos, step.d_valid, step.d_src,
@@ -1790,12 +1814,16 @@ class Engine:
                     prev_sampled)
         if counted:
             step.counted = counted[0]
+        if prev is not None:
+            # the step before often lands while the host launches this one
+            prev.probe()
         # What the host will fetch starts for the host the moment the
         # step ends, not when the host asks: by then the copy has landed.
         for out in (step.nxt, step.acc, step.c_sampled, step.counted):
             if out is not None:
                 out.copy_to_host_async()
-        return 1
+        # it cannot have landed before it was launched
+        step.launch_t1 = step.landed_lo = time.perf_counter()
 
     def _iterate_paged(self, it: int, it_span) -> list[FinishedRequest]:
         """One call's work, one device step ahead of the host where it
@@ -1813,6 +1841,10 @@ class Engine:
                      or self.cfg.deadline_ms is not None)
         finished: list[FinishedRequest] = []
         step = self._in_flight
+        # The step in flight is looked at each time the host passes a
+        # phase's end, until it is seen landed (_DeviceStep.probe).
+        if step is not None:
+            step.probe()
         # A step in flight and the world about to change: land it, and
         # act in the next call on a state with nothing on its way.
         in_order = self._runs_in_order(deadlines)
@@ -1844,6 +1876,8 @@ class Engine:
             blocked_t0 = (time.perf_counter() if len(self.queue) > 0
                           else None)
             live = self.scheduler.num_active
+        if step is not None:
+            step.probe()
 
         launches: list[_DeviceStep] = []
         prev, successor = step, None
@@ -1864,6 +1898,8 @@ class Engine:
                     trace_lib.record(
                         "serve.draft", asm_span.t0, time.perf_counter(),
                         tokens=step.drafted, slots=len(step.decoding))
+            if prev is not None:
+                prev.probe()
         # `program` and the step's counters are those of the step whose
         # tokens this call delivers; `ahead` says whether its successor
         # was launched before they were fetched.
@@ -1878,12 +1914,12 @@ class Engine:
             with span("serve.device_step",
                       program=step.program) as dev_span:
                 if launches:
-                    with span("serve.dispatch", uploads=0) as disp_span:
+                    with span("serve.dispatch"):
                         for q in launches:
-                            disp_span.attrs["uploads"] += \
-                                self._launch(q, prev)
+                            self._launch(q, prev)
                             prev = q
                 with span("serve.token_wait"):
+                    step.land()
                     # graftlint: disable=hot-path-transfer -- the iteration's one wait for the device: this step's tokens must land (docs/SERVING.md); its successor is already queued behind it
                     toks = np.asarray(step.nxt)
                     # graftlint: disable=hot-path-transfer -- per-slot accept lengths ride the same fetch
@@ -1893,6 +1929,13 @@ class Engine:
                         counts = np.asarray(step.counted)
                         it_span.attrs.update(zip(self._step_counters,
                                                  map(int, counts)))
+            it_span.attrs.update(
+                launch_t0=step.launch_t0, launch_t1=step.launch_t1,
+                landed_lo=step.landed_lo, landed_hi=step.landed_hi)
+            if successor is not None:
+                # the device runs its steps in order
+                successor.landed_lo = max(successor.landed_lo,
+                                          step.landed_lo)
             self._in_flight = successor
             # The tokens' landing time: every ledger stamp, TTFT and
             # deadline below reads this one clock value.
@@ -1903,9 +1946,14 @@ class Engine:
                     self.telemetry.on_admission_blocked(t - blocked_t0)
                 finished.extend(self.scheduler.evict_finished(
                     eos, now=t if deadlines else None))
+            if successor is not None:
+                successor.probe()
 
         with span("serve.finish"):
-            return self._finish_iteration(it, had_work, finished)
+            done = self._finish_iteration(it, had_work, finished)
+        if self._in_flight is not None:
+            self._in_flight.probe()
+        return done
 
     def _commit_step(self, step: _DeviceStep, toks, accepts,
                      t: float) -> None:
@@ -1942,7 +1990,7 @@ class Engine:
                 # drafts; the per-slot accept marks land in
                 # _apply_accepts.
                 trace_lib.record(
-                    "serve.verify", step.t0, t,
+                    "serve.verify", step.launch_t0, t,
                     drafted=step.drafted, accepted=accepted)
         self.telemetry.on_decode(lanes=len(decoding), tokens=emitted)
         self.telemetry.on_tokens(emitted, t)
@@ -1973,7 +2021,7 @@ class Engine:
                 led.stamp(CAUSE_RECOMPUTE if rec * 2 >= c
                           else CAUSE_PREFILL, t)
             trace_lib.record(
-                "serve.prefill_chunk", step.t0, t,
+                "serve.prefill_chunk", step.launch_t0, t,
                 key=chunk_seq.request.uid,
                 track=f"slot {chunk_seq.slot}",
                 trace=chunk_seq.request.trace_id,

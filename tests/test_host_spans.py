@@ -3,6 +3,7 @@ spanned): the ring, the nesting, the forwarding to a TraceSession, and
 the names the engine and the trainer put on the profiler's clock."""
 
 import collections
+import gc
 import sys
 import threading
 import time
@@ -214,6 +215,55 @@ class TestRing:
         calls = [s for s in trace_lib.host_spans() if s.name == "deco.call"]
         assert len(calls) == 2 and calls[0].id != calls[1].id
         assert calls[0].t1 <= calls[1].t0
+
+
+class TestPauses:
+    """``proc.gc`` and ``proc.compile``: the process's own pauses, in the
+    ring under the key of the span open on their thread."""
+
+    def test_a_full_collection_is_a_record_under_the_open_span(self):
+        tr = TraceSession()
+        with trace_lib.span("unit.gc", key="gc-key", session=tr) as outer:
+            gc.collect()
+        recs = [s for s in trace_lib.host_spans()
+                if s.name == "proc.gc" and s.parent == outer.id]
+        assert len(recs) == 1
+        rec = recs[0]
+        assert rec.key == "gc-key" and rec.attrs["generation"] == 2
+        assert rec.attrs["collected"] >= 0
+        assert outer.t0 <= rec.t0 <= rec.t1 <= outer.t1
+        # forwarded to the session like any span of the iteration
+        assert [e["args"] for e in tr.to_json()["traceEvents"]
+                if e["name"] == "proc.gc"] == [
+            {**rec.attrs, "key": "gc-key"}]
+
+    def test_a_collection_under_the_ring_lock_does_not_deadlock(self):
+        """The collector may start where this thread holds the ring's
+        lock; its record waits outside the ring for the next read."""
+        done = threading.Event()
+
+        def collect():
+            with trace_lib._ring_lock:
+                gc.collect()
+            done.set()
+
+        th = threading.Thread(target=collect, name="gc-under-lock",
+                              daemon=True)
+        th.start()
+        th.join(timeout=60)
+        assert not th.is_alive() and done.is_set()
+        assert any(s.name == "proc.gc" and s.thread == "gc-under-lock"
+                   for s in trace_lib.host_spans())
+
+    def test_a_compile_is_a_record_under_the_open_span(self):
+        fresh = jax.jit(lambda x: x * 7.0 - 3.0)
+        with trace_lib.span("unit.compile", key="c-key") as outer:
+            fresh(np.arange(5.0))
+        recs = [s for s in trace_lib.host_spans()
+                if s.name == "proc.compile" and s.parent == outer.id]
+        assert recs and all(r.key == "c-key" for r in recs)
+        assert all(outer.t0 <= r.t0 < r.t1 <= outer.t1 for r in recs)
+        assert all(r.attrs["fun_name"] for r in recs)
 
 
 @pytest.fixture(scope="module")

@@ -10,8 +10,8 @@ Pinned here on the CPU:
 1. **No field changes value on the way**: key words with their top bit set,
    pad ids, all-invalid rows, a ``_SRC_CHUNK + row`` source, the chunk's
    slot at either end.
-2. **One transfer a launch**, and ``serve.dispatch``'s ``uploads`` counts
-   the transfers really made.
+2. **One transfer a launch**, and one ``launch_t0`` stamp inside
+   ``serve.dispatch`` for each launch really made.
 3. **The step in flight reads a buffer the host never writes again**: a
    seat or a page crossing after the launch does not reach it (the CPU
    backend may alias host memory, so it would show here).
@@ -119,7 +119,7 @@ def test_the_engines_layout_follows_its_shapes(lm):
 
 # -- 2. one transfer a launch -------------------------------------------------
 @pytest.mark.parametrize("spec_k", [0, 2])
-def test_uploads_counts_the_transfers_of_each_call(lm, prompts, spec_k):
+def test_one_launch_stamp_for_each_launch_of_a_call(lm, prompts, spec_k):
     """A launch takes the parameters, the pool and the step before's own
     outputs as they lie on the device, and ONE array from the host."""
     eng = make(lm, spec_k=spec_k, temperature=0.0)
@@ -145,13 +145,20 @@ def test_uploads_counts_the_transfers_of_each_call(lm, prompts, spec_k):
         before = len(launched)
         t0 = time.perf_counter()
         eng.step()
-        spans = [s for s in trace_lib.host_spans(t0, time.perf_counter())
-                 if s.name == "serve.dispatch"]
+        ring = trace_lib.host_spans(t0, time.perf_counter())
+        spans = [s for s in ring if s.name == "serve.dispatch"]
         made = launched[before:]
-        # no launch, no span; else one span whose counter is the launches'
+        # no launch, no span; else one span holding a launch stamp of each:
+        # of the step delivered, where this call launched it, and of the
+        # step left in flight
         assert len(spans) == bool(made)
         if made:
-            assert spans[0].attrs["uploads"] == len(made)
+            it = next(s for s in ring if s.name == "serve.iteration")
+            stamps = [it.attrs["launch_t0"]]
+            if eng._in_flight is not None:
+                stamps.append(eng._in_flight.launch_t0)
+            assert sum(spans[0].t0 <= t <= spans[0].t1
+                       for t in stamps) == len(made)
         per_call.append(tuple(made))
     kinds = {name for call in per_call for name in call}
     assert kinds == {"decode", "fused"}
