@@ -777,6 +777,9 @@ class DeepseekV32LM(nn.Module):
 
     # HeldExpertsMlp sows them
     step_counters = ("expert_rows", "expert_rows_max", "experts_hit")
+    # The engine keeps the per-leaf tree: a launch is hidden under a
+    # 15-20 ms device step, so fewer buffers would gain nothing.
+    stacked_blocks = None
 
     def paged_lane(self, t_in: int, page_size: int | None = None,
                    kv_dtype: str | None = None) -> str:

@@ -308,6 +308,9 @@ class TransformerLM(nn.Module):
     # What the serving engine asks a model about itself (serving/engine.py).
     # No step counters: nothing here sows into a ``counters`` collection.
     step_counters = ()
+    # The blocks ``block0`` … repeat: the engine may hand their weights to
+    # its step programs stacked over the layers (serving/stacked.py).
+    stacked_blocks = "block"
 
     def paged_lane(self, t_in: int, page_size: int,
                    kv_dtype: str | None) -> str:

@@ -370,6 +370,8 @@ class KeyeVL2LM(nn.Module):
 
     # HeldExpertsMlp sows them
     step_counters = ("expert_rows", "expert_rows_max", "experts_hit")
+    # Per-leaf weights, as DeepseekV32LM's and for its reasons.
+    stacked_blocks = None
 
     def paged_lane(self, t_in: int, page_size: int | None = None,
                    kv_dtype: str | None = None) -> str:

@@ -158,6 +158,11 @@ from distributed_training_tpu.serving.speculative import (
     make_drafter,
     truncate_at_eos,
 )
+from distributed_training_tpu.serving.stacked import (
+    StackedParams,
+    per_layer,
+    stack_plan,
+)
 from distributed_training_tpu.serving.timeseries import (
     TIMESERIES_DUMP_SAMPLES,
     TelemetryRing,
@@ -169,6 +174,14 @@ from distributed_training_tpu.serving.timeseries import (
 # ``nxt[slot, 0]``; or, for a slot whose final chunk was that step, its
 # ``c_sampled[row]`` as ``_SRC_CHUNK + row``.
 _SRC_HOST, _SRC_NXT, _SRC_CHUNK = 0, 1, 2
+
+
+def _abstract(params: Any) -> Any:
+    """``params`` with each array as its shape and dtype: what a swap
+    candidate is compared by."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a)),
+        params)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -323,10 +336,15 @@ class Engine:
     the attention formulation a paged call that wide takes),
     ``attended_rows(live)`` (how many of a slot's live rows one query
     reads), ``index_rows_scored(live, budget)`` (how many index keys a
-    decoding slot's lane scores to select them; 0 without an indexer) and
+    decoding slot's lane scores to select them; 0 without an indexer),
     ``step_counters`` (names of int32 scalars it sows into a
-    ``counters`` collection each step: docs/SERVING.md "What a model
-    tells the engine"). :class:`~distributed_training_tpu.models.gpt.
+    ``counters`` collection each step) and ``stacked_blocks`` (the name
+    prefix of blocks whose weights the engine may stack over the layers,
+    or None: docs/SERVING.md "What a model tells the engine"). Where a
+    model stacks, ``engine.params`` is the stacked tree
+    (:class:`~distributed_training_tpu.serving.stacked.StackedParams`;
+    ``per_layer`` gives the model's own).
+    :class:`~distributed_training_tpu.models.gpt.
     TransformerLM`, :class:`~distributed_training_tpu.models.
     deepseek_v32.DeepseekV32LM` and :class:`~distributed_training_tpu.
     models.keye_vl2.KeyeVL2LM` are the three.
@@ -374,10 +392,8 @@ class Engine:
         # The fp32 abstract tree is pinned BEFORE quantization: hot-swap
         # candidates arrive from checkpoints as fp32 trees and
         # validate_swap must recognize them as armable (arm quantizes).
-        self._fp32_params_abstract = (jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(jnp.shape(a),
-                                           jnp.result_type(a)), params)
-            if self._quantize_weights else None)
+        self._fp32_params_abstract = (_abstract(params)
+                                      if self._quantize_weights else None)
         if self._quantize_weights:
             from distributed_training_tpu.serving.quantize import (
                 quantize_params,
@@ -388,7 +404,16 @@ class Engine:
             params = quantize_params(params)
             self._weight_quant_s = time.perf_counter() - t0_q
             self._quantized_params_bytes = quantized_param_bytes(params)
-        self.params = params
+        # A model whose blocks repeat says so (``stacked_blocks``, the
+        # prefix of their names): its blocks' gains and biases then go to
+        # the step programs stacked over the layers, one buffer per kind of
+        # leaf, so a launch hands over fewer buffers (serving/stacked.py;
+        # docs/SERVING.md "The stacked launch"), and the engine holds the
+        # stacked tree alone.
+        self._stack_plan = (stack_plan(params, model.stacked_blocks)
+                            if model.stacked_blocks else None)
+        self.params = (self._stack_plan.stack(params)
+                       if self._stack_plan is not None else params)
         # Speculative decoding (serving/speculative.py): the decode step
         # becomes a [max_batch, spec_k + 1] verify window — spec_k drafts
         # per slot verified alongside the incoming token in one dispatch,
@@ -400,7 +425,7 @@ class Engine:
             raise ValueError(
                 "a drafter requires spec_k >= 1 (speculation is off)")
         self.drafter = (drafter if drafter is not None
-                        else make_drafter(cfg, model, params)
+                        else make_drafter(cfg, model, self.params)
                         ) if self.spec_k else None
         # Live weight hot-swap state (serving/hotswap.py). The engine
         # serves exactly one params version at a time; a staged
@@ -409,11 +434,13 @@ class Engine:
         # already holds its params argument). The abstract tree pinned
         # here at construction is the validation oracle every candidate
         # must match: same structure, shapes, dtypes ⇒ the compiled
-        # programs accept the new tree without a retrace.
+        # programs accept the new tree without a retrace. A stacking
+        # engine takes a candidate in either form: the per-leaf tree (a
+        # checkpoint's) or the stacked one it serves (rollback's, or
+        # ``engine.params`` re-armed).
         self.weights_epoch = int(weights_epoch)
-        self._params_abstract = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(jnp.shape(a),
-                                           jnp.result_type(a)), params)
+        self._params_abstract = _abstract(params)
+        self._served_abstract = _abstract(self.params)
         self._swap_lock = threading.Lock()
         self._pending_swap: tuple[Any, int] | None = None
         # Rollback insurance: the previously served tree survives one
@@ -641,6 +668,12 @@ class Engine:
             self._no_tokens = (
                 jnp.zeros((s, self.spec_width), jnp.int32),
                 jnp.zeros((self.prefill_chunk,), jnp.int32))
+            # The input buffers a decode launch hands the runtime:
+            # parameters, pools, the packed inputs and the two outputs of
+            # the step before.
+            build_span.attrs["launch_buffers"] = len(
+                jax.tree_util.tree_leaves(
+                    (self.params, self._cache, self._no_tokens))) + 1
 
         # Quantization gauges ride the telemetry from birth:
         # kv_bytes_per_token is measured off the REAL device cache tree
@@ -773,8 +806,10 @@ class Engine:
         disjoint pages (the chunk's slot is not decoding), so their
         order is arithmetic-free. ``packed`` is the host's whole hand-over
         (:class:`_StepLayout`); the last two arguments are the step
-        before's own outputs (:meth:`_decode_step`)."""
+        before's own outputs (:meth:`_decode_step`). Stacked block weights
+        are indexed back into the model's tree once, for both lanes."""
         with jax.named_scope("serve.fused"):
+            params = per_layer(params)
             lane, chunk = self._layout.unpack(packed)
             cache, c_sampled, c_counted = self._chunk_step(
                 params, cache, *chunk)
@@ -789,6 +824,7 @@ class Engine:
         """Iterations with no prefill pending skip the chunk lane's
         compute entirely (the second compiled program)."""
         with jax.named_scope("serve.decode"):
+            params = per_layer(params)
             lane, _ = self._layout.unpack(packed)
             *out, counted = self._decode_step(
                 params, cache, *lane, prev_nxt, prev_sampled)
@@ -1450,11 +1486,12 @@ class Engine:
         re-arms — already int8+scales) and the fp32 restore tree (what
         the hot-swap watcher stages from checkpoints — :meth:`arm_swap`
         quantizes it). Anything else is the same hard mismatch as
-        always."""
-        candidate = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(jnp.shape(a),
-                                           jnp.result_type(a)), params)
-        if candidate == self._params_abstract:
+        always. A stacking engine also accepts the stacked form it
+        serves (:class:`~distributed_training_tpu.serving.stacked.
+        StackedParams` of its own plan)."""
+        candidate = _abstract(params)
+        if (candidate == self._params_abstract
+                or candidate == self._served_abstract):
             return
         if (self._fp32_params_abstract is not None
                 and candidate == self._fp32_params_abstract):
@@ -1487,7 +1524,11 @@ class Engine:
         restore/verify staging, never on the serving thread — and the
         wall time is billed to ``weight_quant_s``. An already-quantized
         candidate (rollback's re-arm of the previous tree) stages
-        as-is."""
+        as-is.
+
+        A stacking engine stacks a per-leaf candidate HERE too, on the
+        caller's thread and waited for, so the barrier only swaps a
+        pointer; a stacked candidate (rollback's) stages as-is."""
         if self._quantize_weights:
             from distributed_training_tpu.serving.quantize import (
                 is_quantized,
@@ -1509,6 +1550,9 @@ class Engine:
                 self.telemetry.on_weight_quant(
                     dt_q, self._quantized_params_bytes)
         self.validate_swap(params, stage="arm", epoch=epoch)
+        if (self._stack_plan is not None
+                and not isinstance(params, StackedParams)):
+            params = jax.block_until_ready(self._stack_plan.stack(params))
         with self._swap_lock:
             self._pending_swap = (params, int(epoch))
         if self.trace is not None:

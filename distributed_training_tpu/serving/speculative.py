@@ -196,7 +196,9 @@ class GPTDrafter:
     ``mirror_target=True`` default the engine wires for
     ``spec_drafter='gpt'``) the serving model itself, window-truncated:
     self-drafting spends a cheap short-window forward per draft token
-    to win the per-dispatch overhead of the full-length verify. In
+    to win the per-dispatch overhead of the full-length verify; it then
+    holds the engine's own tree, stacked where the engine stacks
+    (serving/stacked.py), and the draft program indexes it back. In
     mirror mode :meth:`on_weights_swap` re-points the params snapshot
     at the engine's freshly swapped tree inside the swap barrier, so a
     mid-speculation deploy leaves no stale-drafter window (pinned by
@@ -226,6 +228,10 @@ class GPTDrafter:
         import jax
         import jax.numpy as jnp
         from jax import lax
+
+        from distributed_training_tpu.serving.stacked import per_layer
+
+        params = per_layer(params)
 
         def step(win, _):
             logits = self.model.apply({"params": params}, win[None],

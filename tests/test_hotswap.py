@@ -325,6 +325,49 @@ class TestRefusalSafety:
             np.testing.assert_array_equal(done[i].tokens, base[i].tokens)
         assert eng.stats()["swaps_completed"] == 2  # swap + rollback
 
+    def test_stacked_engine_swaps_to_new_weights_then_rolls_back(
+            self, lm, prompts):
+        """A GPT engine serves its small block leaves stacked over the layers
+        (serving/stacked.py): a per-leaf candidate is stacked at arm, the
+        barrier serves it — tokens of an engine built on the new weights —
+        and rollback re-arms the stacked predecessor as it is, serving the
+        first weights' tokens again. Re-arming ``engine.params`` (the
+        front door's rolling deploy) takes the stacked form too."""
+        from distributed_training_tpu.serving.stacked import (
+            StackedParams,
+            per_layer,
+        )
+
+        model, p1, p2 = lm
+        _, base = _run(model, p1, prompts[:2])
+        _, new = _run(model, p2, prompts[:2])
+        eng = Engine(model, p1, ServeConfig(max_batch=2,
+                                            max_new_tokens=N_NEW))
+        served = eng.params
+        assert isinstance(served, StackedParams)
+        eng.arm_swap(p2, epoch=1)
+        assert isinstance(eng._pending_swap[0], StackedParams)
+        for p in prompts[:2]:
+            eng.submit(p)
+        done = {f.uid: f for f in eng.run()}
+        assert eng.weights_epoch == 1 and eng._prev_params is served
+        for uid in range(2):
+            np.testing.assert_array_equal(done[uid].tokens, new[uid].tokens)
+        assert eng.rollback() == -1
+        for p in prompts[:2]:
+            eng.submit(p)
+        done = {f.uid - 2: f for f in eng.run()}
+        assert eng.weights_epoch == -1 and eng.params is served
+        for uid in range(2):
+            np.testing.assert_array_equal(done[uid].tokens, base[uid].tokens)
+        for a, b in zip(jax.tree.leaves(per_layer(eng.params)),
+                        jax.tree.leaves(p1)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        eng.arm_swap(eng.params, epoch=2)
+        eng.step()
+        assert eng.weights_epoch == 2 and eng.params is served
+        assert eng.stats()["swaps_completed"] == 3
+
     def test_swap_error_typing(self):
         err = SwapError("boom", stage="verify", epoch=3)
         assert isinstance(err, RuntimeError)

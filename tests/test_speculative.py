@@ -354,7 +354,15 @@ class TestHotSwapMidSpeculation:
         assert len(done) == 1 and done[0].tokens.size == N_NEW
         assert eng.weights_epoch == 1
         assert eng.drafter.params is eng.params
-        assert eng.params is params2
+        # the engine serves params2 stacked over the layers
+        # (serving/stacked.py); the drafter indexes the same tree
+        from distributed_training_tpu.serving.stacked import per_layer
+
+        served = per_layer(eng.params)
+        assert (jax.tree.structure(served)
+                == jax.tree.structure(params2))
+        for a, b in zip(jax.tree.leaves(served), jax.tree.leaves(params2)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         eng.pool.check_balanced()
 
 
