@@ -57,12 +57,18 @@ def _keye_vl2(**kw):
     return make_keye_vl2(**kw)
 
 
+def _ling_hybrid(**kw):
+    from distributed_training_tpu.models.ling_hybrid import make_ling_hybrid
+    return make_ling_hybrid(**kw)
+
+
 _REGISTRY["vit_b16"] = _vit
 _REGISTRY["moe_mlp"] = _moe
 _REGISTRY["transformer_lm"] = _lm
 _REGISTRY["deepseek_v32"] = _deepseek_v32
 _REGISTRY["sarvam_mla"] = _sarvam_mla
 _REGISTRY["keye_vl2"] = _keye_vl2
+_REGISTRY["ling_hybrid"] = _ling_hybrid
 
 
 def available_models() -> list[str]:

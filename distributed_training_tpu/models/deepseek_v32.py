@@ -93,6 +93,23 @@ KERNEL_LANE = "masked-blocks-kernel"   # masked-blocks, a block in the kernel
 DENSE_KERNEL_LANE = "dense-latent-kernel"   # live rows read where they lie
 
 
+def lane_name(model, t_in: int, sparse: bool, page_size: int | None) -> str:
+    """What a latent model's ``paged_lane`` answers: :func:`paged_lane`'s
+    name, with ``-kernel`` where the kernel serves the shapes. ``model``
+    gives the widths (``num_heads``, ``kv_rank``, ``nope_dim``,
+    ``rope_dim``, ``v_dim``, ``key_block``, ``dtype``)."""
+    lane = paged_lane(t_in, sparse)
+    if lane == "masked-blocks" and masked_attention.kernel_fits(
+            1, t_in, model.key_block, model.nope_dim, model.rope_dim,
+            model.v_dim, model.dtype):
+        return KERNEL_LANE
+    if lane == "dense-latent-gather" and dense_kernel_fits(
+            t_in, model.num_heads, model.kv_rank, model.rope_dim, page_size,
+            model.dtype):
+        return DENSE_KERNEL_LANE
+    return lane
+
+
 def dense_kernel_fits(t_in: int, num_heads: int, kv_rank: int, rope_dim: int,
                       page_size: int | None, dtype) -> bool:
     """Whether a dense window ``t_in`` rows wide reads its slot's live rows
@@ -397,7 +414,9 @@ class RMSNorm(nn.Module):
 class SparseLatentAttention(nn.Module):
     """MLA, with the indexer's selection where ``index_topk`` is set and
     dense over every earlier key where it is None; with a query latent
-    where ``q_rank`` is set; see the module docstring."""
+    where ``q_rank`` is set; with a head-wise output gate where
+    ``head_gate`` is set (``models/ling_hybrid.py``'s); see the module
+    docstring."""
 
     num_heads: int
     q_rank: int | None
@@ -415,6 +434,8 @@ class SparseLatentAttention(nn.Module):
     dtype: Any = jnp.float32
     kv_page_size: int | None = None
     kv_pages: int | None = None
+    # a head-wise output gate: each head's output times sigmoid(x . w_h)
+    head_gate: bool = False
 
     def _frequencies(self):
         base, factor, original, fast, slow, _ = self.rope
@@ -508,6 +529,11 @@ class SparseLatentAttention(nn.Module):
         else:
             out = self._paged(q_nope, q_rope, index, latent, wkv_b, scale,
                               pages)
+        if self.head_gate:
+            w_gate = self.param("gate", init, (d, h))
+            out = out * jax.nn.sigmoid(jnp.dot(
+                x, w_gate.astype(dt), preferred_element_type=jnp.float32)
+            )[..., None]
         return jnp.einsum("bthv,hvd->btd", out.astype(dt), wo)
 
     # -- where the keys come from --------------------------------------------
@@ -780,22 +806,15 @@ class DeepseekV32LM(nn.Module):
     # The engine keeps the per-leaf tree: a launch is hidden under a
     # 15-20 ms device step, so fewer buffers would gain nothing.
     stacked_blocks = None
+    # Every cache leaf is a paged pool: no per-slot state.
+    slot_state = ()
 
     def paged_lane(self, t_in: int, page_size: int | None = None,
                    kv_dtype: str | None = None) -> str:
         """The attention formulation a paged call ``t_in`` rows wide takes
         (the width alone decides; pools are in the compute dtype)."""
         del kv_dtype
-        lane = paged_lane(t_in, self.index_topk is not None)
-        if lane == "masked-blocks" and masked_attention.kernel_fits(
-                1, t_in, self.key_block, self.nope_dim, self.rope_dim,
-                self.v_dim, self.dtype):
-            return KERNEL_LANE
-        if lane == "dense-latent-gather" and dense_kernel_fits(
-                t_in, self.num_heads, self.kv_rank, self.rope_dim, page_size,
-                self.dtype):
-            return DENSE_KERNEL_LANE
-        return lane
+        return lane_name(self, t_in, self.index_topk is not None, page_size)
 
     def attended_rows(self, live: int) -> int:
         """Of ``live`` cached rows, how many one query attends."""

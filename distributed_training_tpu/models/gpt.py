@@ -311,6 +311,8 @@ class TransformerLM(nn.Module):
     # The blocks ``block0`` … repeat: the engine may hand their weights to
     # its step programs stacked over the layers (serving/stacked.py).
     stacked_blocks = "block"
+    # Every cache leaf is a paged pool: no per-slot state.
+    slot_state = ()
 
     def paged_lane(self, t_in: int, page_size: int,
                    kv_dtype: str | None) -> str:

@@ -372,6 +372,7 @@ class KeyeVL2LM(nn.Module):
     step_counters = ("expert_rows", "expert_rows_max", "experts_hit")
     # Per-leaf weights, as DeepseekV32LM's and for its reasons.
     stacked_blocks = None
+    slot_state = ()
 
     def paged_lane(self, t_in: int, page_size: int | None = None,
                    kv_dtype: str | None = None) -> str:

@@ -176,6 +176,21 @@ from distributed_training_tpu.serving.timeseries import (
 _SRC_HOST, _SRC_NXT, _SRC_CHUNK = 0, 1, 2
 
 
+class RecurrentStateError(ValueError):
+    """A ``ServeConfig`` asks of a model with per-slot recurrent state
+    (``slot_state``) what that state cannot give: a state is not rebuilt
+    from a page chain, so a prefix-cache hit has nothing to resume from,
+    and it is not rolled back, so a rejected draft would stay in it."""
+
+
+def _slabs(cache: Any, names: tuple) -> tuple[list, list]:
+    """The cache's leaves as ``(paged pools, per-slot slabs)``: a slab is a
+    leaf whose name the model lists in ``slot_state``."""
+    flat = flax.traverse_util.flatten_dict(flax.core.unfreeze(cache))
+    pools = [v for k, v in flat.items() if k[-1] not in names]
+    return pools, [v for k, v in flat.items() if k[-1] in names]
+
+
 def _abstract(params: Any) -> Any:
     """``params`` with each array as its shape and dtype: what a swap
     candidate is compared by."""
@@ -241,9 +256,9 @@ class _StepLayout:
     def unpack(self, packed):
         """``(tok, pos, valid, rngs, tables, src), chunk`` as
         ``Engine._decode_step`` and ``_chunk_step`` take them: ``chunk``
-        is ``(c_tok, c_pos, c_valid, c_table, c_rng)`` for a buffer with
-        the fused tail and None without. Static slices, but for the
-        chunk's slot."""
+        is ``(c_tok, c_pos, c_valid, c_table, c_rng, c_slot)`` for a
+        buffer with the fused tail and None without. Static slices, but
+        for the chunk's slot."""
         n = self.slots * self.lane
         if packed.shape not in ((self.size(False),), (self.size(True),)):
             raise ValueError(
@@ -259,7 +274,7 @@ class _StepLayout:
         c_tok, c_pos, c_valid = packed[n:-1].reshape(3, self.chunk)
         slot = packed[-1]
         return lane, (c_tok, c_pos, c_valid != 0, tables[slot][None],
-                      rngs[slot])
+                      rngs[slot], slot)
 
 
 @dataclasses.dataclass
@@ -338,16 +353,20 @@ class Engine:
     reads), ``index_rows_scored(live, budget)`` (how many index keys a
     decoding slot's lane scores to select them; 0 without an indexer),
     ``step_counters`` (names of int32 scalars it sows into a
-    ``counters`` collection each step) and ``stacked_blocks`` (the name
+    ``counters`` collection each step), ``stacked_blocks`` (the name
     prefix of blocks whose weights the engine may stack over the layers,
-    or None: docs/SERVING.md "What a model tells the engine"). Where a
+    or None) and ``slot_state`` (the names of its cache leaves that are
+    per-slot slabs of recurrent state, or none: such a model is cloned
+    with ``state_slots`` and the chunk lane's call is told its ``slots``;
+    docs/SERVING.md "What a model tells the engine"). Where a
     model stacks, ``engine.params`` is the stacked tree
     (:class:`~distributed_training_tpu.serving.stacked.StackedParams`;
     ``per_layer`` gives the model's own).
     :class:`~distributed_training_tpu.models.gpt.
     TransformerLM`, :class:`~distributed_training_tpu.models.
-    deepseek_v32.DeepseekV32LM` and :class:`~distributed_training_tpu.
-    models.keye_vl2.KeyeVL2LM` are the three.
+    deepseek_v32.DeepseekV32LM`, :class:`~distributed_training_tpu.
+    models.keye_vl2.KeyeVL2LM` and :class:`~distributed_training_tpu.
+    models.ling_hybrid.LingHybridLM` are the four.
 
     >>> eng = Engine(model, params, ServeConfig(max_batch=8))
     >>> eng.submit(prompt_tokens)
@@ -373,6 +392,19 @@ class Engine:
     def __init__(self, model: Any, params: Any, cfg: ServeConfig, *,
                  trace=None, weights_epoch: int = -1, drafter=None):
         check_unsharded(model)
+        # Per-slot recurrent state (docs/SERVING.md "What a model tells the
+        # engine"): slabs beside the paged pools, which neither a prefix
+        # hit nor a rejected draft can be squared with.
+        self._slot_state = tuple(model.slot_state)
+        if self._slot_state and cfg.prefix_cache:
+            raise RecurrentStateError(
+                "the prefix cache cannot serve a model with per-slot "
+                "recurrent state: a state is not rebuilt from cached pages")
+        if self._slot_state and (cfg.spec_k or drafter is not None):
+            raise RecurrentStateError(
+                "speculative decoding cannot serve a model with per-slot "
+                "recurrent state: a rejected draft cannot be rolled back "
+                "out of it")
         self.cfg = cfg
         self.trace = trace
         self.budget = cache_budget(model, cfg.max_len)
@@ -474,7 +506,9 @@ class Engine:
         self.model = model.clone(cache_len=self.budget,
                                  kv_page_size=ps,
                                  kv_pages=self.pool_pages + 1,
-                                 kv_dtype=cfg.kv_dtype)
+                                 kv_dtype=cfg.kv_dtype,
+                                 **({"state_slots": s} if self._slot_state
+                                    else {}))
         # A chunk wider than the longest admissible prompt is pure
         # padding compute.
         self.prefill_chunk = min(int(cfg.prefill_chunk),
@@ -617,9 +651,13 @@ class Engine:
         # allocation and slot membership never touch compiled code,
         # and how much of each slot's table is live is data the
         # decode lane's attention kernel reads, not a shape.
-        with trace_lib.span("setup.cache_alloc"):
+        with trace_lib.span("setup.cache_alloc") as alloc_span:
             self._cache = init_decode_cache(self.model, params,
                                             batch_size=1)
+            if self._slot_state:
+                alloc_span.attrs["state_bytes"] = sum(
+                    int(leaf.nbytes) for leaf in
+                    _slabs(self._cache, self._slot_state)[1])
         self._tables = np.zeros((s, self.pages_per_slot), np.int32)
         self._slot_rng = np.zeros(
             (s,) + self._base_rng.shape,
@@ -651,8 +689,8 @@ class Engine:
             # time, by the writer's own test of the shapes and dtypes.
             self.chunk_write = paged_write_form(
                 self.prefill_chunk, ps,
-                {leaf.dtype for leaf in jax.tree_util.tree_leaves(
-                    self._cache)})
+                {leaf.dtype for leaf in
+                 _slabs(self._cache, self._slot_state)[0]})
             build_span.attrs.update(
                 decode_lane=self.lane_formulation["decode"],
                 chunk_lane=self.lane_formulation["chunk"],
@@ -690,7 +728,7 @@ class Engine:
         from the actual cache pytree and divided by the physical pool
         rows (so int8 pages + their fp32 scale planes both count)."""
         total = sum(int(leaf.nbytes)
-                    for leaf in jax.tree_util.tree_leaves(self._cache))
+                    for leaf in _slabs(self._cache, self._slot_state)[0])
         return total / ((self.pool_pages + 1) * self.page_size)
 
     # -- compiled pieces ----------------------------------------------------
@@ -774,7 +812,8 @@ class Engine:
         return jnp.argmax(jnp.concatenate([mismatch, sentinel], axis=1),
                           axis=1).astype(jnp.int32)
 
-    def _chunk_step(self, params, cache, toks, pos, valid, table, rng):
+    def _chunk_step(self, params, cache, toks, pos, valid, table, rng,
+                    slot):
         """One prefill chunk ``[1, C]`` for the oldest prefilling slot.
 
         Writes the chunk's K/V through the slot's page table (padding
@@ -782,14 +821,16 @@ class Engine:
         with ``fold_in(request_rng, position)`` — the host keeps row
         ``true_len-1-start`` as the request's first token when this
         chunk is final, making its RNG and logits row identical to the
-        full-prompt prefill's.
+        full-prompt prefill's. ``slot`` says, to a model with per-slot
+        state, whose slabs the chunk carries on.
         """
         pages = PagedKV(table=table, positions=pos[None],
                         valid=valid[None])
+        slots = {"slots": slot[None]} if self._slot_state else {}
         logits, vars_out = self.model.apply(
             {"params": params, "cache": cache}, toks[None],
             positions=pos[None], train=False, decode=True,
-            mutable=self._mutable, pages=pages)
+            mutable=self._mutable, pages=pages, **slots)
 
         def row(pos_s, lg):
             return sample_token(jax.random.fold_in(rng, pos_s),
@@ -1822,7 +1863,10 @@ class Engine:
                                          rows_live)),
                 index_rows_scored=sum(
                     self.model.index_rows_scored(r, self._l_all)
-                    for r in rows_live)))
+                    for r in rows_live),
+                # the slots whose recurrent state the decode lane carries
+                **({"state_slots": len(decoding)} if self._slot_state
+                   else {})))
 
     def _launch(self, step: _DeviceStep, prev: _DeviceStep | None) -> None:
         """Hand ``step``'s inputs over as one packed buffer

@@ -19,7 +19,10 @@ TPU in Pallas interpret mode.
 5. **For the chip**: the kernel compiles at GPT-2-large's widths for a
    described v5e (no chip needed; skipped where it cannot be described),
    and its shared-row form at sarvam-105b's (the form itself against the
-   gather lane: tests/test_sarvam_mla.py).
+   gather lane: tests/test_sarvam_mla.py); the KDA decode kernel
+   (``ops/kda_decode.py``) at Ling-3.0-flash's (against its one-step
+   form: tests/test_ling_hybrid.py). All compiles for the chip live in
+   this one file.
 """
 
 import time
@@ -532,3 +535,30 @@ def test_the_pool_write_compiles_for_a_v5e_at_gpt2_large_shapes(one_chip,
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == rows * width * 2      # in place
     assert mem.temp_size_in_bytes < rows * width * 2 // 64
+
+
+def test_the_kda_decode_kernel_compiles_for_a_v5e_at_ling_widths(one_chip):
+    """``kda_decode`` at the long-generation cell's shapes: 128 decoding
+    slots, 32 heads of 128 x 128 float32 state a slot. The state is
+    updated in place (aliased, donated): nothing of its size is made."""
+    from distributed_training_tpu.ops.kda_decode import kda_decode
+
+    slots, heads, dim = 128, 32, 128
+
+    def shape(s, d=jnp.float32):
+        return jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+
+    def call(q, k, v, g, beta, state, rows, live):
+        return kda_decode(q, k, v, g, beta, state, rows, live,
+                          interpret=False)
+
+    vec = shape((slots, heads, dim))
+    state_bytes = slots * heads * dim * dim * 4
+    compiled = jax.jit(call, donate_argnums=5).lower(
+        vec, vec, vec, vec, shape((slots, heads)),
+        shape((slots, heads, dim, dim)), shape((slots,), jnp.int32),
+        shape((slots,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == state_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 64

@@ -97,7 +97,8 @@ def test_pack_then_unpack_under_jit_is_bit_for_bit(width, all_invalid,
     if chunk_slot is None:
         assert got_chunk is None
         return
-    want_chunk = (*chunk, tables[chunk_slot][None], keys[chunk_slot])
+    want_chunk = (*chunk, tables[chunk_slot][None], keys[chunk_slot],
+                  np.asarray(chunk_slot, np.int32))
     for got, want in zip(got_chunk, want_chunk, strict=True):
         assert got.dtype == want.dtype and got.shape == want.shape
         np.testing.assert_array_equal(np.asarray(got), want)
