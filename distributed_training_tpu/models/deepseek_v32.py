@@ -68,7 +68,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from distributed_training_tpu.models.moe import GatedMlp, HeldExpertsMlp
+from distributed_training_tpu.models.moe import (
+    GatedMlp,
+    HeldExpertsMlp,
+    model_expert_lane,
+)
 from distributed_training_tpu.ops import masked_attention, paged_attention
 from distributed_training_tpu.parallel.ring_attention import (
     PagedKV,
@@ -793,7 +797,6 @@ class DeepseekV32LM(nn.Module):
     max_len: int = 163840
     norm_eps: float = 1e-6
     key_block: int = 1024
-    expert_block_rows: int = 128
     dtype: Any = jnp.float32
     logits_dtype: Any = jnp.float32
     cache_len: int | None = None
@@ -815,6 +818,8 @@ class DeepseekV32LM(nn.Module):
         (the width alone decides; pools are in the compute dtype)."""
         del kv_dtype
         return lane_name(self, t_in, self.index_topk is not None, page_size)
+
+    expert_lane = model_expert_lane
 
     def attended_rows(self, live: int) -> int:
         """Of ``live`` cached rows, how many one query attends."""
@@ -860,8 +865,7 @@ class DeepseekV32LM(nn.Module):
             hidden_dim=self.expert_dim, top_k=self.experts_per_token,
             n_group=self.n_group, topk_group=self.topk_group,
             routed_scale=self.routed_scale,
-            shared_experts=self.shared_experts,
-            block_rows=self.expert_block_rows)
+            shared_experts=self.shared_experts)
         for i in range(self.num_layers):
             dense = i < self.first_dense
             x = DeepseekV32Block(

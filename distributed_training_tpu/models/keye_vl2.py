@@ -65,7 +65,10 @@ from distributed_training_tpu.models.deepseek_v32 import (
     HIGHEST, KERNEL_LANE, LANES, RMSNorm, by_page, exact_topk_mask,
     index_scores, local_key_blocks, mask_positions, paged_key_blocks,
     paged_lane, rotate_half_split, write_paged_rows)
-from distributed_training_tpu.models.moe import HeldExpertsMlp
+from distributed_training_tpu.models.moe import (
+    HeldExpertsMlp,
+    model_expert_lane,
+)
 from distributed_training_tpu.ops import masked_attention
 from distributed_training_tpu.parallel.ring_attention import PagedKV
 
@@ -360,7 +363,6 @@ class KeyeVL2LM(nn.Module):
     max_len: int = 262144
     norm_eps: float = 1e-6
     key_block: int = 1024
-    expert_block_rows: int = 128
     dtype: Any = jnp.float32
     logits_dtype: Any = jnp.float32
     cache_len: int | None = None
@@ -385,6 +387,8 @@ class KeyeVL2LM(nn.Module):
                 self.dtype):
             return KERNEL_LANE
         return lane
+
+    expert_lane = model_expert_lane
 
     def attended_rows(self, live: int) -> int:
         """Of ``live`` cached rows, how many one query attends."""
@@ -424,8 +428,7 @@ class KeyeVL2LM(nn.Module):
         moe = dict(
             num_experts=self.num_experts, held=tuple(self.held),
             hidden_dim=self.expert_dim, top_k=self.experts_per_token,
-            scoring="softmax", shared_experts=0,
-            block_rows=self.expert_block_rows)
+            scoring="softmax", shared_experts=0)
         for i in range(self.num_layers):
             x = KeyeVL2Block(attn=attn, moe=moe, norm_eps=self.norm_eps,
                              dtype=self.dtype, name=f"layer{i}")(

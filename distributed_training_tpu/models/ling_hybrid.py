@@ -63,7 +63,11 @@ from distributed_training_tpu.models.deepseek_v32 import (
     SparseLatentAttention,
     lane_name,
 )
-from distributed_training_tpu.models.moe import GatedMlp, HeldExpertsMlp
+from distributed_training_tpu.models.moe import (
+    GatedMlp,
+    HeldExpertsMlp,
+    model_expert_lane,
+)
 from distributed_training_tpu.ops import kda_decode as kda_ops
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -317,7 +321,6 @@ class LingHybridLM(nn.Module):
     max_len: int = 262144
     norm_eps: float = 1e-6
     key_block: int = 1024
-    expert_block_rows: int = 128
     dtype: Any = jnp.float32
     logits_dtype: Any = jnp.float32
     cache_len: int | None = None
@@ -338,6 +341,8 @@ class LingHybridLM(nn.Module):
         wide (the KDA layers hold no pages)."""
         del kv_dtype
         return lane_name(self, t_in, False, page_size)
+
+    expert_lane = model_expert_lane
 
     def attended_rows(self, live: int) -> int:
         """Dense latent attention: a query reads every live row."""
@@ -380,8 +385,7 @@ class LingHybridLM(nn.Module):
             hidden_dim=self.expert_dim, top_k=self.experts_per_token,
             n_group=self.n_group, topk_group=self.topk_group,
             routed_scale=self.routed_scale,
-            shared_experts=self.shared_experts,
-            block_rows=self.expert_block_rows)
+            shared_experts=self.shared_experts)
         for i, kind in enumerate(self.kinds):
             dense = i < self.first_dense
             x = LingHybridBlock(

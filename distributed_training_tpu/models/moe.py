@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from distributed_training_tpu.ops import expert_ffn
+
 AUX_LOSS_COLLECTION = "aux_loss"
 
 
@@ -302,6 +304,29 @@ def softmax_topk_route(logits, *, top_k: int):
     return experts.astype(jnp.int32), w / w.sum(-1, keepdims=True)
 
 
+def expert_lane(t: int, d: int, hidden: int, top_k: int, num_experts: int,
+                dtype) -> str:
+    """How :class:`HeldExpertsMlp` computes a pass of ``t`` tokens:
+    ``kernel`` (``ops/expert_ffn.py``). A pass whose ``x`` and sum do not
+    fit the kernel's VMEM beside its weight tiles is refused."""
+    if not expert_ffn.kernel_fits(t, d, hidden, top_k, num_experts, dtype):
+        raise ValueError(
+            f"a pass of {t} tokens of width {d} does not fit the expert "
+            f"kernel's VMEM ({expert_ffn.VMEM_BUDGET >> 20} MiB): pass fewer "
+            "tokens at a time (a smaller prefill_chunk)")
+    return "kernel"
+
+
+def model_expert_lane(model, rows: int) -> str:
+    """:func:`expert_lane` of a language model's held experts (its
+    ``hidden_dim``, ``expert_dim``, ``experts_per_token``, ``num_experts``
+    and ``dtype``) at a pass of ``rows`` tokens: the ``expert_lane``
+    method of the models that have them."""
+    return expert_lane(rows, model.hidden_dim, model.expert_dim,
+                       model.experts_per_token, model.num_experts,
+                       model.dtype)
+
+
 def _silu_ffn(x, w1, w3, w2):
     """``(SiLU(x w1) * (x w3)) w2`` in ``x``'s type."""
     h = jax.nn.silu(jnp.dot(x, w1)) * jnp.dot(x, w3)
@@ -342,13 +367,14 @@ class HeldExpertsMlp(nn.Module):
     chips or the exchange with them.
 
     Dropless at static shapes: the (token, expert) pairs that landed on a
-    held expert are sorted by expert, and one loop walks the experts' runs
-    of pairs ``block_rows`` at a time (rows gathered, multiplied by the
-    block's expert, scattered back under their routing weights): no block
-    for an expert that got none (its weights are not read), one for the
-    usual few rows, as many as it takes otherwise. One loop body whatever
-    the number of experts held, so a program's size and compile time do not
-    grow with it. No capacity, no drop.
+    held expert are sorted by expert, and the grouped SwiGLU kernel
+    ``ops/expert_ffn.py::expert_ffn`` reads each hit expert's weights once
+    and takes its rows ``row_tile`` at a time (an expert that got none is
+    not visited and its weights are not read), picking them from ``x`` and
+    adding them back under their routing weights inside the kernel.
+    ``row_tile`` is :func:`~distributed_training_tpu.ops.expert_ffn.
+    row_tile` of the pass's shape unless set; a pass too wide for the
+    kernel is refused (:func:`expert_lane`). No capacity, no drop.
 
     ``valid`` [T] masks tokens that do not exist (padding rows, empty
     decode slots): they are routed nowhere and counted nowhere. Sows, in
@@ -366,7 +392,7 @@ class HeldExpertsMlp(nn.Module):
     routed_scale: float = 1.0
     shared_experts: int = 1
     scoring: str = "sigmoid"
-    block_rows: int = 128
+    row_tile: int | None = None
     dtype: Any = jnp.float32
 
     @nn.compact
@@ -423,30 +449,14 @@ class HeldExpertsMlp(nn.Module):
                      reduce_fn=jnp.add)
 
         with jax.named_scope("moe.experts"):
-            r = min(int(self.block_rows), t)
-            pair_token = jnp.pad(order // self.top_k, (0, r))
-            pair_weight = jnp.pad(weights.reshape(-1)[order], (0, r))
             w1, w3, w2 = (w.astype(self.dtype) for w in (w1, w3, w2))
-
-            # One walk over the blocks of every held expert in turn: block
-            # ``b`` is the expert's whose span of the running block totals
-            # holds ``b`` (an expert without rows has no block, and its
-            # weights are not read).
-            blocks = (rows + r - 1) // r
-            ends = jnp.cumsum(blocks)
-
-            def block(b, acc):
-                e = (ends <= b).sum(dtype=jnp.int32)
-                i = b - (ends[e] - blocks[e])
-                at = starts[e] + i * r
-                tok = jax.lax.dynamic_slice_in_dim(pair_token, at, r)
-                wgt = jax.lax.dynamic_slice_in_dim(pair_weight, at, r)
-                wgt = jnp.where(i * r + jnp.arange(r) < rows[e], wgt, 0.0)
-                y = _silu_ffn(x[tok], w1[e], w3[e], w2[e])
-                return acc.at[tok].add(y.astype(jnp.float32) * wgt[:, None])
-
-            acc = jax.lax.fori_loop(0, ends[-1], block,
-                                    jnp.zeros((t, d), jnp.float32))
+            tile = self.row_tile or expert_ffn.row_tile(
+                t, self.top_k, self.num_experts)
+            expert_lane(t, d, self.hidden_dim, self.top_k, self.num_experts,
+                        self.dtype)
+            acc = expert_ffn.expert_ffn(
+                x, w1, w3, w2, (order // self.top_k).astype(jnp.int32),
+                weights.reshape(-1)[order], rows, starts, row_tile=tile)
             out = acc.astype(self.dtype)
             if self.shared_experts:
                 out = out + GatedMlp(

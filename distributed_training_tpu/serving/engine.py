@@ -695,6 +695,13 @@ class Engine:
                 decode_lane=self.lane_formulation["decode"],
                 chunk_lane=self.lane_formulation["chunk"],
                 chunk_write=self.chunk_write)
+            # How a model with held experts computes each pass: the
+            # decode program's rows, and the fused program's chunk.
+            if hasattr(self.model, "expert_lane"):
+                build_span.attrs["expert_lane"] = {
+                    lane: self.model.expert_lane(rows)
+                    for lane, rows in (("decode", s * self.spec_width),
+                                       ("chunk", self.prefill_chunk))}
             self._fused = jax.jit(
                 self._fused_impl,
                 donate_argnums=(1,) if donate else ())

@@ -321,17 +321,17 @@ def paged_logits(model, params, seq, page: int, chunk: int, prompt: int):
     return np.concatenate(out)
 
 
-@pytest.mark.parametrize("key_block,expert_rows,page,chunk,prompt", [
-    (8, 4, 4, 16, 29),        # several key blocks; experts overflow
-    (1024, 128, 4, 16, 32),   # the cell's own block sizes; whole chunks
-    (16, 2, 8, 12, 39),       # pages of 8; one decoded token
-    (8, 4, 8, 20, 45)])       # a page at a time, chunks from mid-page
+@pytest.mark.parametrize("key_block,page,chunk,prompt", [
+    (8, 4, 16, 29),           # several key blocks
+    (1024, 4, 16, 32),        # the cell's own key block; whole chunks
+    (16, 8, 12, 39),          # pages of 8; one decoded token
+    (8, 8, 20, 45)])          # a page at a time, chunks from mid-page
 def test_chunked_prefill_then_paged_decode_gives_the_references_logits(
-        key_block, expert_rows, page, chunk, prompt):
+        key_block, page, chunk, prompt):
     flat, params = toy_params(11 + key_block)
     seq = np.random.default_rng(prompt).integers(0, 64, LENGTH).astype(
         np.int32)
-    model = toy_model(key_block=key_block, expert_block_rows=expert_rows)
+    model = toy_model(key_block=key_block)
     got = paged_logits(model, params, seq, page, chunk, prompt)
     want = np.asarray(ref_logits(flat, jnp.asarray(seq)))
     assert np.abs(want).max() > 0.1
@@ -439,7 +439,7 @@ def run_engine(seed, lengths, *, max_new=8, **clone):
 
 
 @pytest.mark.parametrize("seed,clone", [
-    (5, {"key_block": 8, "expert_block_rows": 4}), (6, {})])
+    (5, {"key_block": 8}), (6, {})])
 def test_the_engine_serves_what_the_reference_puts_first(seed, clone):
     """Requests of mixed length through ``Engine`` (continuous batching,
     fused chunk + decode steps): every served token is the reference's
@@ -546,10 +546,10 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(routed + shared, whole, atol=2e-6)
 
 
-@pytest.mark.parametrize("first,count,block_rows", [
-    (0, 4, 128), (4, 4, 3), (8, 4, 1), (12, 4, 128), (0, 16, 2), (6, 1, 128)])
-def test_the_programs_share_is_the_references_share(first, count,
-                                                    block_rows):
+@pytest.mark.parametrize("first,count,row_tile", [
+    (0, 4, None), (4, 4, 3), (8, 4, 1), (12, 4, None), (0, 16, 2),
+    (6, 1, None)])
+def test_the_programs_share_is_the_references_share(first, count, row_tile):
     flat, _ = toy_params(21, UNCUT)
     rng = np.random.default_rng(first)
     x = jnp.asarray(rng.normal(size=(19, 64)), jnp.float32)
@@ -557,7 +557,7 @@ def test_the_programs_share_is_the_references_share(first, count,
     p, cfg = layer_leaves(flat, UNCUT, first, count)
     layer = moe.HeldExpertsMlp(
         num_experts=16, held=(first, count), hidden_dim=32, top_k=4,
-        n_group=4, topk_group=2, routed_scale=2.5, block_rows=block_rows)
+        n_group=4, topk_group=2, routed_scale=2.5, row_tile=row_tile)
     got, sown = jax.jit(lambda v, x, valid: layer.apply(
         v, x, valid, mutable=["counters"]))(
         {"params": weights.unflatten(p)}, x, valid)

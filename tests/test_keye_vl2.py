@@ -122,19 +122,19 @@ def test_three_unequal_streams_give_the_references_logits(forwards):
 
 # -- the program against the reference ---------------------------------------
 
-@pytest.mark.parametrize("key_block,expert_rows,page,chunk,prompt", [
-    (8, 4, 4, 16, 29),        # several key blocks; experts overflow
-    (1024, 128, 8, 12, 39),   # the cell's own block sizes; pages of 8
-    (8, 4, 8, 20, 45)])       # a page at a time, chunks from mid-page
+@pytest.mark.parametrize("key_block,page,chunk,prompt", [
+    (8, 4, 16, 29),           # several key blocks
+    (1024, 8, 12, 39),        # the cell's own key block; pages of 8
+    (8, 8, 20, 45)])          # a page at a time, chunks from mid-page
 def test_chunked_prefill_then_paged_decode_gives_the_references_logits(
-        key_block, expert_rows, page, chunk, prompt):
+        key_block, page, chunk, prompt):
     """Contexts run to 48 keys where a query may attend 8: the selection
     bites in the chunk lane (from the chunk's ninth row on) and at every
     decoded token."""
     flat, params = toy_params(11 + key_block)
     seq = np.random.default_rng(prompt).integers(0, 64, LENGTH).astype(
         np.int32)
-    model = toy_model(key_block=key_block, expert_block_rows=expert_rows)
+    model = toy_model(key_block=key_block)
     assert model.paged_lane(chunk) == "masked-blocks"
     assert model.paged_lane(1) == "sparse-gather"
     got = paged_logits(model, params, seq, page, chunk, prompt)
@@ -177,8 +177,7 @@ def run_engine(seed, lengths, *, max_new=8, **clone):
 @pytest.fixture(scope="module")
 def served():
     with jax.default_matmul_precision("highest"):
-        return run_engine(5, (24, 31, 40), key_block=8,
-                          expert_block_rows=4)
+        return run_engine(5, (24, 31, 40), key_block=8)
 
 
 def test_the_engine_serves_what_the_reference_puts_first(served):
@@ -260,7 +259,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(sum(parts.values()), whole, atol=2e-6)
     layer = moe.HeldExpertsMlp(
         num_experts=128, held=(64, 32), hidden_dim=32, top_k=8,
-        scoring="softmax", shared_experts=0, block_rows=3)
+        scoring="softmax", shared_experts=0, row_tile=3)
     got = jax.jit(layer.apply)(
         {"params": weights.unflatten(share(flat, 64, 32)[0])}, x)
     np.testing.assert_allclose(got, parts[64], atol=2e-6)

@@ -562,3 +562,32 @@ def test_the_kda_decode_kernel_compiles_for_a_v5e_at_ling_widths(one_chip):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == state_bytes
     assert mem.temp_size_in_bytes < state_bytes // 64
+
+
+@pytest.mark.parametrize("t,d,hidden,held,router", [
+    (128, 2560, 768, 64, 512),      # Ling-3.0-flash, the decode pass
+    (1024, 7168, 2048, 16, 256)])   # DeepSeek-V3.2, a chunk: most VMEM
+def test_the_expert_ffn_kernel_compiles_for_a_v5e(one_chip, t, d, hidden,
+                                                  held, router):
+    """``expert_ffn`` at a serving cell's widths: nothing of the experts'
+    or the pairs' size is made beside the kernel (it reads the weights
+    where they lie and keeps ``x`` and the sum in VMEM)."""
+    from distributed_training_tpu.ops import expert_ffn as ef
+
+    def shape(s, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    tile = ef.row_tile(t, 8, router)
+    assert ef.kernel_fits(t, d, hidden, 8, router, jnp.bfloat16)
+
+    def call(*args):
+        return ef.expert_ffn(*args, row_tile=tile, interpret=False)
+
+    w_in = shape((held, d, hidden), jnp.bfloat16)
+    compiled = jax.jit(call).lower(
+        shape((t, d), jnp.bfloat16), w_in, w_in,
+        shape((held, hidden, d), jnp.bfloat16), shape((t * 8,)),
+        shape((t * 8,), jnp.float32), shape((held,)),
+        shape((held,))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * t * d * 4

@@ -204,10 +204,9 @@ def test_the_four_shares_and_the_shared_expert_once_are_the_uncut_layer():
     np.testing.assert_allclose(routed + shared, whole, atol=2e-6)
 
 
-@pytest.mark.parametrize("first,count,block_rows", [
-    (4, 4, 3), (12, 4, 128), (0, 16, 2)])
-def test_the_programs_share_is_the_references_share(first, count,
-                                                    block_rows):
+@pytest.mark.parametrize("first,count,row_tile", [
+    (4, 4, 3), (12, 4, None), (0, 16, 2)])
+def test_the_programs_share_is_the_references_share(first, count, row_tile):
     flat, _ = toy_params(21, UNCUT)
     rng = np.random.default_rng(first)
     x = jnp.asarray(rng.normal(size=(19, 64)), jnp.float32)
@@ -215,7 +214,7 @@ def test_the_programs_share_is_the_references_share(first, count,
     p, cfg = share_of(flat, first, count)
     layer = moe.HeldExpertsMlp(
         num_experts=16, held=(first, count), hidden_dim=32, top_k=4,
-        n_group=1, topk_group=1, routed_scale=2.5, block_rows=block_rows)
+        n_group=1, topk_group=1, routed_scale=2.5, row_tile=row_tile)
     got, sown = jax.jit(lambda v, x, valid: layer.apply(
         v, x, valid, mutable=["counters"]))(
         {"params": weights.unflatten(p)}, x, valid)
@@ -339,16 +338,15 @@ def test_the_kernel_is_the_gather_lane(dtype, t_in, tol):
 
 # -- the program against the reference ---------------------------------------
 
-@pytest.mark.parametrize("cfg,lane,key_block,expert_rows,page,chunk,prompt", [
-    (TOY, "dense-latent-gather", 8, 4, 4, 12, 29),   # several key blocks
-    (TILE, "dense-latent-kernel", 16, 2, 8, 16, 30)])  # the kernel decodes
+@pytest.mark.parametrize("cfg,lane,key_block,page,chunk,prompt", [
+    (TOY, "dense-latent-gather", 8, 4, 12, 29),   # several key blocks
+    (TILE, "dense-latent-kernel", 16, 8, 16, 30)])  # the kernel decodes
 def test_chunked_prefill_then_paged_decode_gives_the_references_logits(
-        cfg, lane, key_block, expert_rows, page, chunk, prompt):
+        cfg, lane, key_block, page, chunk, prompt):
     flat, params = toy_params(11 + key_block, cfg)
     seq = np.random.default_rng(prompt).integers(0, 64, LENGTH).astype(
         np.int32)
-    model = toy_model(cfg, key_block=key_block,
-                      expert_block_rows=expert_rows)
+    model = toy_model(cfg, key_block=key_block)
     assert model.paged_lane(1, page, None) == lane
     got = paged_logits(model, params, seq, page, chunk, prompt)
     want = ref_logits(flat, seq, cfg)
@@ -401,9 +399,15 @@ def test_the_engine_serves_what_the_reference_puts_first(seed, cfg, page,
 
 def test_the_iterations_counters():
     """Dense attention reads every live row (``kv_rows_selected`` is
-    ``kv_rows_live``); ``experts_hit`` comes back with the tokens: against
+    ``kv_rows_live``); ``experts_hit`` comes back with the tokens (the
+    experts computed by the kernel ``expert_ffn``): against
     the reference's routing of one lone request's decode steps."""
     engine, flat, prompts, done, spans = run_engine(9, (26,), max_new=5)
+    # both passes go through the expert kernel, and the engine says so
+    build = [s for s in trace.host_spans()
+             if s.name == "setup.program_build"][-1]
+    assert build.attrs["expert_lane"] == {"decode": "kernel",
+                                          "chunk": "kernel"}
     (prompt,) = prompts.values()
     seq = np.concatenate([prompt, np.asarray(done[0].tokens, np.int32)])
     decode = [s.attrs for s in spans if s.attrs["program"] == "decode"]
